@@ -1,0 +1,948 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives the main path once through the public entry points, at the
+full width of the models the repo is measured on, and checks what comes out:
+
+- **Gate.** Print jax version, platform, ``device_kind``, device count, the
+  resolved compile-cache dir. No TPU -> message and non-zero exit; no result
+  line. Pallas must compile (``_interpret()`` False).
+- **Leg A — trainer, full width.** ResNet-50 bf16, 224x224x3, 1000 classes,
+  batch 128: ``init -> warmup -> fit_on_device -> fit() -> output()``.
+- **Leg B — trainer on the kernel route.** char-RNN 2x512 bf16, B=64, T=256,
+  adam: which variant ``lstm_seq`` / ``softmax_xent`` / ``optimizer`` resolved
+  to, and parity with a twin trained under ``set_mode("reference")``.
+- **Leg C — server.** ``InferenceService`` + ``POST /serving/predict`` with
+  mixed row counts + one ``/serving/rnn`` session; donated request buffers;
+  hot-swap from a net that keeps training.
+- **Leg D — every Pallas kernel, compiled, f32 and bf16**, at the shapes legs
+  A/B feed them, each against its XLA reference.
+- **Leg E — four chips** (only when ``len(jax.devices()) >= 4``; otherwise
+  reported as *not run*, never as passed): data-parallel and data x fsdp
+  ``ParallelWrapper.fit_on_device``.
+
+Legs are plain functions taking sizes: ``tests/test_chip_smoke.py`` calls them
+tiny on the CPU (interpret-mode kernels); ``__main__`` runs them at full width
+and REQUIRES the chip. Run every leg: ``python chip_smoke.py``; while
+debugging: ``python chip_smoke.py --legs D,B``. A failed leg names itself and
+the process exits non-zero. Last stdout line on success::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+One process, no children, no platform override in code: a chip belongs to one
+process at a time.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import os
+import sys
+import time
+import traceback
+import urllib.request
+
+import numpy as np
+
+LEGS = ("A", "B", "C", "D", "E")
+
+
+class LegFailure(AssertionError):
+    """A leg's check did not hold."""
+
+
+def check(cond, message: str) -> None:
+    if not cond:
+        raise LegFailure(message)
+
+
+# --------------------------------------------------------------- monitoring
+class _Monitors:
+    """jax.monitoring listeners (they cannot be unregistered, so one set per
+    process): every backend compile request with its seconds, and the
+    persistent compilation cache's hits and misses."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.backend_compiles = 0
+        self.compile_seconds = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, name, seconds, **_kw):
+        if name.endswith("backend_compile_duration"):
+            self.backend_compiles += 1
+            self.compile_seconds += float(seconds)
+
+    def _on_event(self, name, **_kw):
+        if name.endswith("compilation_cache/cache_hits"):
+            self.cache_hits += 1
+        elif name.endswith("compilation_cache/cache_misses"):
+            self.cache_misses += 1
+
+    def snapshot(self) -> dict:
+        return {"backend_compiles": self.backend_compiles,
+                "compile_seconds": round(self.compile_seconds, 3),
+                "persistent_cache_hits": self.cache_hits,
+                "persistent_cache_misses": self.cache_misses}
+
+
+_MONITORS = None
+
+
+def monitors() -> _Monitors:
+    global _MONITORS
+    if _MONITORS is None:
+        _MONITORS = _Monitors()
+    return _MONITORS
+
+
+@contextlib.contextmanager
+def counting():
+    """Compile activity inside the block: a dict, filled when it ends."""
+    before, out = monitors().snapshot(), {}
+    try:
+        yield out
+    finally:
+        after = monitors().snapshot()
+        out.update({k: round(after[k] - before[k], 3) for k in after})
+
+
+def _manager():
+    from deeplearning4j_tpu.runtime.compile_manager import get_compile_manager
+
+    return get_compile_manager()
+
+
+def _admission_state() -> dict:
+    """Every AOT executable the manager admitted must carry a static-cost
+    record, and no admission-time analysis may have failed."""
+    cm = _manager()
+    stats = cm.stats()
+    return {"aot_entries": len(cm.memory_records()),
+            "cost_records": len(cm.cost_records()),
+            "admission_errors": stats["admission_errors"],
+            "compiles_total": stats["compiles_total"]}
+
+
+def _check_admission(leg: str) -> dict:
+    st = _admission_state()
+    check(st["admission_errors"] == 0,
+          f"leg {leg}: {st['admission_errors']} admission check(s) raised "
+          "(see the flight recorder's admission_error events)")
+    check(st["cost_records"] == st["aot_entries"] and st["aot_entries"] > 0,
+          f"leg {leg}: {st['cost_records']} cost records for "
+          f"{st['aot_entries']} AOT executables — the admission check did "
+          "not produce a record for each")
+    return st
+
+
+def _one_hot(rng, classes: int, shape) -> np.ndarray:
+    return np.eye(classes, dtype=np.float32)[rng.integers(0, classes, shape)]
+
+
+def _tree_np(tree):
+    import jax
+
+    return [np.asarray(l, np.float32) for l in jax.tree_util.tree_leaves(tree)]
+
+
+# --------------------------------------------------------------------- gate
+def device_info() -> dict:
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def gate(require_tpu: bool = True) -> dict:
+    """Say what we run on; refuse to go on without the chip."""
+    import jax
+
+    from deeplearning4j_tpu.analysis.cost_model import roofline_params
+    from deeplearning4j_tpu.ops import kernel_select as ks
+    from deeplearning4j_tpu.ops.pallas_kernels import _interpret
+    from deeplearning4j_tpu.runtime import native_available
+    from deeplearning4j_tpu.runtime.compile_manager import (
+        CACHE_DIR_ENV, resolve_persistent_cache)
+    from deeplearning4j_tpu.tune import store as tuned
+
+    monitors()
+    info = device_info()
+    cache_dir = resolve_persistent_cache()
+    print(f"jax {jax.__version__}  platform={info['platform']}  "
+          f"device_kind={info['kind']!r}  devices={info['count']}")
+    print(f"compile cache: {cache_dir} "
+          f"({CACHE_DIR_ENV} {'set' if os.environ.get(CACHE_DIR_ENV) else 'unset'})")
+    if require_tpu and info["platform"] != "tpu":
+        print(f"chip_smoke: no TPU found (platform is {info['platform']!r}); "
+              "this script measures nothing on a CPU", file=sys.stderr)
+        raise SystemExit(4)  # not 2/3: the chip tool uses those itself
+    if require_tpu:
+        check(not _interpret(), "Pallas kernels would run in interpret mode")
+    rl = roofline_params()  # an unknown TPU device_kind raises here
+    print(f"peaks: {rl['device_kind']!r} assumed={rl['assumed']} "
+          f"{rl['peak_flops'] / 1e12:.0f} TFLOP/s {rl['hbm_gbps']:.0f} GB/s "
+          f"HBM {rl['ici_gbps']:.0f} GB/s ICI")
+    if require_tpu:
+        check(not rl["assumed"] and rl["device_kind"] == info["kind"],
+              "roofline_params() did not resolve the attached device's row")
+    cal = ks.stats()["calibration"]
+    print(f"kernel calibration: entries={cal['entries']} path={cal['path']}")
+    check(cal["entries"] == 0,
+          f"stale {cal['path']} would steer kernel selection; remove it")
+    check(not os.path.exists(tuned.tuned_path()),
+          f"{tuned.tuned_path()} exists; the smoke must not depend on it")
+    print(f"native data loader: "
+          f"{'built (g++)' if native_available() else 'python fallback'}")
+    return info
+
+
+def probe_dispatch(n: int = 8192, chain: int = 8, reps: int = 30) -> dict:
+    """Informational, no claim: does ``block_until_ready`` wait for the
+    device, and what does one dispatch cost? A matmul chain of known FLOPs is
+    timed three ways (enqueue only / + block_until_ready / + host fetch of a
+    scalar); a trivial program is timed round-trip."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def work(x):
+        for _ in range(chain):
+            x = (x @ x) * (1.0 / n)
+        return x, x[0, 0].astype(jnp.float32)
+
+    x = jnp.ones((n, n), jnp.bfloat16)
+    jax.block_until_ready(work(x))
+    t0 = time.perf_counter()
+    out = work(x)
+    enqueue_s = time.perf_counter() - t0
+    jax.block_until_ready(out)
+    block_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    float(np.asarray(work(x)[1]))
+    fetch_s = time.perf_counter() - t0
+
+    tiny = jax.jit(lambda a: a + 1.0)
+    a = jnp.zeros((), jnp.float32)
+    jax.block_until_ready(tiny(a))
+    trips = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(tiny(a))
+        trips.append(time.perf_counter() - t0)
+    flops = chain * 2.0 * n ** 3
+    res = {
+        "matmul_chain_tflop": round(flops / 1e12, 3),
+        "enqueue_ms": round(1e3 * enqueue_s, 3),
+        "block_until_ready_ms": round(1e3 * block_s, 3),
+        "host_fetch_ms": round(1e3 * fetch_s, 3),
+        "achieved_tflops_by_block": round(flops / block_s / 1e12, 1),
+        "block_until_ready_synchronizes": bool(block_s >= 0.8 * fetch_s),
+        "tiny_dispatch_roundtrip_ms_median": round(
+            1e3 * float(np.median(trips)), 4),
+    }
+    print("probe_dispatch:", json.dumps(res))
+    return res
+
+
+# -------------------------------------------------------------------- leg A
+def leg_a_trainer(conf, image: int, classes: int, batch: int,
+                  staged_steps: int = 3, batch_steps: int = 2) -> dict:
+    """Trainer through the public staged path, the donated per-batch step
+    and the inference fast path, for a ComputationGraph conf."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.graph.computation_graph import ComputationGraph
+
+    cm = _manager()
+    t_leg = time.perf_counter()
+    net = ComputationGraph(conf).init()
+    rng = np.random.default_rng(0)
+    xs = rng.normal(size=(2, batch, image, image, 3)).astype(np.float32)
+    ys = _one_hot(rng, classes, (2, batch))
+    probe_leaf = np.asarray(jax.tree_util.tree_leaves(net.params)[0],
+                            np.float32).copy()
+
+    with counting() as warm:
+        net.warmup(xs, ys, steps=staged_steps)
+    print(f"  warmup: {warm}")
+
+    # staged path: nothing may compile after warmup
+    c0 = cm.stats()["compiles_total"]
+    with counting() as first:
+        t0 = time.perf_counter()
+        losses = net.fit_on_device(xs, ys, steps=staged_steps)
+        first_s = time.perf_counter() - t0
+    with counting() as steady:
+        t0 = time.perf_counter()
+        losses2 = net.fit_on_device(xs, ys, steps=staged_steps)
+        steady_s = time.perf_counter() - t0
+    check(cm.stats()["compiles_total"] == c0,
+          "fit_on_device compiled after warmup (compile manager)")
+    check(steady["backend_compiles"] == 0,
+          f"steady fit_on_device hit the backend compiler: {steady}")
+    check(np.all(np.isfinite(losses)) and np.all(np.isfinite(losses2)),
+          f"non-finite staged losses {losses} {losses2}")
+    check(losses.shape == (staged_steps,), f"losses shape {losses.shape}")
+    print(f"  fit_on_device x{staged_steps}: losses {np.round(losses, 4)} "
+          f"then {np.round(losses2, 4)}; first dispatch {first_s:.3f}s "
+          f"{first}, steady {steady_s:.3f}s "
+          f"({1e3 * steady_s / staged_steps:.1f} ms/step incl. fetch)")
+
+    # per-batch fit(): the donated _build_train_step program
+    with counting() as step_compile:
+        net.fit((xs[0], ys[0]))
+    with counting() as again:
+        for i in range(batch_steps):
+            net.fit((xs[i % 2], ys[i % 2]))
+    loss_b = net.score()
+    check(np.isfinite(loss_b), f"non-finite per-batch loss {loss_b}")
+    check(again["backend_compiles"] == 0,
+          f"per-batch fit() recompiled on a seen shape: {again}")
+    print(f"  fit() per-batch: first {step_compile}, "
+          f"{batch_steps} more steps loss {loss_b:.4f} {again}")
+
+    after = np.asarray(jax.tree_util.tree_leaves(net.params)[0], np.float32)
+    check(np.all(np.isfinite(after)) and not np.array_equal(after, probe_leaf),
+          "params did not change (or went non-finite) under training")
+
+    # inference fast path; the SAME device array twice (request buffers are
+    # donated — the caller's array must survive)
+    x_dev = jnp.asarray(xs[0])
+    out1 = net.output(x_dev)
+    with counting() as repeat:
+        out2 = net.output(x_dev)
+    check(repeat["backend_compiles"] == 0,
+          "output() recompiled on a seen shape")
+    check(out1.shape == (batch, classes), f"output shape {out1.shape}")
+    o1 = np.asarray(out1, np.float32)
+    check(np.all(np.isfinite(o1))
+          and np.array_equal(o1, np.asarray(out2, np.float32)),
+          "output() not finite / not repeatable on the same device array")
+    check(np.allclose(o1.sum(-1), 1.0, atol=2e-2), "softmax rows do not sum to 1")
+    np.asarray(x_dev)  # raises if the request buffer was donated away
+
+    adm = _check_admission("A")
+    print(f"  admission: {adm}")
+    return {"losses": [float(l) for l in losses],
+            "warmup": warm, "steady_ms_per_step_with_fetch":
+                round(1e3 * steady_s / staged_steps, 2),
+            "leg_seconds": round(time.perf_counter() - t_leg, 1)}
+
+
+# -------------------------------------------------------------------- leg B
+_LEG_B_SITES = ("lstm_seq", "softmax_xent", "optimizer")
+_REFERENCE_VARIANTS = {"reference", "xla"}  # every site's XLA path
+
+
+def _char_rnn_net(vocab, hidden, layers, dtype):
+    from deeplearning4j_tpu import MultiLayerNetwork
+    from deeplearning4j_tpu.models.char_rnn import char_rnn
+
+    conf = char_rnn(vocab_size=vocab, hidden_size=hidden, num_layers=layers,
+                    dtype=dtype)
+    conf.backprop_type = "standard"  # fit_on_device trains full sequences
+    return MultiLayerNetwork(conf).init()
+
+
+def _char_batches(vocab, batch, seq, slots=2, seed=0):
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, vocab, size=(slots, batch, seq + 1))
+    eye = np.eye(vocab, dtype=np.float32)
+    return eye[idx[:, :, :-1]], eye[idx[:, :, 1:]]
+
+
+def _update_cosines(p0, p_a, p_b):
+    """Per-leaf cosine between the two nets' total updates (p - p0). Adam
+    normalizes gradients, so low-precision noise flips a few near-zero
+    elements by a whole step; a wrong kernel decorrelates the update."""
+    cos = []
+    for a0, a, b in zip(p0, p_a, p_b):
+        da, db = (a - a0).ravel(), (b - a0).ravel()
+        cos.append(float(da @ db / (np.linalg.norm(da) * np.linalg.norm(db)
+                                    + 1e-30)))
+    return cos
+
+
+def leg_b_kernel_route(vocab: int = 96, hidden: int = 512, layers: int = 2,
+                       batch: int = 64, seq: int = 256, steps: int = 3,
+                       dtype: str = "bfloat16", require_fused: bool = True,
+                       loss_rtol: float = 3e-2, min_cosine: float = 0.9):
+    """char-RNN through ``fit_on_device`` with kernels chosen by the default
+    selection, then a twin under ``set_mode("reference")``. Returns
+    ``(info, trained_net, (xs, ys))`` — leg C serves the trained net and
+    trains it on with the same staged batches (same executable)."""
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    t_leg = time.perf_counter()
+    ks.reset()  # selections cache per shape key: start the log clean
+    xs, ys = _char_batches(vocab, batch, seq)
+    net = _char_rnn_net(vocab, hidden, layers, dtype)
+    p0 = _tree_np(net.params)
+    with counting() as warm:
+        net.warmup(xs, ys, steps=steps)
+    t0 = time.perf_counter()
+    losses = net.fit_on_device(xs, ys, steps=steps)
+    run_s = time.perf_counter() - t0
+    check(np.all(np.isfinite(losses)), f"non-finite losses {losses}")
+
+    chosen = {}
+    for rec in ks.selection_log():
+        if rec["site"] in _LEG_B_SITES:
+            chosen.setdefault(rec["site"], []).append(rec)
+            print(f"  kernel_select {rec['site']}: {rec['variant']} "
+                  f"(reason={rec['reason']}, mode={rec['mode']}"
+                  + (f", infeasible={rec['infeasible']}"
+                     if rec.get("infeasible") else "") + f") ctx={rec['ctx']}")
+    for site in _LEG_B_SITES:
+        check(site in chosen, f"site {site} was never consulted")
+        for rec in chosen[site]:
+            check(rec["reason"] != "fallback" and not rec.get("infeasible"),
+                  f"site {site} gave way to {rec['variant']}: fused variant(s) "
+                  f"{rec.get('infeasible')} infeasible at {rec['ctx']}")
+            if require_fused:
+                check(rec["variant"] not in _REFERENCE_VARIANTS,
+                      f"site {site} resolved to {rec['variant']} "
+                      f"({rec['reason']}), expected a fused Pallas variant")
+
+    ks.set_mode("reference")
+    try:
+        twin = _char_rnn_net(vocab, hidden, layers, dtype)
+        losses_ref = twin.fit_on_device(xs, ys, steps=steps)
+    finally:
+        ks.set_mode(None)
+    ref_variants = {r["variant"] for r in ks.selection_log()
+                    if r["mode"] == "reference"}
+    check(ref_variants <= _REFERENCE_VARIANTS,
+          f"reference twin ran fused kernels: {ref_variants}")
+    check(np.allclose(losses, losses_ref, rtol=loss_rtol),
+          f"loss trajectories diverge: {losses} vs reference {losses_ref}")
+    p_a, p_b = _tree_np(net.params), _tree_np(twin.params)
+    cos = _update_cosines(p0, p_a, p_b)
+    max_abs = max(float(np.max(np.abs(a - b))) for a, b in zip(p_a, p_b))
+    print(f"  losses {np.round(losses, 4)} vs reference "
+          f"{np.round(losses_ref, 4)}; update cosine per leaf min "
+          f"{min(cos):.4f}; max |param diff| {max_abs:.2e}; "
+          f"{steps} steps {run_s:.3f}s; warmup {warm}")
+    check(min(cos) >= min_cosine,
+          f"param updates decorrelate from the reference twin: {cos}")
+    adm = _check_admission("B")
+    info = {"variants": {s: sorted({r["variant"] for r in chosen[s]})
+                         for s in _LEG_B_SITES},
+            "reasons": {s: sorted({r["reason"] for r in chosen[s]})
+                        for s in _LEG_B_SITES},
+            "losses": [float(l) for l in losses],
+            "min_update_cosine": round(min(cos), 4), "warmup": warm,
+            "admission": adm,
+            "leg_seconds": round(time.perf_counter() - t_leg, 1)}
+    return info, net, (xs, ys)
+
+
+# -------------------------------------------------------------------- leg C
+def _post(port: int, path: str, payload: dict) -> dict:
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}", data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as resp:
+        return json.loads(resp.read())
+
+
+def leg_c_server(trainer, batches, steps: int = 3, seq: int = 32,
+                 row_cap: int = 8, request_rows=(1, 3, 8, 5, 2),
+                 atol: float = 2e-2) -> dict:
+    """Serve a clone of ``trainer`` (a recurrent MultiLayerNetwork) over
+    HTTP while the trainer keeps training on donated buffers. ``batches``
+    and ``steps`` are what the trainer was staged with (same executable)."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.runtime.checkpoint import CheckpointStore
+    from deeplearning4j_tpu.serving import (InferenceService, reset_services,
+                                            set_service)
+    from deeplearning4j_tpu.ui.server import UIServer
+
+    cm = _manager()
+    t_leg = time.perf_counter()
+    rng = np.random.default_rng(1)
+    xs, ys = batches
+    vocab = int(xs.shape[-1])
+
+    def request(rows):
+        return _one_hot(rng, vocab, (rows, seq))
+
+    def predict(x):
+        return np.asarray(_post(ui.port, "/serving/predict", {
+            "model": "smoke", "features": x.tolist()})["output"], np.float32)
+
+    def rnn(op, **fields):
+        return _post(ui.port, "/serving/rnn",
+                     {"model": "smoke", "op": op, **fields})
+
+    served = trainer.clone()
+    svc = InferenceService(max_batch=row_cap)
+    set_service(svc)
+    ui = UIServer(port=0)
+    try:
+        svc.register("smoke", served)
+        with counting() as warm:
+            warmed = svc.warmup("smoke", request(1))
+        print(f"  warmup: {warmed} row buckets <= {row_cap}: {warm}")
+        # the decode stream's own program (slot batch x 1 step)
+        sid = rnn("open")["session"]
+        frame = request(1)[0, 0]
+        first = rnn("step", session=sid, features=frame.tolist())["output"]
+
+        c0 = cm.stats()["compiles_total"]
+        with counting() as live:
+            for rows in request_rows:
+                x = request(rows)
+                got = predict(x)
+                want = np.asarray(served.output(x), np.float32)
+                check(got.shape == (rows, seq, vocab),
+                      f"served shape {got.shape}")
+                check(np.all(np.isfinite(got))
+                      and np.allclose(got, want, atol=1e-6),
+                      f"/serving/predict rows={rows} differs from net.output: "
+                      f"max {np.max(np.abs(got - want)):.3e}")
+            second = rnn("step", session=sid,
+                         features=frame.tolist())["output"]
+            rnn("close", session=sid)
+        check(cm.stats()["compiles_total"] == c0
+              and live["backend_compiles"] == 0,
+              f"serving compiled after warmup: {live}")
+        # the session alone on a twin must see the same two steps
+        solo = trainer.clone()
+        want1 = np.asarray(solo.rnn_time_step(frame[None]), np.float32)[0]
+        want2 = np.asarray(solo.rnn_time_step(frame[None]), np.float32)[0]
+        check(np.allclose(first, want1, atol=atol)
+              and np.allclose(second, want2, atol=atol),
+              "/serving/rnn session differs from rnn_time_step run alone: "
+              f"{np.max(np.abs(np.asarray(first) - want1)):.3e} "
+              f"{np.max(np.abs(np.asarray(second) - want2)):.3e}")
+        print(f"  {len(request_rows)} predict requests rows={request_rows} "
+              f"+ 1 rnn session match net.output / rnn_time_step; {live}")
+
+        # donated request buffers: the caller's device array must survive
+        x_dev = jnp.asarray(request(4))
+        o1 = np.asarray(served.output(x_dev), np.float32)
+        o2 = np.asarray(served.output(x_dev), np.float32)
+        check(np.array_equal(o1, o2), "output(x) twice on one device array differs")
+        x_host = np.asarray(x_dev)  # raises if donation deleted it
+
+        # the trainer keeps training (its buffers are donated); the clone
+        # must keep serving, then take the new params by hot swap
+        trainer.fit_on_device(xs, ys, steps=steps)
+        before = np.asarray(served.output(x_host), np.float32)
+        check(np.array_equal(before, o1),
+              "serving changed although only the trainer stepped")
+        snap = CheckpointStore.snapshot(trainer)
+        c0 = cm.stats()["compiles_total"]
+        svc.hot_swap("smoke", params=snap.params, state=snap.state, version=1)
+        trainer.fit_on_device(xs, ys, steps=steps)  # donates what it holds
+        after = predict(x_host)
+        check(np.all(np.isfinite(after)) and not np.array_equal(after, before),
+              "hot swap did not change what is served")
+        check(cm.stats()["compiles_total"] == c0,
+              "hot swap (or serving after it) compiled")
+        print("  donated request buffer survived; hot swap served new params "
+              "with zero compiles while the trainer stepped on")
+        stats = svc.stats()["models"]["smoke"]
+    finally:
+        ui.stop()
+        reset_services()
+    adm = _check_admission("C")
+    return {"buckets_warmed": warmed, "swaps": stats["swaps_total"],
+            "requests": stats["requests_total"],
+            "admission": adm,
+            "leg_seconds": round(time.perf_counter() - t_leg, 1)}
+
+
+# -------------------------------------------------------------------- leg D
+def _err(a, b) -> float:
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b) / (np.abs(b) + 1.0)))
+
+
+def _err_norm(a, b) -> float:
+    """Norm-wise error for gradients: sums over many terms cancel, so an
+    element near zero carries the noise of the whole sum."""
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1.0))
+
+
+def _tol(dtype, long_sequence: bool = False) -> float:
+    """bf16 carries ~3 significant digits; over hundreds of recurrent steps
+    the rounding compounds, so the full-length bf16 LSTM check is a
+    compile-and-sanity check and the short one the numerics check."""
+    import jax.numpy as jnp
+
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return 3e-3
+    return 2e-1 if long_sequence else 8e-2
+
+
+def _named(name: str, fn):
+    """Run one sub-check; a failure carries its name (one leg-D line can
+    hide several kernels)."""
+    try:
+        return fn()
+    except Exception as e:
+        e.add_note(f"in sub-check {name}")
+        raise
+
+
+def _kd_lstm(T, B, H, dtype):
+    """Whole-sequence fused LSTM (plain + masked, fwd + every grad) and the
+    per-step fused cell vs autodiff through lax.scan of the same math."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.default_rng(5)
+    r = lambda *sh, s=0.3: jnp.asarray(rng.normal(size=sh) * s, dtype)  # noqa: E731
+    zx, h0, c0 = r(T, B, 4 * H), r(B, H), r(B, H)
+    RW = r(H, 4 * H, s=0.05)
+    pF, pI, pO = r(H, s=0.1), r(H, s=0.1), r(H, s=0.1)
+    mask = jnp.asarray((rng.random((T, B, 1)) > 0.25), dtype)
+    f32 = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: a.astype(jnp.float32), t)
+
+    def scan_ref(zx, h0, c0, RW, pF, pI, pO, m=None):
+        def step(carry, inp):
+            h, c = carry
+            z, mt = inp
+            h2, c2, *_ = pk._cell_math(z, h, c, RW, pF, pI, pO,
+                                       jnp.tanh, jax.nn.sigmoid)
+            h2, c2 = mt * h2 + (1 - mt) * h, mt * c2 + (1 - mt) * c
+            return (h2, c2), h2
+        mm = jnp.ones((zx.shape[0], 1, 1), zx.dtype) if m is None else m
+        (hT, cT), ys = jax.lax.scan(step, (h0, c0), (zx, mm))
+        return ys, hT, cT
+
+    def loss_of(fn):
+        def loss(*a):
+            ys, hT, cT = fn(*a)
+            return (jnp.sum(ys.astype(jnp.float32) ** 2)
+                    + jnp.sum(hT.astype(jnp.float32))
+                    + jnp.sum(jnp.tanh(cT.astype(jnp.float32))))
+        return loss
+
+    args = (zx, h0, c0, RW, pF, pI, pO)
+    out = {}
+    plain = lambda *a: pk.fused_lstm_sequence(*a, "tanh", "sigmoid")  # noqa: E731
+    masked = lambda *a: pk.fused_lstm_sequence_masked(  # noqa: E731
+        a[0], mask, *a[1:], "tanh", "sigmoid")
+    for name, fused, ref in (
+            ("seq", plain, lambda *a: scan_ref(*f32(a))),
+            ("seq_masked", masked,
+             lambda *a: scan_ref(*f32(a), m=mask.astype(jnp.float32)))):
+        out[f"{name}_fwd"] = max(map(
+            _err, _named(f"{name}_fwd", lambda: jax.jit(fused)(*args)),
+            ref(*args)))
+        g = _named(f"{name}_grad", lambda: jax.jit(jax.grad(
+            loss_of(fused), argnums=tuple(range(7))))(*args))
+        gr = jax.grad(loss_of(ref), argnums=tuple(range(7)))(*args)
+        out[f"{name}_grad"] = max(map(_err_norm, g, gr))
+    cell = lambda z, h, c: pk.fused_lstm_cell(z, h, c, RW, pF, pI, pO)  # noqa: E731
+    cref = lambda z, h, c: pk._cell_math(  # noqa: E731
+        *f32((z, h, c, RW, pF, pI, pO)), jnp.tanh, jax.nn.sigmoid)[:2]
+    out["cell_fwd"] = max(map(
+        _err, _named("cell_fwd", lambda: jax.jit(cell)(zx[0], h0, c0)),
+        cref(zx[0], h0, c0)))
+    gl = lambda fn: jax.grad(lambda *a: jnp.sum(  # noqa: E731
+        fn(*a)[0].astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    out["cell_grad"] = max(map(
+        _err_norm,
+        _named("cell_grad", lambda: jax.jit(gl(cell))(zx[0], h0, c0)),
+        gl(cref)(zx[0], h0, c0)))
+    return out
+
+
+def _kd_sxent(N, C, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops.pallas_kernels import fused_softmax_xent
+
+    rng = np.random.default_rng(N + C)
+    x = jnp.asarray(rng.normal(size=(N, C)) * 2.0, dtype)
+    lab = jnp.asarray(_one_hot(rng, C, (N,)), dtype)
+    w = jnp.asarray(rng.random(N) + 0.5, jnp.float32)
+
+    def ref(x, lab):
+        logp = jax.nn.log_softmax(x.astype(jnp.float32), axis=-1)
+        return -jnp.sum(lab.astype(jnp.float32) * logp, axis=-1)
+
+    out = {"fwd": _err(jax.jit(fused_softmax_xent)(x, lab), ref(x, lab))}
+    g = jax.jit(jax.grad(lambda x, l: jnp.sum(fused_softmax_xent(x, l) * w),
+                         argnums=(0, 1)))(x, lab)
+    gr = jax.grad(lambda x, l: jnp.sum(ref(x, l) * w), argnums=(0, 1))(x, lab)
+    out["grad"] = max(map(_err_norm, g, gr))
+    return out
+
+
+def _kd_adam(shape, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops.pallas_kernels import fused_adam_update
+
+    rng = np.random.default_rng(int(np.prod(shape)))
+    g = jnp.asarray(rng.normal(size=shape), dtype)
+    m = jnp.asarray(rng.normal(size=shape) * 0.1, dtype)
+    v = jnp.asarray(rng.random(shape) * 0.1, dtype)
+    b1, b2, eps, lr, t = 0.9, 0.999, 1e-8, 1e-3, 3
+    bc1, bc2 = 1.0 - b1 ** t, 1.0 - b2 ** t
+    u, m2, v2 = jax.jit(lambda g, m, v: fused_adam_update(
+        g, m, v, jnp.float32(lr), jnp.float32(bc1), jnp.float32(bc2),
+        b1, b2, eps))(g, m, v)
+    gf, mf, vf = (np.asarray(a, np.float32) for a in (g, m, v))
+    m_ref = b1 * mf + (1 - b1) * gf
+    v_ref = b2 * vf + (1 - b2) * gf * gf
+    u_ref = -lr * (m_ref / bc1) / (np.sqrt(v_ref / bc2) + eps)
+    check(u.shape == tuple(shape) and u.dtype == g.dtype,
+          f"adam update shape/dtype {u.shape} {u.dtype}")
+    # the update is O(lr): compare it relative to lr, moments as they are
+    return {"update": _err(np.asarray(u, np.float32) / lr, u_ref / lr),
+            "m": _err(m2, m_ref), "v": _err(v2, v_ref)}
+
+
+def _kd_flash(B, H, T, D, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops.flash_attention import flash_attention
+    from deeplearning4j_tpu.parallel.ring_attention import attention
+
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(B, H, T, D)), dtype)
+               for _ in range(3))
+    kmask = jnp.asarray(rng.random((B, T)) > 0.2)
+    out = {}
+    # f32 matmul precision: with the MXU's default bf16 multiply flash-vs-XLA
+    # causal grads differ ~2% from arithmetic alone, masking logic bugs
+    with jax.default_matmul_precision("float32"):
+        for causal in (False, True):
+            fl = lambda q, k, v: flash_attention(  # noqa: E731
+                q, k, v, causal=causal, key_mask=kmask)
+            rf = lambda q, k, v: attention(  # noqa: E731
+                q.astype(jnp.float32), k.astype(jnp.float32),
+                v.astype(jnp.float32), causal=causal, key_mask=kmask)
+            out[f"fwd_causal={causal}"] = _err(jax.jit(fl)(q, k, v),
+                                               rf(q, k, v))
+            gl = lambda fn: jax.grad(lambda *a: jnp.sum(  # noqa: E731
+                fn(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+            out[f"grad_causal={causal}"] = max(map(
+                _err_norm, jax.jit(gl(fl))(q, k, v), gl(rf)(q, k, v)))
+    return out
+
+
+def _kd_lrn(shape, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=shape), dtype)
+    k, n, alpha, beta = 2.0, 5, 1e-4, 0.75
+
+    def ref(x):
+        x = x.astype(jnp.float32)
+        return x * (k + alpha * pk._window_sum(x * x, n)) ** -beta
+
+    fused = lambda x: pk.fused_lrn(x, k, n, alpha, beta)  # noqa: E731
+    gl = lambda fn: jax.grad(lambda x: jnp.sum(  # noqa: E731
+        fn(x).astype(jnp.float32) ** 2))
+    return {"fwd": _err(jax.jit(fused)(x), ref(x)),
+            "grad": _err_norm(jax.jit(gl(fused))(x), gl(ref)(x))}
+
+
+def leg_d_kernels(lstm=(256, 64, 512), lstm_small=(32, 16, 128),
+                  sxent=((16384, 96), (128, 1000), (256, 10)),
+                  adam=((512, 2048), (2048,), (96,), (7, 9)),
+                  flash=(2, 4, 256, 64), lrn=(4, 14, 14, 64)) -> dict:
+    """Every Pallas kernel compiled (interpret mode only off-TPU), f32 and
+    bf16, against its XLA reference. Runs every check before failing, so one
+    chip call shows every kernel Mosaic rejects."""
+    import jax.numpy as jnp
+
+    t_leg = time.perf_counter()
+    plan = []
+    for dt in (jnp.float32, jnp.bfloat16):
+        name = jnp.dtype(dt).name
+        shape = lstm if dt == jnp.bfloat16 else lstm_small
+        plan.append((f"lstm{shape}/{name}", _kd_lstm, (*shape, dt)))
+        if dt == jnp.bfloat16 and lstm_small != lstm:
+            plan.append((f"lstm{lstm_small}/{name}", _kd_lstm,
+                         (*lstm_small, dt)))
+        plan += [(f"softmax_xent{s}/{name}", _kd_sxent, (*s, dt))
+                 for s in sxent]
+        plan += [(f"adam{s}/{name}", _kd_adam, (s, dt)) for s in adam]
+        plan.append((f"flash{flash}/{name}", _kd_flash, (*flash, dt)))
+        plan.append((f"lrn{lrn}/{name}", _kd_lrn, (lrn, dt)))
+    failed, worst = [], {}
+    for label, fn, args in plan:
+        tol = _tol(args[-1], long_sequence=fn is _kd_lstm and args[0] >= 128)
+        try:
+            errs = fn(*args)
+        except Exception as e:  # noqa: BLE001 - report every kernel, then fail
+            msg = f"{getattr(e, '__notes__', '')} {type(e).__name__}: {e}"
+            print(f"  FAIL {label}: {msg[:1500]}")
+            failed.append(label)
+            continue
+        bad = {k: float(f"{v:.2e}") for k, v in errs.items()
+               if not (np.isfinite(v) and v <= tol)}
+        worst[label] = float(f"{max(errs.values()):.2e}")
+        if bad:
+            print(f"  FAIL {label}: over tol {tol}: {bad}")
+            failed.append(label)
+        else:
+            print(f"  ok   {label}: max rel err {worst[label]} (tol {tol})")
+    check(not failed, f"{len(failed)}/{len(plan)} kernel checks failed: {failed}")
+    return {"checks": len(plan), "worst": max(worst.values()),
+            "leg_seconds": round(time.perf_counter() - t_leg, 1)}
+
+
+# -------------------------------------------------------------------- leg E
+def _device_ids(tree) -> set:
+    import jax
+
+    return {s.device.id for leaf in jax.tree_util.tree_leaves(tree)
+            for s in leaf.addressable_shards}
+
+
+def leg_e_four_chips(vocab: int = 96, hidden: int = 512, layers: int = 2,
+                     batch: int = 64, seq: int = 256, steps: int = 3,
+                     dtype: str = "bfloat16", dp_rtol: float = 3e-2,
+                     fsdp_rtol: float = 1e-1) -> dict:
+    """The leg B model on four devices: plain data parallelism, then
+    data x fsdp with bf16 param storage, each against a one-device twin."""
+    import jax
+
+    from deeplearning4j_tpu.parallel import (MeshLayout, ParallelWrapper,
+                                             make_mesh)
+
+    t_leg = time.perf_counter()
+    xs, ys = _char_batches(vocab, batch, seq)
+    single = _char_rnn_net(vocab, hidden, layers, dtype)
+    want = single.fit_on_device(xs, ys, steps=steps)
+    out = {"one_device_losses": [float(l) for l in want]}
+
+    for name, make, rtol in (
+            ("dp4", lambda n: ParallelWrapper(n, mesh=make_mesh(4)), dp_rtol),
+            ("dp2xfsdp2", lambda n: ParallelWrapper(n, layout=MeshLayout(
+                data=2, fsdp=2, params_dtype="bfloat16")), fsdp_rtol)):
+        net = _char_rnn_net(vocab, hidden, layers, dtype)
+        wrapper = make(net)
+        got = wrapper.fit_on_device(xs, ys, steps=steps)
+        ids = _device_ids(net.params)
+        check(len(ids) == 4, f"{name}: params live on devices {sorted(ids)}")
+        shard = wrapper.layout.staged_batch_sharding().shard_shape(xs.shape)
+        check(wrapper.workers == 4 and shard[1] * 4 == batch,
+              f"{name}: batch not split 4 ways: shard {shard} of {xs.shape}")
+        if name != "dp4":
+            leaves = jax.tree_util.tree_leaves(net.params)
+            check(any(l.addressable_shards[0].data.shape != l.shape
+                      for l in leaves), f"{name}: no param leaf is sharded")
+        check(np.all(np.isfinite(got)) and np.allclose(got, want, rtol=rtol),
+              f"{name}: losses {got} vs one-device twin {want}")
+        print(f"  {name}: losses {np.round(got, 4)} vs one device "
+              f"{np.round(want, 4)}; param devices {sorted(ids)}; "
+              f"batch shard {shard}")
+        out[name] = [float(l) for l in got]
+    # GSPMD cannot partition a Mosaic kernel: on the mesh every site must
+    # have said so and taken its XLA path (the one-device twin did not)
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    on_mesh = [r for r in ks.selection_log() if r["ctx"].get("partitioned")]
+    check(on_mesh and all(r["variant"] in _REFERENCE_VARIANTS
+                          for r in on_mesh),
+          f"a partitioned program selected a Mosaic kernel: {on_mesh}")
+    out["on_mesh_selection"] = sorted(
+        {(r["site"], r["variant"], r["reason"]) for r in on_mesh})
+    print(f"  selections inside partitioned programs: "
+          f"{out['on_mesh_selection']}")
+    _check_admission("E")
+    out["leg_seconds"] = round(time.perf_counter() - t_leg, 1)
+    return out
+
+
+# --------------------------------------------------------------------- main
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--legs", default=",".join(LEGS),
+                    help="comma list out of A,B,C,D,E (default: all)")
+    legs = [l.strip().upper() for l in ap.parse_args(argv).legs.split(",")
+            if l.strip()]
+    unknown = [l for l in legs if l not in LEGS]
+    if unknown:
+        ap.error(f"unknown legs {unknown}")
+    t_start = time.perf_counter()
+    info = gate(require_tpu=True)
+    import jax
+
+    from deeplearning4j_tpu.models.resnet import resnet50_conf
+
+    probe_dispatch()
+    results, failed, state = {}, [], {}
+
+    def run(leg, fn):
+        if leg not in legs:
+            return
+        print(f"== leg {leg}")
+        try:
+            with counting() as compiled:
+                results[leg] = fn()
+        except Exception:  # noqa: BLE001 - name the leg, run the rest, exit 1
+            traceback.print_exc()
+            failed.append(leg)
+            print(f"== leg {leg} FAILED")
+            return
+        print(f"== leg {leg} passed: {json.dumps(results[leg])} "
+              f"compile={json.dumps(compiled)}")
+
+    def leg_b():
+        info_b, state["net"], state["batches"] = leg_b_kernel_route()
+        return info_b
+
+    def leg_c():
+        if "net" not in state:  # B skipped or failed: train a net for C alone
+            state["net"] = _char_rnn_net(96, 512, 2, "bfloat16")
+            state["batches"] = _char_batches(96, 64, 256)
+            state["net"].fit_on_device(*state["batches"], steps=3)
+        return leg_c_server(state["net"], state["batches"], steps=3)
+
+    run("D", leg_d_kernels)
+    run("A", lambda: leg_a_trainer(resnet50_conf(dtype="bfloat16"), 224, 1000,
+                                   128))
+    run("B", leg_b)
+    run("C", leg_c)
+    if "E" in legs and len(jax.devices()) < 4:
+        print(f"== leg E not run: {len(jax.devices())} device(s), needs 4")
+        results["E"] = "not run"
+    else:
+        run("E", leg_e_four_chips)
+
+    print(f"compile totals: {json.dumps(monitors().snapshot())}")
+    print(f"compile manager: {json.dumps(_admission_state())}")
+    passed = [l for l in legs if l in results and results[l] != "not run"]
+    print(f"legs passed: {passed}  not run: "
+          f"{[l for l in LEGS if l not in passed and l not in failed]}  "
+          f"failed: {failed}  wall {time.perf_counter() - t_start:.1f}s")
+    if failed:
+        print(f"chip_smoke: FAILED legs {failed}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": info}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

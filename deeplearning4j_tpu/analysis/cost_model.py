@@ -30,11 +30,13 @@ Counting conventions (deliberately simple, deliberately stated):
 Collectives (``psum``/``all_gather``/``ppermute``/...) are tallied
 separately — count and payload bytes per step — feeding the DT207 check.
 
-Roofline knobs: ``DL4JTPU_PEAK_FLOPS`` (peak FLOP/s), ``DL4JTPU_HBM_GBPS``
-(HBM GB/s) and ``DL4JTPU_ICI_GBPS`` (interconnect GB/s per chip); defaults
-model one TPU v4 core (275 Tf/s bf16, 1228 GB/s HBM, 300 GB/s aggregate
-ICI). The interconnect term makes ``predicted_step_seconds`` cover
-compute-, memory- AND communication-bound steps: the per-step collective
+Roofline: the peaks come from :data:`DEVICE_PEAKS`, the one table of
+published per-chip peaks keyed by jax's ``device_kind`` (see
+:func:`device_peaks`); ``DL4JTPU_PEAK_FLOPS`` (peak FLOP/s),
+``DL4JTPU_HBM_GBPS`` (HBM GB/s) and ``DL4JTPU_ICI_GBPS`` (interconnect GB/s
+per chip) override single entries. The interconnect term makes
+``predicted_step_seconds`` cover compute-, memory- AND communication-bound
+steps: the per-step collective
 bytes (the jaxpr census here, plus the sharding-flow predicted census when
 a layout is analyzed — see ``analysis/shard_flow.py``) divide by the ICI
 bandwidth, and ``bound`` reports which of the three ceilings wins.
@@ -49,6 +51,8 @@ __all__ = [
     "PEAK_FLOPS_ENV",
     "HBM_GBPS_ENV",
     "ICI_GBPS_ENV",
+    "DEVICE_PEAKS",
+    "device_peaks",
     "roofline_params",
     "apply_roofline",
     "jaxpr_cost",
@@ -59,9 +63,23 @@ __all__ = [
 PEAK_FLOPS_ENV = "DL4JTPU_PEAK_FLOPS"
 HBM_GBPS_ENV = "DL4JTPU_HBM_GBPS"
 ICI_GBPS_ENV = "DL4JTPU_ICI_GBPS"
-DEFAULT_PEAK_FLOPS = 2.75e14  # one TPU v4 core, bf16 MXU
-DEFAULT_HBM_GBPS = 1228.0  # TPU v4 HBM2 bandwidth
-DEFAULT_ICI_GBPS = 300.0  # TPU v4 aggregate ICI per chip (6 links)
+
+# THE peaks table: published per-chip peaks keyed by ``device_kind`` exactly
+# as jax reports it. Every roofline bound, kernel auto-score and MFU figure
+# in the package reads this one table. A TPU whose kind is not listed is an
+# error, never a default — add its row with its source.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {  # v5e; device_kind string as the chip reports it
+        "peak_flops": 1.97e14,  # bf16 MXU
+        "hbm_gbps": 819.0,
+        "ici_gbps": 200.0,  # 1,600 Gbit/s chip-to-chip
+        "source": 'Google Cloud documentation, "TPU v5e": 197 TFLOP/s '
+                  'bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s interconnect',
+    },
+}
+# the chip this repo is measured on: off-chip analysis (static lint, the CPU
+# tests) models THIS row and says so (``assumed: True``)
+ASSUMED_DEVICE_KIND = "TPU v5 lite"
 
 # pure data movement: 0 FLOPs, bytes only
 _ZERO_FLOP = frozenset({
@@ -96,9 +114,32 @@ _COLLECTIVE_KINDS = {
 }
 
 
+def device_peaks() -> dict:
+    """The :data:`DEVICE_PEAKS` row for the attached device. On a TPU
+    backend the row is looked up by ``jax.devices()[0].device_kind`` and an
+    unknown kind raises; anywhere else the result is the
+    :data:`ASSUMED_DEVICE_KIND` row marked ``assumed: True`` — a modeled
+    target, not the machine the process runs on."""
+    import jax  # noqa: PLC0415
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return {**DEVICE_PEAKS[ASSUMED_DEVICE_KIND],
+                "device_kind": ASSUMED_DEVICE_KIND, "assumed": True}
+    row = DEVICE_PEAKS.get(dev.device_kind)
+    if row is None:
+        raise KeyError(
+            f"no peaks for device_kind {dev.device_kind!r}: add its "
+            f"published peaks (with their source) to "
+            f"analysis.cost_model.DEVICE_PEAKS; known: {sorted(DEVICE_PEAKS)}")
+    return {**row, "device_kind": dev.device_kind, "assumed": False}
+
+
 def roofline_params() -> dict:
-    """The configured roofline: peak FLOP/s, HBM GB/s, and the ridge point
-    (FLOPs/byte above which a kernel is compute-bound)."""
+    """The roofline in force: peak FLOP/s, HBM GB/s, ICI GB/s and the ridge
+    point (FLOPs/byte above which a kernel is compute-bound), from
+    :func:`device_peaks` with the env overrides applied, plus which
+    ``device_kind`` row was used and whether it was ``assumed``."""
     def _env_float(name: str, default: float) -> float:
         raw = os.environ.get(name)
         if raw:
@@ -108,14 +149,17 @@ def roofline_params() -> dict:
                 pass
         return default
 
-    peak = _env_float(PEAK_FLOPS_ENV, DEFAULT_PEAK_FLOPS)
-    gbps = _env_float(HBM_GBPS_ENV, DEFAULT_HBM_GBPS)
-    ici = _env_float(ICI_GBPS_ENV, DEFAULT_ICI_GBPS)
+    row = device_peaks()
+    peak = _env_float(PEAK_FLOPS_ENV, row["peak_flops"])
+    gbps = _env_float(HBM_GBPS_ENV, row["hbm_gbps"])
+    ici = _env_float(ICI_GBPS_ENV, row["ici_gbps"])
     return {
         "peak_flops": peak,
         "hbm_gbps": gbps,
         "ici_gbps": ici,
         "ridge_flops_per_byte": peak / (gbps * 1e9),
+        "device_kind": row["device_kind"],
+        "assumed": row["assumed"],
     }
 
 
@@ -193,7 +237,7 @@ def subjaxprs(eqn) -> List[Tuple[Any, int]]:
     params for jaxpr-shaped values so new wrapper primitives (remat, custom
     derivatives, pjit) keep being walked without a registry update.
     """
-    from jax import core  # noqa: PLC0415
+    from jax.extend import core  # noqa: PLC0415
 
     def closed(j):
         if isinstance(j, core.ClosedJaxpr):

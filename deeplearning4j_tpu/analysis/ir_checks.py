@@ -60,7 +60,8 @@ DT203_FLOOR_BYTES = 32 << 20  # 32 MiB
 # DT205 default: warn when >30% of staged elements were padding
 DT205_THRESHOLD = 0.30
 
-_CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback"}
+_CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
+                   "debug_print"}
 
 
 def _fmt_bytes(n: float) -> str:
@@ -165,7 +166,7 @@ def _iter_leaf_eqns(closed):
     as a constant (:func:`_nested_const_invars` maps the positions), closing
     the DT204 per-jaxpr limitation PR 5 shipped with.
     """
-    from jax import core  # noqa: PLC0415
+    from jax.extend import core  # noqa: PLC0415
 
     stack = [(closed, frozenset())]
     seen = set()
@@ -245,7 +246,7 @@ def check_jaxpr_ir(closed_jaxpr, *, source: str = IR_SOURCE,
 
         # DT204: gather/scatter whose indices operand is a traced value
         if name == "gather" or name.startswith("scatter"):
-            from jax import core  # noqa: PLC0415
+            from jax.extend import core  # noqa: PLC0415
 
             idx = eqn.invars[1] if len(eqn.invars) > 1 else None
             traced = (idx is not None and not isinstance(idx, core.Literal)
@@ -633,8 +634,13 @@ def admission_check(jitted, compiled, args, *, kind: str = "aot") -> Tuple[
     # are spec-indistinguishable here (a ZeRO param shard and a batch shard
     # both read P('fsdp')), so invar gathers are treated as the documented
     # param cost and never fire DT300/DT303 — net.analyze_ir(layout=...)
-    # is the precise entry. Failures degrade silently: analysis must never
-    # break compilation.
+    # is the precise entry. A failing sub-analysis never breaks
+    # compilation, but it is reported: its error lands under
+    # cost["analysis_errors"][stage], which the compile manager counts.
+    def failed(stage: str, exc: Exception) -> None:
+        cost.setdefault("analysis_errors", {})[stage] = (
+            f"{type(exc).__name__}: {exc}"[:300])
+
     try:
         flat, _ = jax.tree_util.tree_flatten(args)
         mesh = None
@@ -665,15 +671,15 @@ def admission_check(jitted, compiled, args, *, kind: str = "aot") -> Tuple[
             apply_roofline(
                 cost, comm_bytes=cost["collectives"]["bytes"]
                 + cost["shard_flow"]["comm_bytes_per_step"])
-    except Exception:
-        pass
+    except Exception as e:
+        failed("shard_flow", e)
 
     # DT5xx numerics at admission: same jaxpr, one extra host-side walk.
     # No declared ranges/policy are available for an arbitrary executable,
     # so invars stay unknown — hazard rules only fire on evidence the
     # trace itself provides (literal clamps, structural softmax shape,
     # low-precision accumulation dtypes); net.analyze_ir is the seeded,
-    # policy-aware entry. Failures degrade silently like the DT3xx block.
+    # policy-aware entry. Failures are reported like the DT3xx block's.
     try:
         from .numerics import check_jaxpr_numerics  # noqa: PLC0415
 
@@ -681,16 +687,16 @@ def admission_check(jitted, compiled, args, *, kind: str = "aot") -> Tuple[
             closed, source=source)
         findings += num_findings
         cost["numerics"] = num_summary
-    except Exception:
-        pass
+    except Exception as e:
+        failed("numerics", e)
 
-    # DT202 at admission: the pjit eqn records the donation actually
+    # DT202 at admission: the jit eqn records the donation actually
     # requested; a requested donation with ZERO aliased bytes in the
     # compiler's own memory analysis was dropped wholesale
     try:
         eqn = closed.jaxpr.eqns[0] if closed.jaxpr.eqns else None
         donated_invars = (eqn.params.get("donated_invars", ())
-                          if eqn is not None and eqn.primitive.name == "pjit"
+                          if eqn is not None and eqn.primitive.name == "jit"
                           else ())
         n_donated = sum(1 for d in donated_invars if d)
         if n_donated:
@@ -707,6 +713,6 @@ def admission_check(jitted, compiled, args, *, kind: str = "aot") -> Tuple[
                     "compiled executable aliases 0 bytes: donation was "
                     "dropped — params/optimizer state are double-buffered",
                     file=source, context=kind))
-    except Exception:
-        pass
+    except Exception as e:
+        failed("donation_audit", e)
     return merge_findings(findings), cost

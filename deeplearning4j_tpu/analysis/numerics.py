@@ -214,7 +214,7 @@ _CMP = {"eq", "ne", "lt", "le", "gt", "ge", "and", "or", "not", "xor"}
 # carries its scalar combinator as params["jaxpr"] with coincidentally
 # matching arity and must be evaluated as a reduction, not inlined.
 _WRAPPERS = {
-    "pjit", "closed_call", "core_closed_call", "xla_call", "remat",
+    "jit", "closed_call", "core_closed_call", "xla_call", "remat",
     "remat2", "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
 }
@@ -222,11 +222,10 @@ _WRAPPERS = {
 
 def _wrapped_closed(eqn):
     """The 1:1-wrapped inner jaxpr of a pjit/remat/custom_*-style eqn."""
-    import jax  # noqa: PLC0415
+    from jax.extend import core  # noqa: PLC0415
 
     if eqn.primitive.name not in _WRAPPERS:
         return None
-    core = jax.core
     for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
         inner = eqn.params.get(key)
         if inner is None:
@@ -273,9 +272,9 @@ class _NumFlow:
         slot[0] += 1
 
     def _read(self, env, v) -> _Av:
-        import jax  # noqa: PLC0415
+        from jax.extend import core  # noqa: PLC0415
 
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, core.Literal):
             return self._const_av(v.val)
         av = env.get(id(v))
         if av is None:
@@ -739,7 +738,7 @@ class _NumFlow:
                body_outvars, trip: Optional[int], kind: str) -> None:
         if not self.record:
             return
-        import jax  # noqa: PLC0415
+        from jax.extend import core  # noqa: PLC0415
 
         if trip is not None and trip < self.carry_steps:
             return
@@ -748,7 +747,7 @@ class _NumFlow:
             if dt not in _LOW:
                 continue
             out_v = body_outvars[i]
-            if out_v is v or isinstance(out_v, jax.core.Literal):
+            if out_v is v or isinstance(out_v, core.Literal):
                 continue  # passthrough carry: no per-step rounding
             if self.params_dtype == dt \
                     and (carry_in[i].lineage & {"param", "opt"}):

@@ -254,7 +254,7 @@ class _Flow:
     def walk(self, closed, in_states: Sequence[_St], *, mult: int = 1,
              scope: str = "top", trip: int = 1,
              record: bool = True) -> List[_St]:
-        from jax import core  # noqa: PLC0415
+        from jax.extend import core  # noqa: PLC0415
 
         jaxpr = closed.jaxpr
         env: Dict[Any, _St] = {}
@@ -350,7 +350,7 @@ class _Flow:
     def _wrapped_jaxpr(eqn):
         """The single nested jaxpr of a 1:1 wrapper (pjit / remat /
         custom_jvp / custom_vjp / closed_call), or None."""
-        from jax import core  # noqa: PLC0415
+        from jax.extend import core  # noqa: PLC0415
 
         found = None
         for v in eqn.params.values():
@@ -792,34 +792,38 @@ class _Flow:
         collectives in the body — the walk models only the explicit ones
         (ppermute, psum, ...), whose payloads are the body's per-shard aval
         bytes: the same per-device convention the measured census counts.
-        At the boundary, an outer sharding axis that ``in_names`` does not
+        At the boundary, an outer sharding axis that ``in_specs`` does not
         carry on that dim forces an all-gather (manual axes absent from the
         spec require replicated inputs); outputs take their specs straight
-        from ``out_names``."""
-        from jax import core  # noqa: PLC0415
+        from ``out_specs``."""
+        from jax.extend import core  # noqa: PLC0415
 
         body = eqn.params.get("jaxpr")
         if isinstance(body, core.Jaxpr):
             body = core.ClosedJaxpr(body, ())
-        in_names = eqn.params.get("in_names")
-        out_names = eqn.params.get("out_names")
+        in_specs = eqn.params.get("in_specs")
+        out_specs = eqn.params.get("out_specs")
         kw = dict(mult=mult, scope=scope, trip=trip, record=record)
-        if (not isinstance(body, core.ClosedJaxpr) or in_names is None
-                or out_names is None
+        if (not isinstance(body, core.ClosedJaxpr) or in_specs is None
+                or out_specs is None
                 or len(body.jaxpr.invars) != len(eqn.invars)
                 or len(body.jaxpr.outvars) != len(eqn.outvars)):
             return [self._meet(eqn, read, i, **kw)
                     for i in range(len(eqn.outvars))]
 
-        def names_spec(names, ndim):
-            return tuple(tuple(str(a) for a in names.get(d, ()))
-                         for d in range(ndim))
+        def names_spec(pspec, ndim):
+            """A PartitionSpec as this walker's per-dim axis tuples."""
+            entries = tuple(pspec) + (None,) * (ndim - len(pspec))
+            return tuple(
+                () if e is None else
+                tuple(str(a) for a in (e if isinstance(e, tuple) else (e,)))
+                for e in entries[:ndim])
 
         inner_in = []
-        for v, iv, names in zip(eqn.invars, body.jaxpr.invars, in_names):
+        for v, iv, pspec in zip(eqn.invars, body.jaxpr.invars, in_specs):
             st = read(v)
             self._materialize(st, **kw)
-            want = names_spec(dict(names), len(st.spec))
+            want = names_spec(pspec, len(st.spec))
             need = {d: set(st.spec[d]) - set(want[d])
                     for d in range(len(st.spec))
                     if set(st.spec[d]) - set(want[d])}
@@ -838,9 +842,9 @@ class _Flow:
         finally:
             self._manual = prev_manual
         outs = []
-        for ov, names in zip(eqn.outvars, out_names):
+        for ov, pspec in zip(eqn.outvars, out_specs):
             oshape = tuple(getattr(ov.aval, "shape", ()) or ())
-            spec = names_spec(dict(names), len(oshape))
+            spec = names_spec(pspec, len(oshape))
             outs.append(_St(spec, _aval_bytes(ov.aval)))
         return outs
 
@@ -870,7 +874,7 @@ class _Flow:
             for st, out in zip(carry, outs)]
 
     def _scan(self, eqn, read, *, mult, scope, trip, record) -> List[_St]:
-        from jax import core  # noqa: PLC0415
+        from jax.extend import core  # noqa: PLC0415
 
         body = eqn.params["jaxpr"]
         if isinstance(body, core.Jaxpr):
@@ -925,7 +929,7 @@ class _Flow:
         return result
 
     def _while(self, eqn, read, *, mult, scope, trip, record) -> List[_St]:
-        from jax import core  # noqa: PLC0415
+        from jax.extend import core  # noqa: PLC0415
 
         def closed(j):
             return (core.ClosedJaxpr(j, ()) if isinstance(j, core.Jaxpr)
@@ -955,7 +959,7 @@ class _Flow:
                 for st, ov in zip(outs, eqn.outvars)]
 
     def _cond(self, eqn, read, *, mult, scope, trip, record) -> List[_St]:
-        from jax import core  # noqa: PLC0415
+        from jax.extend import core  # noqa: PLC0415
 
         branches = [core.ClosedJaxpr(b, ()) if isinstance(b, core.Jaxpr)
                     else b for b in eqn.params["branches"]]
@@ -1473,7 +1477,9 @@ def hlo_collective_census(hlo_text: str, layout=None) -> List[dict]:
     axis_groups = _axis_groups(mesh) if mesh is not None else []
     rows: Dict[Tuple[str, Tuple[str, ...]], dict] = {}
     seen_gathers: Dict[tuple, dict] = {}
-    for line in hlo_text.splitlines():
+    # long tuples carry /*index=5*/ position comments, whose "=" would end
+    # the result-shape match early and drop the fused gradient all-reduce
+    for line in re.sub(r"/\*.*?\*/", "", hlo_text).splitlines():
         m = _HLO_OP_RE.search(line)
         if not m:
             continue

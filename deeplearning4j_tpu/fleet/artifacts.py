@@ -5,11 +5,14 @@ A bundle is one JSON sidecar living NEXT TO the checkpoints (via
 topology) — the same key family as TUNED.json, because the compiled
 program set is a function of exactly those three. It carries:
 
-- the **XLA persistent-cache dir pointer** (``DL4JTPU_XLA_CACHE_DIR``):
-  a worker that points its own cache there re-reads compiled programs
-  from disk instead of recompiling them (when the backend persists them
-  — tiny CPU programs stay under jax's min-compile-time floor, which is
-  why the ready contract below does not depend on the disk cache);
+- the **XLA persistent-cache dir** the builder compiled into. A worker
+  never takes its cache dir from a bundle — the compile manager's one
+  resolver places it (``JAX_COMPILATION_CACHE_DIR``, else
+  ``<repo>/.jax_cache``), the same place for every process of a host —
+  the record only says whether the worker will find the builder's
+  programs on disk (tiny CPU programs stay under jax's
+  min-compile-time floor anyway, which is why the ready contract below
+  does not depend on the disk cache);
 - **kernel selections**: pinned site→variant overrides plus the
   KERNEL_CALIBRATION.json ratio snapshot, so the worker's auto scoring
   applies the same measured discounts;
@@ -184,30 +187,27 @@ def load_bundle(store_or_dir, net=None, *,
     return hits[0]
 
 
-def install_bundle(bundle: dict, *, set_env: bool = True) -> dict:
+def install_bundle(bundle: dict) -> dict:
     """Apply a bundle inside a FRESH worker, before first traffic.
 
-    Order matters: the XLA cache dir must be pointed before the first
-    jax compile, the calibration/tuned state before ``register()`` runs
+    Order matters: the XLA cache must be placed before the first jax
+    compile, the calibration/tuned state before ``register()`` runs
     ``auto_apply``. Returns a report of what was installed plus the
     bundle's warmup spec (the worker drives ``InferenceService.warmup``
     from it, then arms the compile counter and reports ready).
     """
     from ..ops import kernel_select as _ks  # noqa: PLC0415
-    from ..runtime.compile_manager import (CACHE_DIR_ENV,  # noqa: PLC0415
-                                           enable_persistent_cache)
+    from ..runtime.compile_manager import (  # noqa: PLC0415
+        resolve_persistent_cache)
     from ..tune import store as _tuned  # noqa: PLC0415
 
     report = {"xla_cache": False, "calibration": False,
               "site_overrides": 0, "tuned": False}
 
-    cache_dir = bundle.get("xla_cache_dir")
-    if cache_dir:
-        if set_env and not os.environ.get(CACHE_DIR_ENV):
-            # deliberately unscoped: the cache dir must outlive this call
-            # for the whole worker process (EnvScope would restore it)
-            os.environ[CACHE_DIR_ENV] = str(cache_dir)  # dl4jtpu: ignore[DT403]
-        report["xla_cache"] = enable_persistent_cache(str(cache_dir))
+    # True when this worker's cache is the directory the builder compiled
+    # into, i.e. its programs will be found on disk
+    report["xla_cache"] = (
+        resolve_persistent_cache() == bundle.get("xla_cache_dir"))
 
     kernel = bundle.get("kernel") or {}
     cal = kernel.get("calibration") or {}

@@ -1,5 +1,15 @@
 """Fleet router: spawn, supervise and front N serving workers.
 
+**Fleet workers are CPU processes today.** A chip belongs to one process at
+a time: a parent that has touched jax holds it, and a child that reached
+for it would fail or hang. Workers therefore spawn with ``force_cpu=True``
+(``utils.subproc.forced_cpu_env``) and every fleet figure — ``bench_fleet``,
+``bench_history``, the check.sh gates — is a host-side CPU number, never a
+device number. Serving from a chip is one process per chip
+(``InferenceService``; ``chip_smoke.py`` leg C drives it), which a
+deployment reaches by passing its own ``spawn_env`` with per-worker
+accelerator visibility.
+
 The router is deliberately thin — it never imports the model, never
 touches jax. It owns three loops:
 

@@ -101,6 +101,17 @@ class ComputationGraph:
         self._grad_stats_step = None
         self._telemetry_step = None
 
+    def _kernel_scoped(self, fn):
+        """``fn`` traced with kernel selection told whether GSPMD will
+        partition the program: a net living on a multi-device layout cannot
+        run Mosaic kernels outside a shard_map (ops.kernel_select
+        .partitioned_program). AOT programs get the same scope from the
+        compile manager, by their argument shardings."""
+        from ...ops import kernel_select
+
+        return kernel_select.scoped_for_layout(
+            fn, getattr(self, "_mesh_layout", None))
+
     def _step_callable(self, variant: str = "plain"):
         """Per-batch jitted step via the process-wide compile manager (one
         bounded LRU across every net — see MultiLayerNetwork._step_callable)."""
@@ -330,7 +341,7 @@ class ComputationGraph:
 
         donate = ((0, 1, 2) if jax.default_backend() != "cpu"
                   and donation_enabled() else ())
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(self._kernel_scoped(step), donate_argnums=donate)
 
     # ------------------------------------------------- on-device multi-step
     def _build_multi_step(self, steps_cap: int, with_masks: bool = False,
@@ -884,7 +895,7 @@ class ComputationGraph:
             new_rnn = jax.lax.stop_gradient(new_rnn)
             return new_params, new_opt, new_state, new_rnn, loss
 
-        return jax.jit(step)
+        return jax.jit(self._kernel_scoped(step))
 
     def _fit_tbptt(self, mds) -> None:
         # TBPTT bypasses the grad-stats step; drop stale grads (see MLN note).
@@ -1134,8 +1145,12 @@ class ComputationGraph:
             ComputationGraphConfiguration.from_dict(self.conf.to_dict())
         )
         if self.params is not None:
-            other.init(params=jax.tree_util.tree_map(lambda a: a, self.params))
-            other.state = jax.tree_util.tree_map(lambda a: a, self.state)
-            other.opt_state = jax.tree_util.tree_map(lambda a: a, self.opt_state)
+            # real copies, not shared buffers: the train steps donate
+            # params/opt-state/state on accelerators, so a clone that
+            # aliased them would read "Array has been deleted" after the
+            # original's next step (early stopping's best-model saver)
+            other.init(params=jax.tree_util.tree_map(jnp.copy, self.params))
+            other.state = jax.tree_util.tree_map(jnp.copy, self.state)
+            other.opt_state = jax.tree_util.tree_map(jnp.copy, self.opt_state)
             other.iteration = self.iteration
         return other

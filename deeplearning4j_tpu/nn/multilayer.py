@@ -188,6 +188,17 @@ class MultiLayerNetwork:
         self._grad_stats_step = None
         self._telemetry_step = None
 
+    def _kernel_scoped(self, fn):
+        """``fn`` traced with kernel selection told whether GSPMD will
+        partition the program: a net living on a multi-device layout cannot
+        run Mosaic kernels outside a shard_map (ops.kernel_select
+        .partitioned_program). AOT programs get the same scope from the
+        compile manager, by their argument shardings."""
+        from ..ops import kernel_select
+
+        return kernel_select.scoped_for_layout(
+            fn, getattr(self, "_mesh_layout", None))
+
     def _step_callable(self, variant: str = "plain"):
         """The per-batch jitted step, deduplicated through the process-wide
         compile manager (one LRU holds every executable of every net, so
@@ -405,7 +416,7 @@ class MultiLayerNetwork:
 
         donate = ((0, 1, 2) if jax.default_backend() != "cpu"
                   and donation_enabled() else ())
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(self._kernel_scoped(step), donate_argnums=donate)
 
     # ------------------------------------------------- on-device multi-step
     def _build_multi_step(self, steps_cap: int, with_masks: bool = False,
@@ -415,11 +426,11 @@ class MultiLayerNetwork:
         (stacked ``[K, B, ...]``), cycling ``i % n_batches``.
 
         The reference's fit loop dispatches per minibatch
-        (MultiLayerNetwork.fit:917) — on TPU that pays a host round-trip per
-        step, which over a tunnel/network-attached device costs more than the
-        step itself. The loop keeps everything on-chip; per-step RNG uses the
-        same split chain as sequential ``_fit_batch``, so results are
-        bit-identical to per-step dispatch.
+        (MultiLayerNetwork.fit:917) — on TPU that pays a host dispatch per
+        step (~0.6 ms round trip measured on the v5e, PERF.md), which a
+        short step cannot hide. The loop keeps everything on-chip; per-step
+        RNG uses the same split chain as sequential ``_fit_batch``, so
+        results are bit-identical to per-step dispatch.
 
         Recompile elimination: the step count and the real staged-batch
         count are DEVICE scalars (``n_steps``/``n_batches``), not trace-time
@@ -926,7 +937,7 @@ class MultiLayerNetwork:
             new_rnn = jax.lax.stop_gradient(new_rnn)
             return new_params, new_opt, new_state, new_rnn, loss
 
-        return jax.jit(step)
+        return jax.jit(self._kernel_scoped(step))
 
     def _fit_tbptt(self, ds) -> None:
         """Truncated BPTT over time segments (reference: doTruncatedBPTT:1080).
@@ -1183,8 +1194,12 @@ class MultiLayerNetwork:
             MultiLayerConfiguration.from_dict(self.conf.to_dict())
         )
         if self.params is not None:
-            other.init(params=jax.tree_util.tree_map(lambda a: a, self.params))
-            other.state = jax.tree_util.tree_map(lambda a: a, self.state)
-            other.opt_state = jax.tree_util.tree_map(lambda a: a, self.opt_state)
+            # real copies, not shared buffers: the train steps donate
+            # params/opt-state/state on accelerators, so a clone that
+            # aliased them would read "Array has been deleted" after the
+            # original's next step (early stopping's best-model saver)
+            other.init(params=jax.tree_util.tree_map(jnp.copy, self.params))
+            other.state = jax.tree_util.tree_map(jnp.copy, self.state)
+            other.opt_state = jax.tree_util.tree_map(jnp.copy, self.opt_state)
             other.iteration = self.iteration
         return other

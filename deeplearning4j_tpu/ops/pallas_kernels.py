@@ -71,6 +71,15 @@ def _acc_dtype(dt):
     return jnp.float32 if jnp.dtype(dt).itemsize < 4 else dt
 
 
+def _rows(*vectors):
+    """Peephole vectors enter and leave the kernels as (1, H) rows: Mosaic
+    lays 1-D bf16 vectors out in 256-element packed tiles, and an (H,)
+    operand with H=128 fails to lower ("offset not aligned to sublanes" —
+    seen on the v5e at bf16 B=16 H=128 while H=512 compiled). A 2-D row
+    broadcasts against [B, H] in any dtype."""
+    return tuple(v.reshape(1, -1) for v in vectors)
+
+
 def supported_lstm_activations(act: str, gate: str) -> bool:
     return act in _ACT and gate in _ACT
 
@@ -137,9 +146,9 @@ def _bwd_kernel(dact, dgate, a_ref, f_ref, o_ref, i_ref, cact_ref, cprev_ref,
     drw_out[:] = jnp.dot(
         hprev_ref[:].T, dzx, preferred_element_type=_acc_dtype(dzx.dtype)
     ).astype(dzx.dtype)
-    dpf_out[:] = jnp.sum(df * c_prev, axis=0)
-    dpi_out[:] = jnp.sum(di * c_prev, axis=0)
-    dpo_out[:] = jnp.sum(do * c, axis=0)
+    dpf_out[:] = jnp.sum(df * c_prev, axis=0, keepdims=True)
+    dpi_out[:] = jnp.sum(di * c_prev, axis=0, keepdims=True)
+    dpo_out[:] = jnp.sum(do * c, axis=0, keepdims=True)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
@@ -169,7 +178,7 @@ def _cell_fwd_impl(zx, h_prev, c_prev, RW, pF, pI, pO, act_name, gate_name):
         kernel,
         out_shape=tuple(shapes),
         interpret=_interpret(),
-    )(zx, h_prev, c_prev, RW, pF, pI, pO)
+    )(zx, h_prev, c_prev, RW, *_rows(pF, pI, pO))
 
 
 def _cell_fwd(zx, h_prev, c_prev, RW, pF, pI, pO, act_name, gate_name):
@@ -194,16 +203,17 @@ def _cell_bwd(act_name, gate_name, residuals, grads):
         jax.ShapeDtypeStruct((B, H), dt),       # dh_prev
         jax.ShapeDtypeStruct((B, H), dt),       # dc_prev
         jax.ShapeDtypeStruct((H, 4 * H), dt),   # dRW
-        jax.ShapeDtypeStruct((H,), dt),         # dpF
-        jax.ShapeDtypeStruct((H,), dt),         # dpI
-        jax.ShapeDtypeStruct((H,), dt),         # dpO
+        jax.ShapeDtypeStruct((1, H), dt),       # dpF
+        jax.ShapeDtypeStruct((1, H), dt),       # dpI
+        jax.ShapeDtypeStruct((1, H), dt),       # dpO
     )
     kernel = functools.partial(_bwd_kernel, dact, dgate)
-    return pl.pallas_call(
+    *grads, dpF, dpI, dpO = pl.pallas_call(
         kernel,
         out_shape=out_shape,
         interpret=_interpret(),
-    )(a, f, o, i, cact, c_prev, c, h_prev, RW, pF, pI, pO, dh, dc)
+    )(a, f, o, i, cact, c_prev, c, h_prev, RW, *_rows(pF, pI, pO), dh, dc)
+    return (*grads, dpF[0], dpI[0], dpO[0])
 
 
 fused_lstm_cell.defvjp(_cell_fwd, _cell_bwd)
@@ -426,17 +436,17 @@ def _seq_bwd_kernel(act, dact, dgate, T,
     dc_scr[:] = dc_tot * f + df * pF + di * pI
     f32 = drw_scr.dtype
     drw_scr[:] += jnp.dot(h_prev.T, dzx, preferred_element_type=f32)
-    dpf_scr[:] += jnp.sum(df * c_prev, axis=0, dtype=f32)[None]
-    dpi_scr[:] += jnp.sum(di * c_prev, axis=0, dtype=f32)[None]
-    dpo_scr[:] += jnp.sum(do * c, axis=0, dtype=f32)[None]
+    dpf_scr[:] += jnp.sum(df * c_prev, axis=0, dtype=f32, keepdims=True)
+    dpi_scr[:] += jnp.sum(di * c_prev, axis=0, dtype=f32, keepdims=True)
+    dpo_scr[:] += jnp.sum(do * c, axis=0, dtype=f32, keepdims=True)
     # constant-index outputs: last (t==0) write carries the full sums
     dt = dzx.dtype
     dh0_out[:] = dh_scr[:]
     dc0_out[:] = dc_scr[:]
     drw_out[:] = drw_scr[:].astype(dt)
-    dpf_out[:] = dpf_scr[0].astype(dt)
-    dpi_out[:] = dpi_scr[0].astype(dt)
-    dpo_out[:] = dpo_scr[0].astype(dt)
+    dpf_out[:] = dpf_scr[:].astype(dt)
+    dpi_out[:] = dpi_scr[:].astype(dt)
+    dpo_out[:] = dpo_scr[:].astype(dt)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
@@ -501,11 +511,11 @@ def _seq_lean_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name):
         pl.BlockSpec((B, H), const),
         pl.BlockSpec((B, H), const),
         pl.BlockSpec((H, H4), const),
-        pl.BlockSpec((H,), lambda t: (0,)),
-        pl.BlockSpec((H,), lambda t: (0,)),
-        pl.BlockSpec((H,), lambda t: (0,)),
+        pl.BlockSpec((1, H), lambda t: (0, 0)),
+        pl.BlockSpec((1, H), lambda t: (0, 0)),
+        pl.BlockSpec((1, H), lambda t: (0, 0)),
     ]
-    args += [h0, c0, RW, pF, pI, pO]
+    args += [h0, c0, RW, *_rows(pF, pI, pO)]
     return pl.pallas_call(
         functools.partial(_seq_lean_kernel, act, gate, mask is not None),
         grid=(T,),
@@ -551,9 +561,9 @@ def _seq_fwd_impl(zx, h0, c0, RW, pF, pI, pO, act_name, gate_name):
             pl.BlockSpec((B, H), const3),
             pl.BlockSpec((B, H), const3),
             pl.BlockSpec((H, H4), const3),
-            pl.BlockSpec((H,), lambda t: (0,)),
-            pl.BlockSpec((H,), lambda t: (0,)),
-            pl.BlockSpec((H,), lambda t: (0,)),
+            pl.BlockSpec((1, H), lambda t: (0, 0)),
+            pl.BlockSpec((1, H), lambda t: (0, 0)),
+            pl.BlockSpec((1, H), lambda t: (0, 0)),
         ],
         out_specs=(
             seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H),
@@ -564,7 +574,7 @@ def _seq_fwd_impl(zx, h0, c0, RW, pF, pI, pO, act_name, gate_name):
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
         interpret=_interpret(),
-    )(zx, h0, c0, RW, pF, pI, pO)
+    )(zx, h0, c0, RW, *_rows(pF, pI, pO))
 
 
 def _seq_fwd(zx, h0, c0, RW, pF, pI, pO, act_name, gate_name):
@@ -596,9 +606,9 @@ def _seq_bwd(act_name, gate_name, residuals, grads):
         jax.ShapeDtypeStruct((B, H), dt),         # dh0
         jax.ShapeDtypeStruct((B, H), dt),         # dc0
         jax.ShapeDtypeStruct((H, 4 * H), dt),     # dRW
-        jax.ShapeDtypeStruct((H,), dt),           # dpF
-        jax.ShapeDtypeStruct((H,), dt),           # dpI
-        jax.ShapeDtypeStruct((H,), dt),           # dpO
+        jax.ShapeDtypeStruct((1, H), dt),         # dpF
+        jax.ShapeDtypeStruct((1, H), dt),         # dpI
+        jax.ShapeDtypeStruct((1, H), dt),         # dpO
     )
     dzx, dh0, dc0, dRW, dpF, dpI, dpO = pl.pallas_call(
         functools.partial(_seq_bwd_kernel, act, dact, dgate, T),
@@ -611,9 +621,9 @@ def _seq_bwd(act_name, gate_name, residuals, grads):
             seq(prev),                      # c_{t-1} (from c)
             seq(prev),                      # h_{t-1} (from ys)
             pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
             pl.BlockSpec((B, H), const),    # h0
             pl.BlockSpec((B, H), const),    # c0
         ],
@@ -622,9 +632,9 @@ def _seq_bwd(act_name, gate_name, residuals, grads):
             pl.BlockSpec((B, H), const),
             pl.BlockSpec((B, H), const),
             pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
         ),
         out_shape=out_shape,
         scratch_shapes=[
@@ -634,8 +644,8 @@ def _seq_bwd(act_name, gate_name, residuals, grads):
             pltpu.VMEM((1, H), jnp.float32),
         ],
         interpret=_interpret(),
-    )(dys, dhT, dcT, a, f, o, i, c, ys, RW, pF, pI, pO, h0, c0)
-    return dzx, dh0, dc0, dRW, dpF, dpI, dpO
+    )(dys, dhT, dcT, a, f, o, i, c, ys, RW, *_rows(pF, pI, pO), h0, c0)
+    return dzx, dh0, dc0, dRW, dpF[0], dpI[0], dpO[0]
 
 
 fused_lstm_sequence.defvjp(_seq_fwd, _seq_bwd)
@@ -727,16 +737,16 @@ def _seq_bwd_kernel_masked(act, dact, dgate, T,
     dc_scr[:] = dc_tot * f + df * pF + di * pI + (1.0 - m) * dc_t
     f32 = drw_scr.dtype
     drw_scr[:] += jnp.dot(h_prev.T, dzx, preferred_element_type=f32)
-    dpf_scr[:] += jnp.sum(df * c_prev, axis=0, dtype=f32)[None]
-    dpi_scr[:] += jnp.sum(di * c_prev, axis=0, dtype=f32)[None]
-    dpo_scr[:] += jnp.sum(do * c_tilde, axis=0, dtype=f32)[None]
+    dpf_scr[:] += jnp.sum(df * c_prev, axis=0, dtype=f32, keepdims=True)
+    dpi_scr[:] += jnp.sum(di * c_prev, axis=0, dtype=f32, keepdims=True)
+    dpo_scr[:] += jnp.sum(do * c_tilde, axis=0, dtype=f32, keepdims=True)
     dt = dzx.dtype
     dh0_out[:] = dh_scr[:]
     dc0_out[:] = dc_scr[:]
     drw_out[:] = drw_scr[:].astype(dt)
-    dpf_out[:] = dpf_scr[0].astype(dt)
-    dpi_out[:] = dpi_scr[0].astype(dt)
-    dpo_out[:] = dpo_scr[0].astype(dt)
+    dpf_out[:] = dpf_scr[:].astype(dt)
+    dpi_out[:] = dpi_scr[:].astype(dt)
+    dpo_out[:] = dpo_scr[:].astype(dt)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
@@ -778,9 +788,9 @@ def _seq_masked_fwd_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name,
             pl.BlockSpec((B, H), const),
             pl.BlockSpec((B, H), const),
             pl.BlockSpec((H, H4), const),
-            pl.BlockSpec((H,), lambda t: (0,)),
-            pl.BlockSpec((H,), lambda t: (0,)),
-            pl.BlockSpec((H,), lambda t: (0,)),
+            pl.BlockSpec((1, H), lambda t: (0, 0)),
+            pl.BlockSpec((1, H), lambda t: (0, 0)),
+            pl.BlockSpec((1, H), lambda t: (0, 0)),
         ],
         out_specs=(
             seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H),
@@ -791,7 +801,7 @@ def _seq_masked_fwd_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
         interpret=_interpret(),
-    )(zx, mask.astype(dt), h0, c0, RW, pF, pI, pO)
+    )(zx, mask.astype(dt), h0, c0, RW, *_rows(pF, pI, pO))
 
 
 def _seq_masked_fwd(zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name):
@@ -821,9 +831,9 @@ def _seq_masked_bwd(act_name, gate_name, residuals, grads):
         jax.ShapeDtypeStruct((B, H), dt),
         jax.ShapeDtypeStruct((B, H), dt),
         jax.ShapeDtypeStruct((H, 4 * H), dt),
-        jax.ShapeDtypeStruct((H,), dt),
-        jax.ShapeDtypeStruct((H,), dt),
-        jax.ShapeDtypeStruct((H,), dt),
+        jax.ShapeDtypeStruct((1, H), dt),
+        jax.ShapeDtypeStruct((1, H), dt),
+        jax.ShapeDtypeStruct((1, H), dt),
     )
     dzx, dh0, dc0, dRW, dpF, dpI, dpO = pl.pallas_call(
         functools.partial(_seq_bwd_kernel_masked, act, dact, dgate, T),
@@ -839,9 +849,9 @@ def _seq_masked_bwd(act_name, gate_name, residuals, grads):
             seq(prev),
             seq(prev),
             pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
             pl.BlockSpec((B, H), const),
             pl.BlockSpec((B, H), const),
         ],
@@ -850,9 +860,9 @@ def _seq_masked_bwd(act_name, gate_name, residuals, grads):
             pl.BlockSpec((B, H), const),
             pl.BlockSpec((B, H), const),
             pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
-            pl.BlockSpec((H,), lambda k: (0,)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
+            pl.BlockSpec((1, H), lambda k: (0, 0)),
         ),
         out_shape=out_shape,
         scratch_shapes=[
@@ -863,8 +873,8 @@ def _seq_masked_bwd(act_name, gate_name, residuals, grads):
         ],
         interpret=_interpret(),
     )(dys, dhT, dcT, mask.astype(dt), a, f, o, i, c, ys,
-      RW, pF, pI, pO, h0, c0)
-    return dzx, None, dh0, dc0, dRW, dpF, dpI, dpO
+      RW, *_rows(pF, pI, pO), h0, c0)
+    return dzx, None, dh0, dc0, dRW, dpF[0], dpI[0], dpO[0]
 
 
 fused_lstm_sequence_masked.defvjp(_seq_masked_fwd, _seq_masked_bwd)
@@ -883,12 +893,27 @@ fused_lstm_sequence_masked.defvjp(_seq_masked_fwd, _seq_masked_bwd)
 # bandwidth-bound (it always is — pure elementwise/reduce chains).
 
 _SXENT_TILE_ROWS = 1024
+# VMEM the backward's pipelined blocks may take: 2 in + 2 out (tile, C)
+# blocks, double-buffered, modeled at f32 with C padded to the 128-lane
+# tile — half of the 16 MiB default scoped limit, the rest is left to the
+# in-kernel f32 temporaries
+_SXENT_BLOCK_BUDGET_BYTES = 8 * 1024 * 1024
+
+
+def _sxent_tile(rows: int, C: int) -> int:
+    """Rows per grid step, sized from C so a wide head (ImageNet's 1000
+    classes) fits VMEM where a fixed 1024-row tile would need ~32 MB. A
+    tile below ``rows`` stays a multiple of 16 (bf16 sublane packing)."""
+    lanes = -(-C // 128) * 128
+    fit = _SXENT_BLOCK_BUDGET_BYTES // (8 * 4 * lanes)
+    tile = max(16, min(_SXENT_TILE_ROWS, fit // 16 * 16))
+    return min(tile, rows)
 
 
 def _sxent_specs(rows: int, C: int):
     from jax.experimental import pallas as pl  # noqa: PLC0415
 
-    tile = min(_SXENT_TILE_ROWS, rows)
+    tile = _sxent_tile(rows, C)
     grid = (pl.cdiv(rows, tile),)
     mat = pl.BlockSpec((tile, C), lambda i: (i, 0))
     col = pl.BlockSpec((tile, 1), lambda i: (i, 0))
@@ -998,16 +1023,17 @@ _ADAM_TILE_ROWS = 4096
 @jit_entry
 def _adam_kernel(b1, b2, eps, g_ref, m_ref, v_ref, sc_ref,
                  u_out, m_out, v_out):
-    g = g_ref[:]
-    dt = g.dtype
-    lr = sc_ref[0, 0].astype(dt)
-    bc1 = sc_ref[0, 1].astype(dt)  # 1 - b1**t
-    bc2 = sc_ref[0, 2].astype(dt)  # 1 - b2**t
-    m = b1 * m_ref[:] + (1.0 - b1) * g
-    v = b2 * v_ref[:] + (1.0 - b2) * g * g
-    u_out[:] = -lr * (m / bc1) / (jnp.sqrt(v / bc2) + eps)
-    m_out[:] = m
-    v_out[:] = v
+    # math at the scalars' dtype (>= f32): Mosaic has no bf16 scalar
+    # arithmetic ("failed to legalize arith.subf (bf16, bf16)" on the v5e),
+    # and the optimizer update is the f32 island of a bf16 step anyway
+    cdt = sc_ref.dtype
+    g = g_ref[:].astype(cdt)
+    lr, bc1, bc2 = sc_ref[0], sc_ref[1], sc_ref[2]  # bcK = 1 - bK**t
+    m = b1 * m_ref[:].astype(cdt) + (1.0 - b1) * g
+    v = b2 * v_ref[:].astype(cdt) + (1.0 - b2) * g * g
+    u_out[:] = (-lr * (m / bc1) / (jnp.sqrt(v / bc2) + eps)).astype(u_out.dtype)
+    m_out[:] = m.astype(m_out.dtype)
+    v_out[:] = v.astype(v_out.dtype)
 
 
 def fused_adam_update(g, m, v, lr, bc1, bc2,
@@ -1019,6 +1045,7 @@ def fused_adam_update(g, m, v, lr, bc1, bc2,
     shape: the view is flattened, lane-padded, and row-tiled; padded slots
     compute a zero update and are sliced off."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
     shape, dt = g.shape, g.dtype
     n = g.size
@@ -1032,15 +1059,16 @@ def fused_adam_update(g, m, v, lr, bc1, bc2,
             a = jnp.concatenate([a, jnp.zeros((pad,), dt)])
         return a.reshape(rows, cols)
 
-    # traced scalars ride one (1, 3) array: lr, 1-b1^t, 1-b2^t (kept at
+    # traced scalars ride one (3,) array in SMEM — Mosaic reads scalars
+    # from scalar memory, not from a VMEM block: lr, 1-b1^t, 1-b2^t (kept at
     # >=f32 — f64 under the x64 test env so parity against optax holds)
     sdt = jnp.promote_types(dt, jnp.float32)
     scalars = jnp.stack([jnp.asarray(lr), jnp.asarray(bc1),
-                         jnp.asarray(bc2)]).astype(sdt).reshape(1, 3)
+                         jnp.asarray(bc2)]).astype(sdt)
     tile = min(_ADAM_TILE_ROWS, rows)
     grid = (pl.cdiv(rows, tile),)
     mat = pl.BlockSpec((tile, cols), lambda i: (i, 0))
-    sc = pl.BlockSpec((1, 3), lambda i: (0, 0))
+    sc = pl.BlockSpec(memory_space=pltpu.SMEM)
     u2, m2, v2 = pl.pallas_call(
         functools.partial(_adam_kernel, float(b1), float(b2), float(eps)),
         grid=grid,

@@ -648,6 +648,11 @@ class MeshLayout:
             net.opt_state = self.put_opt_state(net.opt_state)
         if jax.tree_util.tree_leaves(net.state):
             net.state = self.put_replicated(net.state)
+        if getattr(net, "_mesh_layout", None) is not self:
+            # programs traced for another placement must not be reused:
+            # kernel variants are chosen per placement (a Mosaic kernel
+            # picked for one device cannot be partitioned over this mesh)
+            net._invalidate_compiled()
         net._mesh_layout = self
         return self
 

@@ -124,7 +124,7 @@ def pipeline_apply(block_fn: Callable, stacked_params, microbatches, mesh,
     block_{P-1}(...block_0(x)) per microbatch, computed with the GPipe
     schedule. Differentiable end-to-end.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_stages = mesh.shape[axis]
@@ -180,7 +180,7 @@ def pipeline_apply(block_fn: Callable, stacked_params, microbatches, mesh,
         mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stacked_params, microbatches)
 
@@ -620,7 +620,7 @@ class PipelinedTrainer:
         specs (zero warm compiles: GSPMD must hand params back exactly
         where the next dispatch expects them)."""
         import optax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -683,7 +683,7 @@ class PipelinedTrainer:
             region, mesh=self.mesh,
             in_specs=(P("pipe"), P(None, batch_axes or None), P("pipe")),
             out_specs=P(batch_axes or None),
-            check_rep=False)
+            check_vma=False)
 
         layers = net.conf.layers
 

@@ -97,10 +97,7 @@ def ring_attention(q, k, v, mesh, seq_axis: str = "seq",
     """
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
-    try:
-        from jax import shard_map  # noqa: PLC0415
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map  # noqa: PLC0415
+    from jax import shard_map  # noqa: PLC0415
 
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     n_shards = mesh.shape[seq_axis]
@@ -156,10 +153,7 @@ def all_to_all_attention(q, k, v, mesh, seq_axis: str = "seq",
     heads ≥ devices and the full sequence fits per device."""
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
-    try:
-        from jax import shard_map  # noqa: PLC0415
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map  # noqa: PLC0415
+    from jax import shard_map  # noqa: PLC0415
 
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     n = mesh.shape[seq_axis]

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import kernel_select
 from .layout import MeshLayout
 from .mesh import make_mesh, global_put, global_put_local
 
@@ -308,7 +309,8 @@ class ParallelWrapper:
             return replica, rng, losses
 
         donate = (0, 1) if jax.default_backend() != "cpu" else ()
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(kernel_select.scoped_for_layout(run, self.layout),
+                       donate_argnums=donate)
 
     def _fit_on_device_periodic(self, xs, ys, steps, features_masks, labels_masks):
         if self._replica is None:
@@ -426,7 +428,10 @@ class ParallelWrapper:
         # vmap over the replica axis: every replica steps independently in one
         # XLA program; sharding over "data" keeps each on its own device.
         self._one_step = one_step  # pure, un-jitted: reused by the scanned loop
-        self._vstep = jax.jit(jax.vmap(one_step))
+        # replicas are stacked and sharded over the data axes: a partitioned
+        # program, where Mosaic kernels cannot run (ops.kernel_select)
+        self._vstep = jax.jit(kernel_select.scoped_for_layout(
+            jax.vmap(one_step), self.layout))
 
         avg_upd = self.average_updaters
 

@@ -26,9 +26,9 @@ from typing import Dict, List, Optional
 
 from .optimize.listeners import TrainingListener
 
-# Peak bf16 TFLOP/s per chip for MFU math. v5e ~197, v4 ~275, v5p ~459.
-# Overridable because the bench can run on anything from a dev VM to a pod.
-PEAK_BF16_TFLOPS = float(os.environ.get("DL4J_TPU_PEAK_BF16_TFLOPS", "197"))
+# env override of the attached chip's peak bf16 TFLOP/s for MFU math; unset,
+# the peak comes from the one table (analysis.cost_model.DEVICE_PEAKS)
+PEAK_BF16_TFLOPS_ENV = "DL4J_TPU_PEAK_BF16_TFLOPS"
 
 
 @contextlib.contextmanager
@@ -136,23 +136,26 @@ def compiled_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
     """
     try:
         compiled = jitted_fn.lower(*args, **kwargs).compile()
-        analyses = compiled.cost_analysis()
-        if analyses is None:
-            return None
-        # cost_analysis() is a dict on current jax, a per-device list on older.
-        if isinstance(analyses, (list, tuple)):
-            analyses = analyses[0] if analyses else None
-        if not analyses:
-            return None
-        flops = analyses.get("flops")
+        analysis = compiled.cost_analysis()  # a dict, or None if unsupported
+        flops = (analysis or {}).get("flops")
         return float(flops) if flops else None
     except Exception:
         return None
 
 
 def mfu(flops_per_step: float, step_time_s: float,
-        peak_tflops: float = PEAK_BF16_TFLOPS) -> float:
-    """Model FLOPs utilisation in percent."""
+        peak_tflops: Optional[float] = None) -> float:
+    """Model FLOPs utilisation in percent of the chip's peak: the
+    ``peak_tflops`` argument, else ``DL4J_TPU_PEAK_BF16_TFLOPS``, else the
+    peaks-table row of the attached device (an unknown TPU kind raises)."""
+    if peak_tflops is None:
+        raw = os.environ.get(PEAK_BF16_TFLOPS_ENV)
+        if raw:
+            peak_tflops = float(raw)
+        else:
+            from .analysis.cost_model import device_peaks  # noqa: PLC0415
+
+            peak_tflops = device_peaks()["peak_flops"] / 1e12
     if step_time_s <= 0 or peak_tflops <= 0:
         return 0.0
     return 100.0 * (flops_per_step / step_time_s) / (peak_tflops * 1e12)

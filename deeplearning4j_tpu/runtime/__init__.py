@@ -16,8 +16,9 @@ from .native_loader import (
 from .checkpoint import CheckpointCorruptError, CheckpointStore
 from .compile_manager import (
     CompileManager,
-    enable_persistent_cache,
     get_compile_manager,
+    persistent_cache_dir,
+    resolve_persistent_cache,
 )
 from .inference import canonicalize_input, fast_path_enabled
 from .resilience import (
@@ -40,12 +41,13 @@ __all__ = [
     "OnlineTrainer",
     "RetryPolicy",
     "canonicalize_input",
-    "enable_persistent_cache",
     "fast_path_enabled",
     "get_compile_manager",
     "get_online_trainers",
     "native_available",
     "native_csv_read",
     "native_idx_read",
+    "persistent_cache_dir",
     "resilience_stats",
+    "resolve_persistent_cache",
 ]

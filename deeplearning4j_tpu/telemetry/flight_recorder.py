@@ -80,7 +80,7 @@ CRASH = register_event_kind("crash")
 # table stays the human-readable inventory.
 for _kind in (
     # runtime/compile_manager.py, telemetry/session.py, analysis
-    "ir_finding",
+    "ir_finding", "admission_error",
     # nn kernel selection + tuned-config auto-apply
     "kernel_select", "tuned_config_applied",
     # serving/service.py

@@ -16,7 +16,7 @@ kernel overrides by hand. This package closes the loop:
   count is asserted zero so the search measures steady state.
 - :mod:`~deeplearning4j_tpu.tune.store` — winners persist as ``TUNED.json``
   keyed by (model-signature, backend, mesh topology) next to
-  ``DL4JTPU_XLA_CACHE_DIR``; ``fit``/``warmup``/``InferenceService.register``/
+  ``JAX_COMPILATION_CACHE_DIR``; ``fit``/``warmup``/``InferenceService.register``/
   ``OnlineTrainer`` auto-apply a matching entry at startup (explicit user
   settings always win).
 

@@ -242,12 +242,6 @@ def _register_builtins() -> None:
              cost_hint="compute", contexts=(),
              doc="sequence length at which attention switches to the "
                  "flash kernel"))
-    add(Knob("xla_persistent_cache", (True, False), True, "env",
-             env="DL4JTPU_XLA_CACHE_DIR",
-             cost_hint="host", contexts=(),
-             doc="False unsets DL4JTPU_XLA_CACHE_DIR for the scope "
-                 "(disables the on-disk executable cache); True keeps "
-                 "the user's configured dir"))
     for site in KERNEL_SITES:
         add(Knob(f"kernel_{site}", ("auto", "reference", "fused"), "auto",
                  "env", env="DL4JTPU_KERNELS",
@@ -272,9 +266,8 @@ def apply_config(config: Dict[str, object], scope: EnvScope) -> Dict[str, object
     """Apply every env-kind knob in ``config`` into ``scope`` and return
     the call-kind residue for the caller to thread as arguments.
 
-    Kernel-site knobs compose into one ``DL4JTPU_KERNELS`` write; the
-    ``xla_persistent_cache`` knob only ever *unsets* the cache dir (it has
-    no dir of its own to invent). Restoring ``scope`` undoes everything.
+    Kernel-site knobs compose into one ``DL4JTPU_KERNELS`` write.
+    Restoring ``scope`` undoes everything.
     """
     validate_config(config)
     call_args: Dict[str, object] = {}
@@ -287,10 +280,6 @@ def apply_config(config: Dict[str, object], scope: EnvScope) -> Dict[str, object
         if name.startswith("kernel_"):
             if value != "auto":
                 kernel_overrides[name[len("kernel_"):]] = value
-            continue
-        if name == "xla_persistent_cache":
-            if not value:
-                scope.set(knob.env, None)
             continue
         if name == "donation":
             scope.set(knob.env, "1" if value else "0")

@@ -503,7 +503,14 @@ def run_autotune(
             raise ValueError(
                 f"no workload for model={model!r} objective={objective!r}; "
                 f"available: {sorted(_WORKLOADS)}") from None
-    env_before = dict(os.environ)
+    def knob_env() -> dict:
+        # this package's namespaces only: jax mutates the environment on its
+        # own (importing its Mosaic GPU module sets CUDA_ROOT, backend init
+        # sets TPU_LIBRARY_PATH), which is not the autopilot leaking
+        return {k: v for k, v in os.environ.items()
+                if k.startswith(("DL4JTPU_", "DL4J_TPU_"))}
+
+    env_before = knob_env()
     t_start = time.monotonic()
     default = workload.default_config()
     candidates = [default]
@@ -518,10 +525,11 @@ def run_autotune(
         prune_factor=prune_factor, rungs=rungs, keep=keep,
         fidelities=fidelities, deadline=deadline, log=log)
     elapsed = time.monotonic() - t_start
-    env_ok = dict(os.environ) == env_before
+    env_after = knob_env()
+    env_ok = env_after == env_before
     if not env_ok:
-        changed = {k for k in set(env_before) | set(os.environ)
-                   if env_before.get(k) != os.environ.get(k)}
+        changed = {k for k in set(env_before) | set(env_after)
+                   if env_before.get(k) != env_after.get(k)}
         raise RuntimeError(
             "autopilot leaked process env state; changed vars: "
             f"{sorted(changed)}")

@@ -2,8 +2,9 @@
 
 Winners persist as ``TUNED.json`` keyed by ``(model-signature, backend,
 mesh topology)`` — the same partitioning the XLA persistent cache uses, so
-the file lives next to ``DL4JTPU_XLA_CACHE_DIR`` and a warm boot picks up
-both the compiled executables AND the knob settings that produced them.
+when the cache is placed from outside (``JAX_COMPILATION_CACHE_DIR``) the
+file lives next to it and a warm boot picks up both the compiled
+executables AND the knob settings that produced them.
 
 Auto-apply contract (the startup half of the loop):
 
@@ -56,8 +57,8 @@ TUNED_PATH_ENV = "DL4JTPU_TUNED_PATH"  # explicit override, mostly for tests
 
 
 def tuned_path() -> str:
-    """Resolve the store location: explicit env override, else next to the
-    XLA persistent cache, else the user cache dir."""
+    """Resolve the store location: explicit env override, else next to an
+    externally placed XLA persistent cache, else the user cache dir."""
     explicit = os.environ.get(TUNED_PATH_ENV)
     if explicit:
         return explicit

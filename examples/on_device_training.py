@@ -3,9 +3,9 @@
 The TPU-first answer to the reference's per-minibatch fit loop
 (MultiLayerNetwork.fit:917): `fit_on_device` stages K batches in HBM and
 `lax.scan`s the jitted train step over them, so the host dispatches once per
-LOOP instead of once per STEP. On a network-attached TPU each dispatch costs
-an RPC round-trip that can exceed the step itself (BASELINE.md methodology
-notes); on any TPU it removes the host from the hot path entirely. Numerics
+LOOP instead of once per STEP: the host leaves the hot path entirely (one
+dispatch is a ~0.6 ms round trip on the v5e — PERF.md — which a short step
+cannot hide). Numerics
 are bit-identical to per-step fit — same RNG split chain — which this
 example verifies, then shows the same API running data-parallel over the
 whole mesh via ParallelWrapper (gradient psums ride ICI *inside* the scan).

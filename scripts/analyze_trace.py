@@ -1,9 +1,8 @@
 """Summarize an xplane trace captured by profiler.trace / BENCH_TRACE_DIR.
 
-The VERDICT round-3 MFU task asks for a committed, trace-backed breakdown of
-the ResNet-50 step: what fraction of device time is convolution vs BN-style
-elementwise vs copies/transposes, and whether any f32 leaks appear in the
-hot ops. This reads the .xplane.pb files jax.profiler writes (via
+A trace-backed breakdown of a training step: what fraction of device time
+is convolution vs BN-style elementwise vs copies/transposes, and whether any
+f32 leaks appear in the hot ops. This reads the .xplane.pb files jax.profiler writes (via
 jax.profiler.ProfileData — no TensorBoard needed), buckets device-plane
 events by op kind, and prints a ranked table plus bucket totals.
 
@@ -21,76 +20,7 @@ import re
 import sys
 from collections import defaultdict
 
-try:
-    # jax >= 0.5 ships the xplane reader directly
-    from jax.profiler import ProfileData
-except ImportError:  # older jax: fall back to TF's xplane protobuf below
-    ProfileData = None
-
-
-class _Event:
-    __slots__ = ("name", "duration_ns")
-
-    def __init__(self, name, duration_ns):
-        self.name = name
-        self.duration_ns = duration_ns
-
-
-class _Line:
-    __slots__ = ("name", "events")
-
-    def __init__(self, name, events):
-        self.name = name
-        self.events = events
-
-
-class _Plane:
-    __slots__ = ("name", "lines")
-
-    def __init__(self, name, lines):
-        self.name = name
-        self.lines = lines
-
-
-class _XSpaceData:
-    """Minimal ProfileData stand-in over TF's xplane_pb2 (same traversal
-    surface: .planes -> .lines -> .events with .name/.duration_ns)."""
-
-    def __init__(self, planes):
-        self.planes = planes
-
-    @classmethod
-    def from_file(cls, path):
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2  # lazy: TF import is slow
-
-        space = xplane_pb2.XSpace()
-        with open(path, "rb") as fh:
-            space.ParseFromString(fh.read())
-        planes = []
-        for plane in space.planes:
-            meta = plane.event_metadata
-            lines = []
-            for line in plane.lines:
-                events = []
-                for ev in line.events:
-                    m = meta.get(ev.metadata_id)
-                    name = (m.display_name or m.name) if m is not None else ""
-                    events.append(_Event(name, ev.duration_ps / 1e3))
-                lines.append(_Line(line.name, events))
-            planes.append(_Plane(plane.name, lines))
-        return cls(planes)
-
-
-def _load_profile(path):
-    if ProfileData is not None:
-        return ProfileData.from_file(path)
-    try:
-        return _XSpaceData.from_file(path)
-    except ImportError:
-        raise SystemExit(
-            "trace parsing needs jax.profiler.ProfileData (jax>=0.5) or "
-            "tensorflow's xplane protobuf; neither is importable"
-        )
+from jax.profiler import ProfileData
 
 # op-name → bucket. Order matters: first match wins.
 _BUCKETS = [
@@ -156,7 +86,7 @@ def analyze(trace_dir: str):
                     continue
                 op_time[event.name] += event.duration_ns
 
-    datas = [_load_profile(p) for p in files]
+    datas = [ProfileData.from_file(p) for p in files]
     for data in datas:
         for plane in data.planes:
             # device planes: "/device:TPU:0" or "TPU:0"-style; host

@@ -747,13 +747,17 @@ from deeplearning4j_tpu.tune.search import MlpFitWorkload
 tuned_path = os.path.join(
     tempfile.mkdtemp(prefix="dl4jtpu_check_tuned_"), "TUNED.json")
 with scoped_env(DL4JTPU_TUNED_PATH=tuned_path):
-    env_before = dict(os.environ)
+    def knob_env():  # jax mutates os.environ on its own (CUDA_ROOT, ...)
+        return {k: v for k, v in os.environ.items()
+                if k.startswith(("DL4JTPU_", "DL4J_TPU_"))}
+
+    env_before = knob_env()
     wl = MlpFitWorkload(hidden=64, features=32, classes=8)
     result = run_autotune(
         workload=wl, budget_s=45.0, rungs=1, fidelities=(2,),
         space={"train_batch": (16, 64, 128), "stage_window": (2, 4)},
         log=lambda m: print(f"  {m}"))
-    assert dict(os.environ) == env_before, "search leaked env state"
+    assert knob_env() == env_before, "search leaked env state"
     assert result.env_ok
     default, best = result.default.measured, result.best.measured
     assert default and default > 0, "default config was never measured"
@@ -1379,24 +1383,24 @@ if [[ "${1:-}" == "--lint" ]]; then
     exit 0
 fi
 
-echo "== bench regression gate (CPU fallback mode vs BENCH_BASELINE.json)"
+echo "== bench regression gate (explicit-CPU mlp mode vs BENCH_BASELINE.json)"
 # One real CPU bench run, gated against the persisted per-mode baselines —
 # a silent mlp-style throughput drop (r03 7888 -> r04 5508) now fails the
 # check. Re-anchor intentionally with: scripts/bench_gate.py --refresh.
 rm -f /tmp/_bench_gate_line.json
-BENCH_FORCE_CPU=1 BENCH_DEADLINE_S=240 python bench.py | tail -1 \
+BENCH_FORCE_CPU=1 python bench.py | tail -1 \
     > /tmp/_bench_gate_line.json
 python scripts/bench_gate.py /tmp/_bench_gate_line.json
 
 echo "== bench regression gate (serve mode vs BENCH_BASELINE.json)"
 rm -f /tmp/_bench_gate_serve.json
-BENCH_FORCE_CPU=1 BENCH_MODEL=serve BENCH_DEADLINE_S=240 python bench.py \
+BENCH_FORCE_CPU=1 BENCH_MODEL=serve python bench.py \
     | tail -1 > /tmp/_bench_gate_serve.json
 python scripts/bench_gate.py /tmp/_bench_gate_serve.json
 
 echo "== bench regression gate (online mode vs BENCH_BASELINE.json)"
 rm -f /tmp/_bench_gate_online.json
-BENCH_FORCE_CPU=1 BENCH_MODEL=online BENCH_DEADLINE_S=240 python bench.py \
+BENCH_FORCE_CPU=1 BENCH_MODEL=online python bench.py \
     | tail -1 > /tmp/_bench_gate_online.json
 python scripts/bench_gate.py /tmp/_bench_gate_online.json
 python - <<'PY'
@@ -1414,7 +1418,7 @@ PY
 
 echo "== bench regression gate (shard mode vs BENCH_BASELINE.json + HBM ratio)"
 rm -f /tmp/_bench_gate_shard.json
-BENCH_FORCE_CPU=1 BENCH_MODEL=shard BENCH_DEADLINE_S=240 python bench.py \
+BENCH_FORCE_CPU=1 BENCH_MODEL=shard python bench.py \
     | tail -1 > /tmp/_bench_gate_shard.json
 python scripts/bench_gate.py /tmp/_bench_gate_shard.json
 python - <<'PY'
@@ -1460,7 +1464,7 @@ PY
 
 echo "== bench regression gate (pipeline mode vs BENCH_BASELINE.json)"
 rm -f /tmp/_bench_gate_pipeline.json
-BENCH_FORCE_CPU=1 BENCH_MODEL=pipeline BENCH_DEADLINE_S=240 python bench.py \
+BENCH_FORCE_CPU=1 BENCH_MODEL=pipeline python bench.py \
     | tail -1 > /tmp/_bench_gate_pipeline.json
 python scripts/bench_gate.py /tmp/_bench_gate_pipeline.json
 python - <<'PY'
@@ -1484,7 +1488,7 @@ PY
 
 echo "== bench regression gate (autotune mode vs BENCH_BASELINE.json)"
 rm -f /tmp/_bench_gate_autotune.json
-BENCH_FORCE_CPU=1 BENCH_MODEL=autotune BENCH_DEADLINE_S=240 \
+BENCH_FORCE_CPU=1 BENCH_MODEL=autotune \
     BENCH_AUTOTUNE_BUDGET_S=60 python bench.py | tail -1 \
     > /tmp/_bench_gate_autotune.json
 python scripts/bench_gate.py /tmp/_bench_gate_autotune.json
@@ -1505,7 +1509,7 @@ PY
 
 echo "== bench regression gate (fleet mode vs BENCH_BASELINE.json)"
 rm -f /tmp/_bench_gate_fleet.json
-BENCH_FORCE_CPU=1 BENCH_MODEL=fleet BENCH_DEADLINE_S=240 python bench.py \
+BENCH_FORCE_CPU=1 BENCH_MODEL=fleet python bench.py \
     | tail -1 > /tmp/_bench_gate_fleet.json
 python scripts/bench_gate.py /tmp/_bench_gate_fleet.json
 python - <<'PY'
@@ -1536,7 +1540,7 @@ PY
 
 echo "== bench regression gate (history mode vs BENCH_BASELINE.json)"
 rm -f /tmp/_bench_gate_history.json
-BENCH_FORCE_CPU=1 BENCH_MODEL=history BENCH_DEADLINE_S=360 python bench.py \
+BENCH_FORCE_CPU=1 BENCH_MODEL=history python bench.py \
     | tail -1 > /tmp/_bench_gate_history.json
 python scripts/bench_gate.py /tmp/_bench_gate_history.json
 python - <<'PY'

@@ -10,8 +10,8 @@ upgrades itself to true MNIST whenever `fetch_mnist` can reach a mirror (or
 MNIST_DIR holds the IDX files) — exercised here against a local file:// mirror
 with real digest verification.
 
-Pinned numbers live in BASELINE.md's measured table; these tests are the
-assertions that keep them true.
+The pinned numbers live HERE: each test's thresholds are the record of
+what the framework reaches on that data.
 """
 
 import gzip
@@ -44,7 +44,7 @@ class TestRealDataAccuracy:
     def test_lenet_digits_accuracy_pinned(self):
         """LeNet-style CNN (conv-pool-conv-pool-dense, kernels scaled to the
         8×8 raster) on REAL handwritten digit scans: >= 0.95 held-out accuracy
-        in one short run (BASELINE.md row 'lenet-digits')."""
+        in one short run."""
         from deeplearning4j_tpu.nn.layers.convolution import ConvolutionLayer
         from deeplearning4j_tpu.nn.layers.pooling import SubsamplingLayer
 
@@ -70,8 +70,7 @@ class TestRealDataAccuracy:
         assert ev.accuracy() >= 0.95, ev.stats()
 
     def test_mlp_iris_accuracy_pinned(self):
-        """MLP on real Fisher Iris: >= 0.95 full-set accuracy
-        (BASELINE.md row 'mlp-iris')."""
+        """MLP on real Fisher Iris: >= 0.95 full-set accuracy."""
         conf = MultiLayerConfiguration(
             layers=[DenseLayer(n_out=16, activation="tanh"),
                     OutputLayer(n_out=3, activation="softmax", loss="mcxent")],
@@ -88,8 +87,7 @@ class TestRealDataAccuracy:
     def test_char_rnn_bits_per_char_pinned(self):
         """Stacked GravesLSTM char model (BASELINE config #3 family) on real
         English text via TBPTT: <= 1.8 bits/char after 60 epochs (measured
-        1.36; random over the 29-char vocab is 4.86 — BASELINE.md row
-        'char-rnn-pangrams')."""
+        1.36; random over the 29-char vocab is 4.86)."""
         from deeplearning4j_tpu.datasets.iterators import DataSet
         from deeplearning4j_tpu.models.char_rnn import char_rnn
 

@@ -9,6 +9,10 @@ staged program goes through an explicit, counted ``lower().compile()``) and
 cannot fake). Same counting style as PR 2's no-extra-syncs test.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -256,21 +260,86 @@ class TestRecompileElimination:
         assert cm.compiles.value == before + 1
 
 
-class TestPersistentCacheKnob:
-    def test_env_knob_wires_jax_config(self, tmp_path, monkeypatch):
+class TestPersistentCacheResolver:
+    """One resolver places jax's persistent cache: where
+    JAX_COMPILATION_CACHE_DIR says when it is set (and then nothing is set
+    in code), else the fixed <repo>/.jax_cache."""
+
+    def test_env_set_means_no_directory_is_set_in_code(self, tmp_path,
+                                                       monkeypatch):
         from deeplearning4j_tpu.runtime import compile_manager as cmod
 
         monkeypatch.setenv(cmod.CACHE_DIR_ENV, str(tmp_path))
-        # conftest already set a cache dir; the knob must win and restore
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            assert cmod.enable_persistent_cache() is True
-            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda name, value: updates.append(name))
+        cmod.resolve_persistent_cache()
+        assert updates == []
 
-    def test_disabled_without_env(self, monkeypatch):
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    @classmethod
+    def _resolve_in_fresh_process(cls, cwd, **env_overrides):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(JAX_PLATFORMS="cpu", PYTHONPATH=cls.REPO, **env_overrides)
+        code = ("from deeplearning4j_tpu.runtime import "
+                "resolve_persistent_cache as r; print(r())")
+        return subprocess.run(
+            [sys.executable, "-c", code], cwd=cwd, env=env, text=True,
+            capture_output=True, timeout=120, check=True,
+        ).stdout.strip().splitlines()[-1]
+
+    def test_unset_resolves_to_the_fixed_checkout_path(self, monkeypatch):
         from deeplearning4j_tpu.runtime import compile_manager as cmod
 
         monkeypatch.delenv(cmod.CACHE_DIR_ENV, raising=False)
-        assert cmod.enable_persistent_cache() is False
+        prev = jax.config.jax_compilation_cache_dir
+        try:
+            got = cmod.resolve_persistent_cache()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
+        assert got == os.path.join(self.REPO, ".jax_cache") \
+            == cmod.DEFAULT_CACHE_DIR
+
+    def test_two_processes_resolve_the_same_directory(self):
+        outs = [self._resolve_in_fresh_process(cwd)
+                for cwd in (self.REPO, os.path.join(self.REPO, "tests"))]
+        assert outs[0] == outs[1] == os.path.join(self.REPO, ".jax_cache")
+
+    def test_env_placed_cache_is_honoured_by_a_fresh_process(self, tmp_path):
+        out = self._resolve_in_fresh_process(
+            self.REPO, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+        assert out == str(tmp_path)
+
+
+class TestAdmissionFailureIsCounted:
+    def test_a_raising_admission_check_is_counted_not_silent(self,
+                                                             monkeypatch):
+        from deeplearning4j_tpu.analysis import ir_checks
+        from deeplearning4j_tpu.runtime.compile_manager import CompileManager
+        from deeplearning4j_tpu.telemetry import MetricsRegistry
+
+        def boom(*a, **k):
+            raise AttributeError("module 'jax.core' has no attribute 'X'")
+
+        monkeypatch.setattr(ir_checks, "admission_check", boom)
+        cm = CompileManager(registry=MetricsRegistry())
+        fn = cm.aot(("t", "probe"), lambda: jax.jit(lambda x: x + 1),
+                    (jnp.ones((4,)),))
+        assert float(fn(jnp.ones((4,)))[0]) == 2.0  # compilation went on
+        stats = cm.stats()
+        assert stats["admission_errors"] == 1
+        assert stats["static_cost"]["entries_with_cost"] == 0
+        assert cm.ir_findings.labels(rule="admission_error").value == 1
+
+    def test_a_working_admission_check_counts_nothing(self):
+        from deeplearning4j_tpu.runtime.compile_manager import CompileManager
+        from deeplearning4j_tpu.telemetry import MetricsRegistry
+
+        cm = CompileManager(registry=MetricsRegistry())
+        cm.aot(("t", "probe"), lambda: jax.jit(lambda x: x @ x),
+               (jnp.ones((8, 8)),))
+        stats = cm.stats()
+        assert stats["admission_errors"] == 0
+        assert stats["static_cost"]["entries_with_cost"] == 1

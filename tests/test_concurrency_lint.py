@@ -421,7 +421,7 @@ class TestSchemaAggregation:
         )
         findings = check_runtime_package()
         assert findings == [], [
-            (f.rule_id, f.filename, f.lineno, f.message) for f in findings
+            (f.rule_id, f.file, f.line, f.message) for f in findings
         ]
 
 

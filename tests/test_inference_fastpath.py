@@ -141,13 +141,15 @@ class TestBucketedParity:
 
     def test_dense_per_row_unbatched_parity(self, rng, legacy_env):
         """Bucketed batch output == every row served alone (the serving
-        coalescing contract)."""
+        coalescing contract). Different row counts are different programs
+        (XLA:CPU picks a gemv for one row, a gemm for eight), so equality
+        holds to the last ulp or two of f32, not bit-for-bit."""
         net = _dense_net()
         x = rng.normal(size=(6, 5)).astype(np.float32)
         fast = np.asarray(net.output(x))
         for i in range(x.shape[0]):
             row = np.asarray(legacy_env(lambda: net.output(x[i:i + 1])))
-            np.testing.assert_array_equal(fast[i:i + 1], row)
+            np.testing.assert_allclose(fast[i:i + 1], row, rtol=5e-7, atol=0)
 
     def test_recurrent_ragged_time_bit_exact(self, rng, legacy_env):
         net = _rnn_net()
@@ -336,6 +338,8 @@ class TestSharedLruTenancy:
         fast = np.asarray(net.output(x))
         monkeypatch.setenv(inf.INFER_ENV, "legacy")
         legacy = net.output(x)
-        # legacy returns a device array, same numbers
+        # legacy returns a device array, same numbers (3 rows vs the padded
+        # 4-row bucket: two programs, equal to f32's last ulp or two)
         assert isinstance(legacy, jax.Array)
-        np.testing.assert_array_equal(fast, np.asarray(legacy))
+        np.testing.assert_allclose(fast, np.asarray(legacy), rtol=5e-7,
+                                   atol=0)

@@ -146,6 +146,40 @@ class TestCostModelGroundTruth:
         assert cost["arithmetic_intensity"] == pytest.approx(
             cost["flops"] / cost["hbm_bytes"])
 
+    def test_peaks_table_off_chip_is_the_assumed_v5e_row(self):
+        from deeplearning4j_tpu.analysis.cost_model import (DEVICE_PEAKS,
+                                                             device_peaks)
+
+        row = device_peaks()
+        assert row["assumed"] is True and row["device_kind"] == "TPU v5 lite"
+        assert row["peak_flops"] == 1.97e14 and row["hbm_gbps"] == 819.0
+        assert row["ici_gbps"] == 200.0
+        assert all(r["source"] for r in DEVICE_PEAKS.values())
+        rl = roofline_params()
+        assert rl["assumed"] is True and rl["peak_flops"] == 1.97e14
+
+    def test_peaks_table_on_a_tpu_is_keyed_by_device_kind(self, monkeypatch):
+        import jax
+
+        from deeplearning4j_tpu import profiler
+        from deeplearning4j_tpu.analysis import cost_model
+
+        class FakeTpu:
+            platform = "tpu"
+
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu("TPU v5 lite")])
+        row = cost_model.device_peaks()
+        assert row["assumed"] is False and row["device_kind"] == "TPU v5 lite"
+        assert profiler.mfu(1.97e14, 1.0) == pytest.approx(100.0)
+        monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu("TPU v9")])
+        with pytest.raises(KeyError, match="no peaks for device_kind 'TPU v9'"):
+            cost_model.roofline_params()
+        with pytest.raises(KeyError, match="TPU v9"):
+            profiler.mfu(1e12, 1.0)
+
     def test_roofline_env_knobs(self, monkeypatch):
         monkeypatch.setenv("DL4JTPU_PEAK_FLOPS", "1e12")
         monkeypatch.setenv("DL4JTPU_HBM_GBPS", "100")

@@ -68,6 +68,54 @@ class TestSelectionCore:
         ctx = _charrnn_ctx(H=8192, itemsize=4)
         assert ks.select("lstm_seq", ctx) == "reference"
 
+    def test_a_give_way_is_never_quiet(self):
+        ks.set_force_available(True)
+        ctx = _charrnn_ctx(H=8192, itemsize=4)
+        assert ks.select("lstm_seq", ctx) == "reference"
+        rec = ks.selection_log()[-1]
+        assert rec["reason"] == "fallback"
+        assert rec["infeasible"] == ["seqfused", "fusedcell"]
+        # a fitting shape records no give-way
+        ks.select("lstm_seq", _charrnn_ctx())
+        assert "infeasible" not in ks.selection_log()[-1]
+        # flash past its K+V VMEM budget: the site says xla (the path that
+        # runs), also when flash was asked for by name
+        big = _attn_ctx(1 << 17)
+        assert ks.select("attention", big, forced="flash") == "xla"
+        rec = ks.selection_log()[-1]
+        assert rec["reason"] == "fallback" and rec["infeasible"] == ["flash"]
+
+    def test_partitioned_program_keeps_mosaic_kernels_out(self):
+        ks.set_force_available(True)
+        ctx = {"N": 16384, "C": 96, "itemsize": 4}
+        assert ks.select("softmax_xent", ctx) == "fused"
+        with ks.partitioned_program():
+            assert ks.select("softmax_xent", ctx) == "reference"
+            assert ks.select("lstm_seq", _charrnn_ctx(),
+                             forced="seqfused") == "reference"
+            with ks.partitioned_program(False):  # inner no-op, outer holds
+                assert ks.select("optimizer", {
+                    "n_elems": 1 << 20, "itemsize": 4, "updater": "adam",
+                    "n_leaves": 4}) == "reference"
+        for rec in ks.selection_log()[1:]:
+            assert rec["ctx"]["partitioned"] is True
+            assert rec["infeasible"] and rec["reason"] == "fallback"
+        # the scope is over; the unpartitioned selection is its own cache key
+        assert ks.select("softmax_xent", ctx) == "fused"
+
+    def test_scoped_for_layout_only_wraps_multi_device_layouts(self):
+        from deeplearning4j_tpu.parallel import MeshLayout
+
+        seen = []
+        probe = lambda: seen.append(  # noqa: E731
+            ks.select("softmax_xent", {"N": 64, "C": 8, "itemsize": 4}))
+        ks.set_mode("fused")
+        assert ks.scoped_for_layout(probe, None) is probe
+        assert ks.scoped_for_layout(probe, MeshLayout(data=1)) is probe
+        ks.scoped_for_layout(probe, MeshLayout(data=2, fsdp=2))()
+        probe()
+        assert seen == ["reference", "fused"]
+
     def test_unsupported_activations_always_reference(self):
         ks.set_force_available(True)
         assert ks.select("lstm_seq",

@@ -1,5 +1,5 @@
 """conf.params_dtype="bfloat16": carry parameters in the compute dtype
-(the round-5 weight-copy-bound lever; BASELINE.md trace analysis). The
+(the round-5 weight-copy-bound lever; docs/resnet50_step_analysis.md). The
 default (None) keeps f32 master params with a per-step bf16 compute cast."""
 
 import jax

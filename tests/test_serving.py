@@ -164,7 +164,10 @@ class TestInferenceService:
             monkeypatch.setenv("DL4JTPU_INFER", "legacy")
             for i, x in enumerate(xs):
                 ref = np.asarray(net.output(x))
-                np.testing.assert_array_equal(np.asarray(results[i]), ref)
+                # coalesced bucket vs unbatched: two programs, equal to
+                # f32's last ulp or two (XLA:CPU gemv vs gemm)
+                np.testing.assert_allclose(np.asarray(results[i]), ref,
+                                           rtol=5e-7, atol=0)
         finally:
             svc.stop()
 

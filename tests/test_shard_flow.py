@@ -331,8 +331,6 @@ class TestDT306:
         """A pipe x tp manual region shaped like the 1F1B tick loop: per
         tick a stage matmul, a pipe ppermute handoff, and — unless hoisted
         — a tp psum of the activations inside the tick body."""
-        from jax.experimental.shard_map import shard_map
-
         m, p = self.M, 2
 
         def region(x, w):
@@ -347,9 +345,9 @@ class TestDT306:
                                        [(i, (i + 1) % p) for i in range(p)])
             return acc[None]
 
-        return shard_map(region, lo.mesh,
-                         in_specs=(P("pipe"), P()),
-                         out_specs=P("pipe"), check_rep=False)
+        return jax.shard_map(region, mesh=lo.mesh,
+                             in_specs=(P("pipe"), P()),
+                             out_specs=P("pipe"), check_vma=False)
 
     def _analyze(self, *, hoist, microbatches):
         lo = self._lo()

@@ -76,7 +76,7 @@ class TestKnobRegistry:
             "train_batch", "stage_window", "bucket_boundaries",
             "telemetry_fetch_every", "precision_params_dtype", "donation",
             "serve_max_delay_ms", "serve_max_batch", "decode_slots",
-            "flash_min_seq", "xla_persistent_cache",
+            "flash_min_seq",
         } | {f"kernel_{s}" for s in KERNEL_SITES}
         assert expected <= names
 
@@ -122,13 +122,12 @@ class TestEnvScope:
         assert os.environ["DL4JTPU_TUNE_T4"] == "keepme"
 
     def test_apply_config_composes_kernels_and_gates(self, monkeypatch):
-        monkeypatch.setenv("DL4JTPU_XLA_CACHE_DIR", "/tmp/xla")
         monkeypatch.delenv("DL4JTPU_KERNELS", raising=False)
         monkeypatch.delenv("DL4JTPU_DONATE", raising=False)
         config = {
             "kernel_attention": "reference", "kernel_lrn": "fused",
             "kernel_optimizer": "auto",   # auto = no override, not listed
-            "donation": False, "xla_persistent_cache": False,
+            "donation": False,
             "stage_window": 8,            # call-kind: returned, not set
         }
         with EnvScope() as scope:
@@ -137,10 +136,8 @@ class TestEnvScope:
             assert (os.environ["DL4JTPU_KERNELS"]
                     == "attention=reference,lrn=fused")
             assert os.environ["DL4JTPU_DONATE"] == "0"
-            assert "DL4JTPU_XLA_CACHE_DIR" not in os.environ
         assert "DL4JTPU_KERNELS" not in os.environ
         assert "DL4JTPU_DONATE" not in os.environ
-        assert os.environ["DL4JTPU_XLA_CACHE_DIR"] == "/tmp/xla"
 
 
 # ------------------------------------------------------- search engine
