@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ...telemetry.spans import span
 from ..multilayer import (
     _carry_params_dtype,
     _cast_input,
@@ -64,23 +65,25 @@ class ComputationGraph:
     def init(self, params=None, force: bool = False) -> "ComputationGraph":
         if self.params is not None and not force and params is None:
             return self
-        vit = self.conf.vertex_input_types()
-        key = jax.random.PRNGKey(self.conf.seed)
-        keys = jax.random.split(key, max(len(self._topo), 1))
-        if params is None:
-            params = {
-                name: self.conf.vertices[name].init_params(k, *vit[name])
-                for name, k in zip(self._topo, keys)
+        with span("dl4j.net.init", net="graph"):
+            vit = self.conf.vertex_input_types()
+            key = jax.random.PRNGKey(self.conf.seed)
+            keys = jax.random.split(key, max(len(self._topo), 1))
+            if params is None:
+                params = {
+                    name: self.conf.vertices[name].init_params(k, *vit[name])
+                    for name, k in zip(self._topo, keys)
+                }
+            params = _carry_params_dtype(self.conf, params)
+            self.params = params
+            self.state = {
+                name: self.conf.vertices[name].init_state(*vit[name])
+                for name in self._topo
             }
-        params = _carry_params_dtype(self.conf, params)
-        self.params = params
-        self.state = {
-            name: self.conf.vertices[name].init_state(*vit[name]) for name in self._topo
-        }
-        self._tx = self.conf.updater.build()
-        self.opt_state = self._tx.init(self.params)
-        self.iteration = 0
-        self._invalidate_compiled()
+            self._tx = self.conf.updater.build()
+            self.opt_state = self._tx.init(self.params)
+            self.iteration = 0
+            self._invalidate_compiled()
         return self
 
     def _invalidate_compiled(self) -> None:
@@ -234,23 +237,29 @@ class ComputationGraph:
         for name, r in zip(self._topo, rngs):
             vertex = conf.vertices[name]
             ins = [acts[src] for src in conf.vertex_inputs[name]]
-            if new_rnn is not None and new_rnn.get(name):
-                acts[name], new_rnn[name] = vertex.apply_seq(
-                    params[name], ins, new_rnn[name], train=train, rng=r, masks=vmasks
-                )
-            elif train and conf.remat:
-                # per-vertex jax.checkpoint: keep only vertex-boundary
-                # activations for backward (see MultiLayerConfiguration.remat)
-                def _ck(p_, ins_, st_, r_, m_, _v=vertex):
-                    return _v.apply(p_, ins_, st_, train=True, rng=r_, masks=m_)
+            # every operation of a vertex carries its configured name
+            with jax.named_scope(name):
+                if new_rnn is not None and new_rnn.get(name):
+                    acts[name], new_rnn[name] = vertex.apply_seq(
+                        params[name], ins, new_rnn[name], train=train, rng=r,
+                        masks=vmasks
+                    )
+                elif train and conf.remat:
+                    # per-vertex jax.checkpoint: keep only vertex-boundary
+                    # activations for backward (see
+                    # MultiLayerConfiguration.remat)
+                    def _ck(p_, ins_, st_, r_, m_, _v=vertex):
+                        return _v.apply(p_, ins_, st_, train=True, rng=r_,
+                                        masks=m_)
 
-                acts[name], new_state[name] = jax.checkpoint(_ck)(
-                    params[name], ins, state[name], r, vmasks
-                )
-            else:
-                acts[name], new_state[name] = vertex.apply(
-                    params[name], ins, state[name], train=train, rng=r, masks=vmasks
-                )
+                    acts[name], new_state[name] = jax.checkpoint(_ck)(
+                        params[name], ins, state[name], r, vmasks
+                    )
+                else:
+                    acts[name], new_state[name] = vertex.apply(
+                        params[name], ins, state[name], train=train, rng=r,
+                        masks=vmasks
+                    )
         return acts, new_state, new_rnn
 
     def _forward(self, params, inputs, state, train, rng, masks=None, rnn_state=None):
@@ -285,19 +294,23 @@ class ComputationGraph:
                     f"Training output '{out_name}' is not an output layer vertex"
                 )
             ins = [acts[src] for src in conf.vertex_inputs[out_name]]
-            h = vertex.pre_output_input(ins)
-            h32 = h.astype(jnp.float32) if h.dtype == jnp.bfloat16 else h
-            p = params[out_name]
-            if conf.dtype == "bfloat16":
-                p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p)
-            lm = labels_masks[i] if labels_masks is not None else None
-            total = total + vertex.layer.compute_loss(
-                p, h32, labels[i], lm, train=train, rng=out_rngs[i]
+            with jax.named_scope("loss"), jax.named_scope(out_name):
+                h = vertex.pre_output_input(ins)
+                h32 = h.astype(jnp.float32) if h.dtype == jnp.bfloat16 else h
+                p = params[out_name]
+                if conf.dtype == "bfloat16":
+                    p = jax.tree_util.tree_map(
+                        lambda a: a.astype(jnp.float32), p)
+                lm = labels_masks[i] if labels_masks is not None else None
+                total = total + vertex.layer.compute_loss(
+                    p, h32, labels[i], lm, train=train, rng=out_rngs[i]
+                )
+        with jax.named_scope("loss"):
+            reg = sum(
+                (self.conf.vertices[n].regularization_loss(params[n])
+                 for n in self._topo),
+                start=jnp.asarray(0.0),
             )
-        reg = sum(
-            (self.conf.vertices[n].regularization_loss(params[n]) for n in self._topo),
-            start=jnp.asarray(0.0),
-        )
         return total + reg, new_state, new_rnn
 
     def loss_fn(self, params, inputs, labels, *, train=False, state=None, rng=None,
@@ -316,7 +329,8 @@ class ComputationGraph:
         tx = self._tx
         ls = getattr(self.conf, "loss_scale", None)
 
-        def step(params, opt_state, state, inputs, labels, rng, labels_masks, masks):
+        def dl4j_graph_train_step(params, opt_state, state, inputs, labels,
+                                  rng, labels_masks, masks):
             def loss_of(p):
                 loss, new_state, _ = self._loss(
                     p, state, inputs, labels, rng, True, labels_masks, masks
@@ -326,8 +340,9 @@ class ComputationGraph:
             (loss, new_state), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
             loss = unscale_loss(loss, ls)
             grads = unscale_grads(grads, ls)
-            updates, new_opt, new_params = optimizer_update(
-                tx, grads, opt_state, params)
+            with jax.named_scope("optimizer_update"):
+                updates, new_opt, new_params = optimizer_update(
+                    tx, grads, opt_state, params)
             if with_grad_stats:
                 return new_params, new_opt, new_state, loss, grads, updates
             if with_telemetry:
@@ -341,7 +356,8 @@ class ComputationGraph:
 
         donate = ((0, 1, 2) if jax.default_backend() != "cpu"
                   and donation_enabled() else ())
-        return jax.jit(self._kernel_scoped(step), donate_argnums=donate)
+        return jax.jit(self._kernel_scoped(dl4j_graph_train_step),
+                       donate_argnums=donate)
 
     # ------------------------------------------------- on-device multi-step
     def _build_multi_step(self, steps_cap: int, with_masks: bool = False,
@@ -365,8 +381,8 @@ class ComputationGraph:
         ls = getattr(self.conf, "loss_scale", None)
         constrain = MultiLayerNetwork._staged_out_constraint(self)
 
-        def run(params, opt_state, state, rng, n_steps, n_batches,
-                xs_list, ys_list, xmasks, ymasks):
+        def dl4j_graph_staged(params, opt_state, state, rng, n_steps,
+                              n_batches, xs_list, ys_list, xmasks, ymasks):
             from ...telemetry import device as _tdev  # noqa: PLC0415
 
             losses0 = jnp.zeros((steps_cap,), jnp.float32)
@@ -407,8 +423,9 @@ class ComputationGraph:
                 (loss, new_state), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
                 loss = unscale_loss(loss, ls)
                 grads = unscale_grads(grads, ls)
-                updates, new_opt, new_params = optimizer_update(
-                    tx, grads, opt, params)
+                with jax.named_scope("optimizer_update"):
+                    updates, new_opt, new_params = optimizer_update(
+                        tx, grads, opt, params)
                 losses = jax.lax.dynamic_update_index_in_dim(
                     losses, loss.astype(jnp.float32), i, 0)
                 if with_telemetry:
@@ -429,7 +446,7 @@ class ComputationGraph:
 
         donate = ((0, 1, 2, 3) if jax.default_backend() != "cpu"
                   and donation_enabled() else ())
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(dl4j_graph_staged, donate_argnums=donate)
 
     @staticmethod
     def _as_stage_list(value, n: int, kind: str):
@@ -547,67 +564,77 @@ class ComputationGraph:
         self.init()
         if self.conf.backprop_type == "tbptt":
             raise ValueError("fit_on_device does not support TBPTT; use fit()")
-        if not isinstance(features, (list, tuple)):
-            features = [features]
-        if not isinstance(labels, (list, tuple)):
-            labels = [labels]
-        xs_list = [jnp.asarray(x) for x in features]
-        ys_list = [jnp.asarray(y) for y in labels]
-        fmasks = self._as_stage_list(features_masks,
-                                     len(self.conf.network_inputs),
-                                     "features_masks")
-        lmasks = self._as_stage_list(labels_masks,
-                                     len(self.conf.network_outputs),
-                                     "labels_masks")
-        if fmasks is not None:
-            fmasks = [None if m is None else jnp.asarray(m) for m in fmasks]
-            if all(m is None for m in fmasks):
-                fmasks = None
-        if lmasks is not None:
-            lmasks = [None if m is None else jnp.asarray(m) for m in lmasks]
-            if all(m is None for m in lmasks):
-                lmasks = None
-        tel = self.telemetry
-        steps_cap, with_masks, n_steps, args = self._staged_args(
-            xs_list, ys_list, steps, fmasks, lmasks, real_batches)
-        fn = self._staged_executable(steps_cap, with_masks, tel is not None,
-                                     args)
-        t0 = time.perf_counter()
-        out = fn(*args)
-        mvecs = None
-        if tel is not None:
-            (self.params, self.opt_state, self.state, self._rng,
-             losses, mvecs) = out
-        else:
-            self.params, self.opt_state, self.state, self._rng, losses = out
-        # host fetch = the sync point; buffer tails slice off HOST-side (a
-        # device-side slice would compile per distinct step count)
-        losses = np.asarray(losses)[:n_steps]
-        elapsed = time.perf_counter() - t0
-        if tel is not None:
-            if tel.flight is not None:
-                # dispatch event rings BEFORE the fetch: an anomaly found at
-                # fetch time auto-dumps with the dispatch already on record
-                tel.flight.record(
-                    "staged_dispatch", net="graph", steps=int(n_steps),
-                    slots=int(xs_list[0].shape[0]),
-                    batch=int(xs_list[0].shape[1]),
-                    seconds=round(elapsed, 6))
-            tel.on_staged(self.iteration + 1, np.asarray(mvecs)[:n_steps],
-                          per_step_time_s=elapsed / max(len(losses), 1))
-        self.last_batch_size = int(xs_list[0].shape[1])
-        self.staged_steps_total += len(losses)
-        # see MultiLayerNetwork.fit_on_device: even per-step attribution for
-        # throughput listeners during the tight replay loop
-        self.staged_step_time = elapsed / max(len(losses), 1)
-        try:
-            for loss in losses:
-                self.iteration += 1
-                self._last_loss = loss
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.iteration, loss)
-        finally:
-            self.staged_step_time = None
+        with span("dl4j.fit.dispatch", net="graph") as dispatch:
+            with span("dl4j.fit.prepare"):
+                if not isinstance(features, (list, tuple)):
+                    features = [features]
+                if not isinstance(labels, (list, tuple)):
+                    labels = [labels]
+                xs_list = [jnp.asarray(x) for x in features]
+                ys_list = [jnp.asarray(y) for y in labels]
+                fmasks = self._as_stage_list(features_masks,
+                                             len(self.conf.network_inputs),
+                                             "features_masks")
+                lmasks = self._as_stage_list(labels_masks,
+                                             len(self.conf.network_outputs),
+                                             "labels_masks")
+                if fmasks is not None:
+                    fmasks = [None if m is None else jnp.asarray(m)
+                              for m in fmasks]
+                    if all(m is None for m in fmasks):
+                        fmasks = None
+                if lmasks is not None:
+                    lmasks = [None if m is None else jnp.asarray(m)
+                              for m in lmasks]
+                    if all(m is None for m in lmasks):
+                        lmasks = None
+                tel = self.telemetry
+                steps_cap, with_masks, n_steps, args = self._staged_args(
+                    xs_list, ys_list, steps, fmasks, lmasks, real_batches)
+                fn = self._staged_executable(steps_cap, with_masks,
+                                             tel is not None, args)
+            slots, batch = (int(d) for d in xs_list[0].shape[:2])
+            dispatch.args.update(steps=int(n_steps), slots=slots, batch=batch)
+            t0 = time.perf_counter()
+            with span("dl4j.fit.launch"):
+                out = fn(*args)
+            mvecs = None
+            if tel is not None:
+                (self.params, self.opt_state, self.state, self._rng,
+                 losses, mvecs) = out
+            else:
+                self.params, self.opt_state, self.state, self._rng, losses = out
+            # host fetch = the sync point; buffer tails slice off HOST-side
+            # (a device-side slice would compile per distinct step count)
+            with span("dl4j.fit.fetch"):
+                losses = np.asarray(losses)[:n_steps]
+                if mvecs is not None:
+                    mvecs = np.asarray(mvecs)[:n_steps]
+            elapsed = time.perf_counter() - t0
+            if tel is not None:
+                if tel.flight is not None:
+                    # dispatch event rings BEFORE on_staged reads the
+                    # metrics: an anomaly found there auto-dumps with the
+                    # dispatch already on record
+                    tel.flight.record(
+                        "staged_dispatch", net="graph", steps=int(n_steps),
+                        slots=slots, batch=batch, seconds=round(elapsed, 6))
+                tel.on_staged(self.iteration + 1, mvecs,
+                              per_step_time_s=elapsed / max(len(losses), 1))
+            self.last_batch_size = batch
+            self.staged_steps_total += len(losses)
+            # see MultiLayerNetwork.fit_on_device: even per-step attribution
+            # for throughput listeners during the tight replay loop
+            self.staged_step_time = elapsed / max(len(losses), 1)
+            with span("dl4j.fit.listeners"):
+                try:
+                    for loss in losses:
+                        self.iteration += 1
+                        self._last_loss = loss
+                        for lst in self.listeners:
+                            lst.iteration_done(self, self.iteration, loss)
+                finally:
+                    self.staged_step_time = None
         return losses
 
     def fit(self, data, epochs: int = 1,
@@ -854,7 +881,8 @@ class ComputationGraph:
                 return None
             return {n: (None if m is None else m[:, sl]) for n, m in md.items()}
 
-        def step(params, opt_state, state, rnn, xs, ys, rng, labels_masks, masks):
+        def dl4j_graph_tbptt_step(params, opt_state, state, rnn, xs, ys, rng,
+                                  labels_masks, masks):
             seg_len = next(a.shape[1] for a in xs if a.ndim == 3)
             k = seg_len if back_len <= 0 else min(back_len, seg_len)
             if k < seg_len:
@@ -895,7 +923,7 @@ class ComputationGraph:
             new_rnn = jax.lax.stop_gradient(new_rnn)
             return new_params, new_opt, new_state, new_rnn, loss
 
-        return jax.jit(self._kernel_scoped(step))
+        return jax.jit(self._kernel_scoped(dl4j_graph_tbptt_step))
 
     def _fit_tbptt(self, mds) -> None:
         # TBPTT bypasses the grad-stats step; drop stale grads (see MLN note).

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ..telemetry.spans import span
 from .conf.multi_layer import MultiLayerConfiguration
 from .conf.inputs import InputType
 from .updaters import (optimizer_update, scaled_loss, unscale_grads,
@@ -150,23 +151,25 @@ class MultiLayerNetwork:
         """Initialize params/state/updater (reference: MultiLayerNetwork.init():382)."""
         if self.params is not None and not force and params is None:
             return self
-        input_types = self.conf.layer_input_types()
-        key = jax.random.PRNGKey(self.conf.seed)
-        keys = jax.random.split(key, len(self.conf.layers))
-        if params is None:
-            params = tuple(
-                layer.init_params(k, it)
-                for layer, k, it in zip(self.conf.layers, keys, input_types)
+        with span("dl4j.net.init", net="mln"):
+            input_types = self.conf.layer_input_types()
+            key = jax.random.PRNGKey(self.conf.seed)
+            keys = jax.random.split(key, len(self.conf.layers))
+            if params is None:
+                params = tuple(
+                    layer.init_params(k, it)
+                    for layer, k, it in zip(self.conf.layers, keys, input_types)
+                )
+            params = _carry_params_dtype(self.conf, params)
+            self.params = params
+            self.state = tuple(
+                layer.init_state(it)
+                for layer, it in zip(self.conf.layers, input_types)
             )
-        params = _carry_params_dtype(self.conf, params)
-        self.params = params
-        self.state = tuple(
-            layer.init_state(it) for layer, it in zip(self.conf.layers, input_types)
-        )
-        self._tx = self.conf.updater.build()
-        self.opt_state = self._tx.init(self.params)
-        self.iteration = 0
-        self._invalidate_compiled()
+            self._tx = self.conf.updater.build()
+            self.opt_state = self._tx.init(self.params)
+            self.iteration = 0
+            self._invalidate_compiled()
         return self
 
     def _invalidate_compiled(self) -> None:
@@ -314,32 +317,40 @@ class MultiLayerNetwork:
         new_state = list(state)
         new_rnn = list(rnn_state) if rnn_state is not None else None
         for i in range(n):
-            pre = self.conf.preprocessors.get(i)
-            if pre is not None:
-                x = pre.apply(x)
-            if new_rnn is not None and new_rnn[i]:
-                x, new_rnn[i] = layers[i].apply_seq(
-                    params[i], x, new_rnn[i], mask=features_mask, train=train, rng=rngs[i]
-                )
-            elif train and self.conf.remat:
-                # per-layer rematerialization (jax.checkpoint): keep only
-                # layer-boundary activations for the backward pass and
-                # recompute each layer's internals — HBM for FLOPs, the
-                # standard TPU trade at memory-bound batch sizes
-                layer = layers[i]
+            with jax.named_scope(self.layer_scope(i)):
+                pre = self.conf.preprocessors.get(i)
+                if pre is not None:
+                    x = pre.apply(x)
+                if new_rnn is not None and new_rnn[i]:
+                    x, new_rnn[i] = layers[i].apply_seq(
+                        params[i], x, new_rnn[i], mask=features_mask,
+                        train=train, rng=rngs[i]
+                    )
+                elif train and self.conf.remat:
+                    # per-layer rematerialization (jax.checkpoint): keep only
+                    # layer-boundary activations for the backward pass and
+                    # recompute each layer's internals — HBM for FLOPs, the
+                    # standard TPU trade at memory-bound batch sizes
+                    layer = layers[i]
 
-                def _ck(p_, x_, st_, rng_, m_, _layer=layer):
-                    return _layer.apply(p_, x_, st_, train=True, rng=rng_,
-                                        mask=m_)
+                    def _ck(p_, x_, st_, rng_, m_, _layer=layer):
+                        return _layer.apply(p_, x_, st_, train=True, rng=rng_,
+                                            mask=m_)
 
-                x, new_state[i] = jax.checkpoint(_ck)(
-                    params[i], x, state[i], rngs[i], features_mask
-                )
-            else:
-                x, new_state[i] = layers[i].apply(
-                    params[i], x, state[i], train=train, rng=rngs[i], mask=features_mask
-                )
+                    x, new_state[i] = jax.checkpoint(_ck)(
+                        params[i], x, state[i], rngs[i], features_mask
+                    )
+                else:
+                    x, new_state[i] = layers[i].apply(
+                        params[i], x, state[i], train=train, rng=rngs[i],
+                        mask=features_mask
+                    )
         return x, tuple(new_state), (tuple(new_rnn) if new_rnn is not None else None)
+
+    def layer_scope(self, i: int) -> str:
+        """The ``jax.named_scope`` of layer ``i``'s operations in every
+        compiled program: its configured name, else ``layer<i>``."""
+        return self.conf.layers[i].name or f"layer{i}"
 
     def _loss(self, params, state, x, y, rng, train: bool, labels_mask=None,
               features_mask=None, rnn_state=None):
@@ -354,20 +365,24 @@ class MultiLayerNetwork:
             rnn_state=rnn_state,
         )
         out_layer = layers[out_idx]
-        pre = self.conf.preprocessors.get(out_idx)
-        if pre is not None:
-            h = pre.apply(h)
         if not hasattr(out_layer, "compute_loss"):
             raise ValueError(f"Last layer {type(out_layer).__name__} is not an output layer")
-        h32 = h.astype(jnp.float32) if h.dtype == jnp.bfloat16 else h
-        cast_p = params[out_idx]
-        if self.conf.dtype == "bfloat16":
-            cast_p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), cast_p)
-        loss = out_layer.compute_loss(cast_p, h32, y, labels_mask, train=train, rng=out_rng)
-        reg = sum(
-            (layer.regularization_loss(params[i]) for i, layer in enumerate(layers)),
-            start=jnp.asarray(0.0),
-        )
+        with jax.named_scope("loss"):
+            with jax.named_scope(self.layer_scope(out_idx)):
+                pre = self.conf.preprocessors.get(out_idx)
+                if pre is not None:
+                    h = pre.apply(h)
+                h32 = h.astype(jnp.float32) if h.dtype == jnp.bfloat16 else h
+                cast_p = params[out_idx]
+                if self.conf.dtype == "bfloat16":
+                    cast_p = jax.tree_util.tree_map(
+                        lambda a: a.astype(jnp.float32), cast_p)
+                loss = out_layer.compute_loss(cast_p, h32, y, labels_mask,
+                                              train=train, rng=out_rng)
+            reg = sum(
+                (layer.regularization_loss(params[i]) for i, layer in enumerate(layers)),
+                start=jnp.asarray(0.0),
+            )
         return loss + reg, new_state, new_rnn
 
     def loss_fn(self, params, x, y, *, train: bool = False, state=None, rng=None,
@@ -391,7 +406,8 @@ class MultiLayerNetwork:
         tx = self._tx
         ls = getattr(self.conf, "loss_scale", None)
 
-        def step(params, opt_state, state, x, y, rng, labels_mask, features_mask):
+        def dl4j_mln_train_step(params, opt_state, state, x, y, rng,
+                                labels_mask, features_mask):
             def loss_of(p):
                 loss, new_state, _ = self._loss(
                     p, state, x, y, rng, True, labels_mask, features_mask
@@ -401,8 +417,9 @@ class MultiLayerNetwork:
             (loss, new_state), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
             loss = unscale_loss(loss, ls)
             grads = unscale_grads(grads, ls)
-            updates, new_opt, new_params = optimizer_update(
-                tx, grads, opt_state, params)
+            with jax.named_scope("optimizer_update"):
+                updates, new_opt, new_params = optimizer_update(
+                    tx, grads, opt_state, params)
             if with_grad_stats:
                 return new_params, new_opt, new_state, loss, grads, updates
             if with_telemetry:
@@ -416,7 +433,8 @@ class MultiLayerNetwork:
 
         donate = ((0, 1, 2) if jax.default_backend() != "cpu"
                   and donation_enabled() else ())
-        return jax.jit(self._kernel_scoped(step), donate_argnums=donate)
+        return jax.jit(self._kernel_scoped(dl4j_mln_train_step),
+                       donate_argnums=donate)
 
     # ------------------------------------------------- on-device multi-step
     def _build_multi_step(self, steps_cap: int, with_masks: bool = False,
@@ -449,8 +467,8 @@ class MultiLayerNetwork:
         ls = getattr(self.conf, "loss_scale", None)
         constrain = self._staged_out_constraint()
 
-        def run(params, opt_state, state, rng, n_steps, n_batches, xs, ys,
-                xmasks, ymasks):
+        def dl4j_mln_staged(params, opt_state, state, rng, n_steps, n_batches,
+                            xs, ys, xmasks, ymasks):
             from ..telemetry import device as _tdev  # noqa: PLC0415
 
             losses0 = jnp.zeros((steps_cap,), jnp.float32)
@@ -479,8 +497,9 @@ class MultiLayerNetwork:
                 (loss, new_state), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
                 loss = unscale_loss(loss, ls)
                 grads = unscale_grads(grads, ls)
-                updates, new_opt, new_params = optimizer_update(
-                    tx, grads, opt, params)
+                with jax.named_scope("optimizer_update"):
+                    updates, new_opt, new_params = optimizer_update(
+                        tx, grads, opt, params)
                 losses = jax.lax.dynamic_update_index_in_dim(
                     losses, loss.astype(jnp.float32), i, 0)
                 if with_telemetry:
@@ -503,7 +522,7 @@ class MultiLayerNetwork:
 
         donate = ((0, 1, 2, 3) if jax.default_backend() != "cpu"
                   and donation_enabled() else ())
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(dl4j_mln_staged, donate_argnums=donate)
 
     def _staged_out_constraint(self):
         """Output-sharding pin for the staged step of a layout-applied net:
@@ -610,55 +629,65 @@ class MultiLayerNetwork:
         self.init()
         if self.conf.backprop_type == "tbptt":
             raise ValueError("fit_on_device does not support TBPTT; use fit()")
-        xs = jnp.asarray(xs)
-        ys = jnp.asarray(ys)
-        fm = None if features_masks is None else jnp.asarray(features_masks)
-        lm = None if labels_masks is None else jnp.asarray(labels_masks)
-        tel = self.telemetry
-        steps_cap, with_masks, n_steps, args = self._staged_args(
-            xs, ys, steps, fm, lm, real_batches)
-        fn = self._staged_executable(steps_cap, with_masks, tel is not None,
-                                     args)
-        t0 = time.perf_counter()
-        out = fn(*args)
-        mvecs = None
-        if tel is not None:
-            (self.params, self.opt_state, self.state, self._rng,
-             losses, mvecs) = out
-        else:
-            self.params, self.opt_state, self.state, self._rng, losses = out
-        # host fetch = the sync point; the tail of the buffer (beyond
-        # n_steps) is sliced off HOST-side — a device-side slice would
-        # compile a tiny program per distinct step count
-        losses = np.asarray(losses)[:n_steps]
-        elapsed = time.perf_counter() - t0
-        if tel is not None:
-            if tel.flight is not None:
-                # ring the dispatch BEFORE the fetch below — an anomaly
-                # found at fetch time auto-dumps, and the bundle should
-                # already show what was dispatched
-                tel.flight.record(
-                    "staged_dispatch", net="mln", steps=int(n_steps),
-                    slots=int(xs.shape[0]), batch=int(xs.shape[1]),
-                    seconds=round(elapsed, 6))
-            # the loop stacked per-step metrics; ONE more (already-computed)
-            # fetch records the whole window — never a per-step sync
-            tel.on_staged(self.iteration + 1, np.asarray(mvecs)[:n_steps],
-                          per_step_time_s=elapsed / max(len(losses), 1))
-        self.last_batch_size = int(xs.shape[1])
-        self.staged_steps_total += len(losses)
-        # replayed callbacks arrive in a tight host loop; wall-clock deltas
-        # between them measure nothing, so publish the dispatch's even
-        # per-step share for throughput listeners (PerformanceListener)
-        self.staged_step_time = elapsed / max(len(losses), 1)
-        try:
-            for loss in losses:
-                self.iteration += 1
-                self._last_loss = loss
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.iteration, loss)
-        finally:
-            self.staged_step_time = None
+        with span("dl4j.fit.dispatch", net="mln") as dispatch:
+            with span("dl4j.fit.prepare"):
+                xs = jnp.asarray(xs)
+                ys = jnp.asarray(ys)
+                fm = (None if features_masks is None
+                      else jnp.asarray(features_masks))
+                lm = None if labels_masks is None else jnp.asarray(labels_masks)
+                tel = self.telemetry
+                steps_cap, with_masks, n_steps, args = self._staged_args(
+                    xs, ys, steps, fm, lm, real_batches)
+                fn = self._staged_executable(steps_cap, with_masks,
+                                             tel is not None, args)
+            slots, batch = int(xs.shape[0]), int(xs.shape[1])
+            dispatch.args.update(steps=int(n_steps), slots=slots, batch=batch)
+            t0 = time.perf_counter()
+            with span("dl4j.fit.launch"):
+                out = fn(*args)
+            mvecs = None
+            if tel is not None:
+                (self.params, self.opt_state, self.state, self._rng,
+                 losses, mvecs) = out
+            else:
+                self.params, self.opt_state, self.state, self._rng, losses = out
+            # host fetch = the sync point; the tail of the buffer (beyond
+            # n_steps) is sliced off HOST-side — a device-side slice would
+            # compile a tiny program per distinct step count. The loop
+            # stacked per-step metrics; ONE more (already-computed) fetch
+            # brings the whole window — never a per-step sync
+            with span("dl4j.fit.fetch"):
+                losses = np.asarray(losses)[:n_steps]
+                if mvecs is not None:
+                    mvecs = np.asarray(mvecs)[:n_steps]
+            elapsed = time.perf_counter() - t0
+            if tel is not None:
+                if tel.flight is not None:
+                    # ring the dispatch BEFORE on_staged reads the metrics —
+                    # an anomaly found there auto-dumps, and the bundle
+                    # should already show what was dispatched
+                    tel.flight.record(
+                        "staged_dispatch", net="mln", steps=int(n_steps),
+                        slots=slots, batch=batch, seconds=round(elapsed, 6))
+                tel.on_staged(self.iteration + 1, mvecs,
+                              per_step_time_s=elapsed / max(len(losses), 1))
+            self.last_batch_size = batch
+            self.staged_steps_total += len(losses)
+            # replayed callbacks arrive in a tight host loop; wall-clock
+            # deltas between them measure nothing, so publish the dispatch's
+            # even per-step share for throughput listeners
+            # (PerformanceListener)
+            self.staged_step_time = elapsed / max(len(losses), 1)
+            with span("dl4j.fit.listeners"):
+                try:
+                    for loss in losses:
+                        self.iteration += 1
+                        self._last_loss = loss
+                        for lst in self.listeners:
+                            lst.iteration_done(self, self.iteration, loss)
+                finally:
+                    self.staged_step_time = None
         return losses
 
     def fit(self, data, epochs: int = 1,
@@ -892,7 +921,8 @@ class MultiLayerNetwork:
         ls = getattr(self.conf, "loss_scale", None)
         back_len = int(self.conf.tbptt_back_length or 0)
 
-        def step(params, opt_state, state, rnn, x, y, rng, labels_mask, features_mask):
+        def dl4j_mln_tbptt_step(params, opt_state, state, rnn, x, y, rng,
+                                labels_mask, features_mask):
             seg_len = x.shape[1]
             k = seg_len if back_len <= 0 else min(back_len, seg_len)
             if k < seg_len:
@@ -937,7 +967,7 @@ class MultiLayerNetwork:
             new_rnn = jax.lax.stop_gradient(new_rnn)
             return new_params, new_opt, new_state, new_rnn, loss
 
-        return jax.jit(self._kernel_scoped(step))
+        return jax.jit(self._kernel_scoped(dl4j_mln_tbptt_step))
 
     def _fit_tbptt(self, ds) -> None:
         """Truncated BPTT over time segments (reference: doTruncatedBPTT:1080).

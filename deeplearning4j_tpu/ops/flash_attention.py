@@ -202,6 +202,7 @@ def _flash_call(q, k, v, mask, causal, scale, block_q, block_k):
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v, mask)
 
 
@@ -229,6 +230,7 @@ def _flash_bwd(causal, scale, block_q, block_k, residuals, g):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, mask, g, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q, causal, scale),
@@ -251,6 +253,7 @@ def _flash_bwd(causal, scale, block_q, block_k, residuals, g):
             jax.ShapeDtypeStruct((bh, t, d), v.dtype),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, mask, g, lse, delta)
     return dq, dk, dv, None
 

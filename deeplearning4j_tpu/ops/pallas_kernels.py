@@ -178,6 +178,7 @@ def _cell_fwd_impl(zx, h_prev, c_prev, RW, pF, pI, pO, act_name, gate_name):
         kernel,
         out_shape=tuple(shapes),
         interpret=_interpret(),
+        name="lstm_cell_fwd",
     )(zx, h_prev, c_prev, RW, *_rows(pF, pI, pO))
 
 
@@ -212,6 +213,7 @@ def _cell_bwd(act_name, gate_name, residuals, grads):
         kernel,
         out_shape=out_shape,
         interpret=_interpret(),
+        name="lstm_cell_bwd",
     )(a, f, o, i, cact, c_prev, c, h_prev, RW, *_rows(pF, pI, pO), dh, dc)
     return (*grads, dpF[0], dpI[0], dpO[0])
 
@@ -306,6 +308,7 @@ def _lrn_fwd_impl(x, k, n, alpha, beta):
         out_specs=(out_spec, out_spec),
         out_shape=(jax.ShapeDtypeStruct(x2.shape, x2.dtype),) * 2,
         interpret=_interpret(),
+        name="lrn_fwd",
     )(x2)
     return y.reshape(x.shape), d
 
@@ -329,6 +332,7 @@ def _lrn_bwd(k, n, alpha, beta, residuals, g):
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
         interpret=_interpret(),
+        name="lrn_bwd",
     )(x2, d, g2)
     return (dx.reshape(x.shape),)
 
@@ -532,6 +536,7 @@ def _seq_lean_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name):
         ),
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
         interpret=_interpret(),
+        name="lstm_seq_lean",
     )(*args)
 
 
@@ -574,6 +579,7 @@ def _seq_fwd_impl(zx, h0, c0, RW, pF, pI, pO, act_name, gate_name):
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
         interpret=_interpret(),
+        name="lstm_seq_fwd",
     )(zx, h0, c0, RW, *_rows(pF, pI, pO))
 
 
@@ -644,6 +650,7 @@ def _seq_bwd(act_name, gate_name, residuals, grads):
             pltpu.VMEM((1, H), jnp.float32),
         ],
         interpret=_interpret(),
+        name="lstm_seq_bwd",
     )(dys, dhT, dcT, a, f, o, i, c, ys, RW, *_rows(pF, pI, pO), h0, c0)
     return dzx, dh0, dc0, dRW, dpF[0], dpI[0], dpO[0]
 
@@ -801,6 +808,7 @@ def _seq_masked_fwd_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
         interpret=_interpret(),
+        name="lstm_seq_masked_fwd",
     )(zx, mask.astype(dt), h0, c0, RW, *_rows(pF, pI, pO))
 
 
@@ -872,6 +880,7 @@ def _seq_masked_bwd(act_name, gate_name, residuals, grads):
             pltpu.VMEM((1, H), jnp.float32),
         ],
         interpret=_interpret(),
+        name="lstm_seq_masked_bwd",
     )(dys, dhT, dcT, mask.astype(dt), a, f, o, i, c, ys,
       RW, *_rows(pF, pI, pO), h0, c0)
     return dzx, None, dh0, dc0, dRW, dpF[0], dpI[0], dpO[0]
@@ -975,6 +984,7 @@ def _sxent_fwd_impl(preout, labels):
         out_specs=col,
         out_shape=jax.ShapeDtypeStruct((N, 1), _sxent_compute_dt(preout.dtype)),
         interpret=_interpret(),
+        name="softmax_xent_fwd",
     )(preout, labels)
     return out[:, 0]
 
@@ -998,6 +1008,7 @@ def _sxent_bwd(residuals, g):
         out_shape=(jax.ShapeDtypeStruct((N, C), preout.dtype),
                    jax.ShapeDtypeStruct((N, C), labels.dtype)),
         interpret=_interpret(),
+        name="softmax_xent_bwd",
     )(preout, labels, g2)
     return dx, dl
 
@@ -1076,6 +1087,7 @@ def fused_adam_update(g, m, v, lr, bc1, bc2,
         out_specs=(mat, mat, mat),
         out_shape=(jax.ShapeDtypeStruct((rows, cols), dt),) * 3,
         interpret=_interpret(),
+        name="adam_update",
     )(flat(g), flat(m), flat(v), scalars)
 
     def unflat(a):

@@ -25,6 +25,7 @@ import time
 from typing import Dict, List, Optional
 
 from .optimize.listeners import TrainingListener
+from .telemetry.spans import span
 
 # env override of the attached chip's peak bf16 TFLOP/s for MFU math; unset,
 # the peak comes from the one table (analysis.cost_model.DEVICE_PEAKS)
@@ -86,11 +87,16 @@ class StepTimer:
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+        """Time one phase; it is also the span ``dl4j.<component>.<name>``
+        (telemetry.spans), so it lies on the device trace's clock whenever
+        a profiler capture is running."""
+        parts = ("dl4j", self._component, name)
+        with span(".".join(p for p in parts if p)):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(name, time.perf_counter() - t0)
 
     def tick(self, name: str) -> None:
         self.tock()

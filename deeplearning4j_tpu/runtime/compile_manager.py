@@ -41,6 +41,8 @@ import time
 from collections import OrderedDict
 from typing import Any, Callable, Optional, Tuple
 
+from ..telemetry.spans import span
+
 __all__ = [
     "CompileManager",
     "get_compile_manager",
@@ -260,6 +262,16 @@ class CompileManager:
             return {f"{self._key_kind(k)}#{i}": dict(rec)
                     for i, (k, rec) in enumerate(self._memory.items())}
 
+    def program_texts(self) -> list:
+        """The optimized HLO text of every live AOT executable, rendered on
+        demand (nothing is kept: the executables are). Each instruction's
+        ``metadata={op_name=...}`` carries the ``jax.named_scope`` path of
+        its layer, which the device trace's events do not: a trace reader
+        joins the two on the instruction's text."""
+        with self._lock:
+            entries = list(self._entries.values())
+        return [e.as_text() for e in entries if hasattr(e, "as_text")]
+
     def cost_records(self) -> dict:
         """{key label: static_cost report} for every live AOT entry — the
         roofline twin of :meth:`memory_records` (same labeling scheme)."""
@@ -426,11 +438,22 @@ class CompileManager:
         """AOT-compile ``jitted`` against ``args`` (counted + timed), take
         its memory record, and run the admission analysis. Returns
         ``(compiled, seconds, memory_record, cost_or_None)``."""
+        kind = self._key_kind(key)
         t0 = time.perf_counter()
-        compiled = jitted.lower(*args).compile()
+        with span("dl4j.cm.lower", kind=kind):  # trace + lowering
+            lowered = jitted.lower(*args)
+        with span("dl4j.cm.compile", kind=kind):  # backend compile or
+            compiled = lowered.compile()          # persistent-cache load
         seconds = time.perf_counter() - t0
         self.compile_time.observe(seconds)
         self.compiles.inc()
+        with span("dl4j.cm.admission", kind=kind):
+            record, cost = self._admit(key, jitted, compiled, args)
+        return compiled, seconds, record, cost
+
+    def _admit(self, key, jitted, compiled, args):
+        """The memory record and the admission analysis of one freshly
+        compiled executable: ``(memory_record, cost_or_None)``."""
         # static HBM accounting from the compiler itself — every admitted
         # executable carries a memory_analysis record (or an explicit
         # "unavailable on this backend" flag), see telemetry/memory.py
@@ -469,7 +492,7 @@ class CompileManager:
             except Exception as e:
                 cost = None
                 self._admission_failed(key, "admission_check", e)
-        return compiled, seconds, record, cost
+        return record, cost
 
     def aot(self, key: Tuple, build: Callable[[], Any], args) -> Any:
         """Compiled executable for ``key``; on miss, ``build()`` must return
@@ -482,7 +505,8 @@ class CompileManager:
             return entry
         if os.environ.get(IR_CHECKS_ENV, "1") != "0":
             try:  # analysis must never break compilation
-                self._check_arg_shardings(key, args)
+                with span("dl4j.cm.admission", kind=self._key_kind(key)):
+                    self._check_arg_shardings(key, args)
             except Exception as e:
                 self._admission_failed(key, "arg_shardings", e)
         # kernel-selection hook: variants are resolved by ops.kernel_select

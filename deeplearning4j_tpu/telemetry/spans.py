@@ -1,33 +1,39 @@
-"""Host-side spans with Chrome/Perfetto trace export.
+"""Host-side spans: the program's one span primitive.
 
-A :class:`Span` measures one named host-side region (data load, dispatch,
-sync, checkpoint write). Two properties make it TPU-honest:
+A :class:`Span` measures one named host-side region (a dispatch, its
+prepare/launch/fetch phases, a compile, ``net.init()``). Names used by the
+program start with ``dl4j.`` (the list is in ``docs/observability.md``).
+Every span is recorded three ways, each on one clock:
 
-- Entering a span also enters ``jax.profiler.TraceAnnotation(name)``, so when
-  a ``jax.profiler.trace`` capture is active the host span appears in the
-  SAME xplane timeline as the XLA device slices it encloses — host and device
-  views line up instead of living in two disconnected tools.
-- Closing a span never syncs the device: it records wall-clock enqueue time.
-  Under async dispatch a span around an un-synced jit call measures dispatch,
-  not execution — wrap the sync point (the host fetch) in its own span when
-  execution time is what you want.
+- ``jax.profiler.TraceAnnotation(name)``: whenever a profiler capture is
+  running, the span lies in the SAME xplane timeline as the XLA device
+  slices it encloses, so device-idle time can be attributed to it.
+- an in-memory Chrome trace event in the :class:`SpanRecorder` ring
+  (``ph: "X"``, microseconds; ``ts`` and ``dur`` both from
+  ``time.perf_counter``) carrying ``parent`` — the name of the span that
+  encloses it on the same thread — and ``dispatch`` — the identifier of the
+  root span it descends from (one number per ``fit_on_device`` call, shared
+  by all its children). Both come from a thread-local stack.
+- seconds and count by name in the default registry's
+  ``dl4jtpu_span_seconds{name=...}`` histogram, which is where runs without
+  a profiler (set-up is never inside a traced window) read them, and where
+  ``/metrics`` scrapes them.
 
-Completed spans land in a :class:`SpanRecorder` ring buffer and export as
-Chrome trace-event JSON (``chrome://tracing`` / Perfetto "trace event"
-format): complete events (``ph: "X"``), microsecond timestamps, pid/tid, and
-user args. Durations optionally feed a registry histogram
-(``dl4jtpu_span_seconds{name=...}``) so span timing is also scrapeable.
+Closing a span never syncs the device: it records wall-clock enqueue time.
+Under async dispatch a span around an un-synced jit call measures dispatch,
+not execution — the sync point (the host fetch) has its own span.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from typing import List, Optional
 
-from .registry import MetricsRegistry
+from .registry import get_registry
 
 
 class SpanRecorder:
@@ -78,23 +84,43 @@ def get_recorder() -> SpanRecorder:
     return _GLOBAL_RECORDER
 
 
-class Span:
-    """One named region; context manager or explicit ``start()``/``stop()``."""
+_STACK = threading.local()     # .spans: the open spans of this thread
+_ROOT_IDS = itertools.count(1)  # one per root span (next() is atomic)
 
-    def __init__(self, name: str, recorder: Optional[SpanRecorder] = None,
-                 registry: Optional[MetricsRegistry] = None, **args):
+
+def _open_spans() -> list:
+    try:
+        return _STACK.spans
+    except AttributeError:
+        _STACK.spans = []
+        return _STACK.spans
+
+
+class Span:
+    """One named region; context manager or explicit ``start()``/``stop()``.
+
+    After ``start()``, ``parent`` is the enclosing span's name (None for a
+    root) and ``dispatch`` the root's identifier. ``args`` may be added to
+    until ``stop()``; they land in the in-memory event."""
+
+    def __init__(self, name: str, **args):
         self.name = str(name)
-        self.recorder = recorder if recorder is not None else _GLOBAL_RECORDER
-        self._registry = registry
-        self.args = {k: v for k, v in args.items()}
+        self.args = dict(args)
+        self.parent: Optional[str] = None
+        self.dispatch: Optional[int] = None
         self._annotation = None
         self._t0: Optional[float] = None
-        self._ts_us: Optional[float] = None
         self.duration_s: Optional[float] = None
 
     def start(self) -> "Span":
         if self._t0 is not None:
             raise RuntimeError(f"span {self.name!r} already started")
+        stack = _open_spans()
+        if stack:
+            self.parent, self.dispatch = stack[-1].name, stack[-1].dispatch
+        else:
+            self.parent, self.dispatch = None, next(_ROOT_IDS)
+        stack.append(self)
         try:
             import jax  # noqa: PLC0415 - keep telemetry importable without jax
 
@@ -102,37 +128,37 @@ class Span:
             self._annotation.__enter__()
         except Exception:
             self._annotation = None  # no profiler backend: host-only span
-        self._ts_us = time.time() * 1e6
         self._t0 = time.perf_counter()
         return self
 
     def stop(self) -> float:
         if self._t0 is None:
             raise RuntimeError(f"span {self.name!r} was never started")
-        dur = time.perf_counter() - self._t0
-        self._t0 = None
+        t0, self._t0 = self._t0, None
+        dur = time.perf_counter() - t0
         if self._annotation is not None:
             try:
                 self._annotation.__exit__(None, None, None)
             finally:
                 self._annotation = None
+        stack = _open_spans()
+        if self in stack:  # with it goes what it left open, if closed out of order
+            del stack[stack.index(self):]
         self.duration_s = dur
-        event = {
+        _GLOBAL_RECORDER.add({
             "name": self.name,
             "ph": "X",
-            "ts": self._ts_us,
+            "ts": t0 * 1e6,
             "dur": dur * 1e6,
             "pid": os.getpid(),
             "tid": threading.get_ident(),
-        }
-        if self.args:
-            event["args"] = self.args
-        self.recorder.add(event)
-        if self._registry is not None:
-            self._registry.histogram(
-                "dl4jtpu_span_seconds", "host span durations",
-                labelnames=("name",),
-            ).labels(name=self.name).observe(dur)
+            "args": dict(self.args, parent=self.parent,
+                         dispatch=self.dispatch),
+        })
+        get_registry().histogram(
+            "dl4jtpu_span_seconds", "host span durations",
+            labelnames=("name",),
+        ).labels(name=self.name).observe(dur)
         return dur
 
     def __enter__(self) -> "Span":
@@ -142,7 +168,6 @@ class Span:
         self.stop()
 
 
-def span(name: str, recorder: Optional[SpanRecorder] = None,
-         registry: Optional[MetricsRegistry] = None, **args) -> Span:
-    """``with span("data_load", batch=i): ...`` — the usual entry point."""
-    return Span(name, recorder=recorder, registry=registry, **args)
+def span(name: str, **args) -> Span:
+    """``with span("dl4j.fit.launch"): ...`` — the usual entry point."""
+    return Span(name, **args)

@@ -22,9 +22,9 @@ from deeplearning4j_tpu import (
 from deeplearning4j_tpu.telemetry import (
     NAN_LOSS,
     MetricsRegistry,
-    SpanRecorder,
     Telemetry,
     Watchdog,
+    get_recorder,
     get_registry,
     span,
 )
@@ -149,40 +149,81 @@ class TestPrometheusExposition:
 # --------------------------------------------------------------------------
 # spans
 # --------------------------------------------------------------------------
+def _own_events(prefix):
+    """Events of the process-wide recorder whose names start with
+    ``prefix`` (each test uses a prefix of its own)."""
+    return [e for e in get_recorder().events if e["name"].startswith(prefix)]
+
+
+def _span_family():
+    return get_registry().get("dl4jtpu_span_seconds")
+
+
 class TestSpans:
     def test_chrome_trace_round_trip(self, tmp_path):
-        rec = SpanRecorder()
-        with span("outer", recorder=rec, step=1):
-            with span("inner", recorder=rec):
+        with span("rt.outer", step=1):
+            with span("rt.inner"):
                 pass
-        path = rec.export_chrome_trace(str(tmp_path / "trace.json"))
+        path = get_recorder().export_chrome_trace(str(tmp_path / "trace.json"))
         with open(path) as fh:
             doc = json.load(fh)
-        events = doc["traceEvents"]
-        assert [e["name"] for e in events] == ["inner", "outer"]  # close order
+        events = [e for e in doc["traceEvents"] if e["name"].startswith("rt.")]
+        assert [e["name"] for e in events] == ["rt.inner", "rt.outer"]  # close order
         for e in events:
             assert e["ph"] == "X" and e["dur"] >= 0 and e["pid"] > 0
         inner, outer = events
-        # the inner span nests inside the outer's [ts, ts+dur] window
+        # ts and dur are one clock (perf_counter): the inner span nests
+        # inside the outer's [ts, ts+dur] window exactly
         assert outer["ts"] <= inner["ts"]
-        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1.0
-        assert events[1]["args"] == {"step": 1}
+        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+        assert outer["args"]["step"] == 1
+        # the record: the enclosing span's name, and the root's identifier
+        assert inner["args"]["parent"] == "rt.outer"
+        assert outer["args"]["parent"] is None
+        assert inner["args"]["dispatch"] == outer["args"]["dispatch"]
 
     def test_span_registry_histogram(self):
-        r = MetricsRegistry()
-        with span("phase_x", recorder=SpanRecorder(), registry=r):
+        """The default registry's dl4jtpu_span_seconds{name} is the store of
+        seconds and count by name."""
+        before = _span_family().labels(name="hist.phase_x").summary() \
+            if _span_family() is not None else {"count": 0, "sum": 0.0}
+        with span("hist.phase_x") as sp:
             pass
-        fam = r.get("dl4jtpu_span_seconds")
-        assert fam.labels(name="phase_x").count == 1
+        after = _span_family().labels(name="hist.phase_x").summary()
+        assert after["count"] == before["count"] + 1
+        assert after["sum"] - before["sum"] == pytest.approx(sp.duration_s,
+                                                             abs=1e-8)
 
     def test_explicit_start_stop_and_misuse(self):
-        rec = SpanRecorder()
-        s = span("manual", recorder=rec)
+        s = span("manual.one")
         s.start()
         assert s.stop() >= 0
         with pytest.raises(RuntimeError):
             s.stop()  # double stop
-        assert len(rec.events) == 1
+        assert len(_own_events("manual.")) == 1
+
+    def test_roots_get_distinct_dispatch_ids_per_thread_stack(self):
+        """Two roots never share an identifier; a span opened on another
+        thread is a root of its own, whatever is open here."""
+        import threading
+
+        seen = {}
+
+        def other():
+            with span("ids.thread") as sp:
+                seen["thread"] = (sp.parent, sp.dispatch)
+
+        with span("ids.a") as a:
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+        with span("ids.b") as b:
+            with span("ids.child") as child:
+                pass
+        assert seen["thread"][0] is None
+        assert len({a.dispatch, b.dispatch, seen["thread"][1]}) == 3
+        assert (child.parent, child.dispatch) == ("ids.b", b.dispatch)
 
     def test_span_wraps_device_work_in_profiler_trace(self, tmp_path):
         """Host spans enter jax.profiler.TraceAnnotation: under an active
@@ -200,7 +241,7 @@ class TestSpans:
         a = jnp.ones((64, 64))
         f(a)  # compile outside the capture
         with profiler.trace(logdir):
-            with span("telemetry_step_span", recorder=SpanRecorder()):
+            with span("telemetry_step_span"):
                 np.asarray(f(a))
         found = [os.path.join(d, fn) for d, _, fs in os.walk(logdir)
                  for fn in fs]
