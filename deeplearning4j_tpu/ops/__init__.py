@@ -77,8 +77,8 @@ def lstm_helper_enabled() -> bool:
 
 def lstm_sequence_enabled() -> bool:
     """The time-fused whole-sequence kernel (fused_lstm_sequence): grid over
-    T with h/c carried in VMEM scratch — the multi-step fusion the cell
-    docstring anticipates.
+    blocks of time steps with h/c carried in VMEM scratch — the multi-step
+    fusion the cell docstring anticipates.
 
     DEFAULT ON for TPU (measured, v5e char-RNN bench B=64 H=512 T=256:
     3.10M chars/sec median seq-fused vs 1,489,072 scan — 2.1x; probe steps

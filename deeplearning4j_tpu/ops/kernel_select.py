@@ -112,6 +112,8 @@ class Variant:
     scoring. ``cost`` returns (flops, hbm_bytes, overhead_seconds) for the
     ctx; ``unfused_bytes`` marks estimates produced by the cost model's
     un-fused counting, which the measured calibration factor discounts.
+    ``detail`` returns what else the selection record says of the variant at
+    this ctx (the seq-fused LSTM's ``time_block``).
     """
 
     name: str
@@ -120,6 +122,7 @@ class Variant:
     available: Callable[[dict], bool] = lambda ctx: True
     auto_gate: Callable[[dict], bool] = lambda ctx: True
     unfused_bytes: bool = False
+    detail: Callable[[dict], dict] = lambda ctx: {}
 
 
 @dataclass
@@ -487,7 +490,7 @@ def select(site_name: str, ctx: dict, forced: Optional[str] = None) -> str:
         reason = "fallback"
 
     record = {"site": site_name, "variant": choice, "reason": reason,
-              "ctx": dict(ctx), "mode": m}
+              "ctx": dict(ctx), "mode": m, **site.variants[choice].detail(ctx)}
     if infeasible:
         record["infeasible"] = infeasible
     if predicted is not None:
@@ -594,6 +597,15 @@ def _seq_fits_ctx(ctx) -> bool:
                                               ctx["itemsize"])
 
 
+def _seq_detail(ctx) -> dict:
+    """Time steps a grid step the seq kernels take at these shapes; 1 means
+    the blocking did not engage."""
+    from .pallas_kernels import _seq_time_block  # noqa: PLC0415
+
+    return {"time_block": _seq_time_block(ctx["T"], ctx["B"], ctx["H"],
+                                          ctx["itemsize"])}
+
+
 def _cell_fits_ctx(ctx) -> bool:
     from . import _cell_fits  # noqa: PLC0415
 
@@ -608,7 +620,7 @@ register_site(Site(
     variants={
         "seqfused": Variant("seqfused", fused=True,
                             cost=_lstm_seqfused_cost,
-                            available=_seq_fits_ctx),
+                            available=_seq_fits_ctx, detail=_seq_detail),
         "fusedcell": Variant("fusedcell", fused=True,
                              cost=_lstm_fusedcell_cost,
                              available=_cell_fits_ctx),

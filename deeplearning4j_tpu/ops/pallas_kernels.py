@@ -345,157 +345,158 @@ fused_lrn.defvjp(_lrn_fwd, _lrn_bwd)
 # ---------------------------------------------------------------------------
 #
 # The per-step fused cell above loses to XLA's scan on TPU because its custom
-# VJP spills 7 residual arrays to HBM every step. This kernel fuses the WHOLE
-# time loop instead: grid=(T,) executes sequentially on TPU, h/c live in VMEM
-# scratch across grid steps, RW stays VMEM-resident, and only the 5 residual
-# tensors cuDNN also reserves (gate activations + cell state) stream out —
-# c_{t-1}/h_{t-1} are re-read in the backward via shifted block indices
-# rather than stored twice. Select with DL4J_TPU_PALLAS=seq (measured winner
-# becomes the default).
+# VJP spills 7 residual arrays to HBM every step. These kernels fuse the WHOLE
+# time loop instead, a block of ``Tc`` time steps a grid step: the grid
+# ``(T // Tc,)`` executes sequentially on TPU, every streamed operand is a
+# ``(Tc, B, ·)`` block, the recurrence runs as an unrolled loop over the block,
+# h/c (dh/dc) live in VMEM scratch across grid steps, RW stays VMEM-resident,
+# and only the 5 residual tensors cuDNN also reserves (gate activations + cell
+# state) stream out. What the recurrence does not order is done once a block:
+# the backward's ``dRW += h_prev^T @ dzx`` contracts over the block's ``Tc*B``
+# rows (at B=64 one step half fills the MXU's contraction depth, and the
+# [H, 4H] f32 accumulator would go through VMEM every step), the peephole sums
+# are reduced over rows once a block, and the constant-index outputs (h_T/c_T;
+# dh0/dc0/dRW/dp*) are written on the last grid step only. h_{t-1}/c_{t-1} are
+# the block's own ys/c rows shifted by one; the row before the block comes from
+# a one-step block of ys/c at ``t0 - 1`` (``h0``/``c0`` at ``t0 == 0``).
+# ``Tc == 1`` is the one-step-a-grid-step kernel this grew from.
+#
+# VMEM (``_seq_footprint`` reckons the backward, the larger of the two): the
+# streamed blocks are double-buffered — 2 x Tc x (7 BH + 4 BH) elements of dy,
+# a, f, o, i, c, ys in and dzx out — the constant-index operands (RW, dRW, the
+# [B, H] states) are single-buffered (``pl.Buffered(1)``), and the f32 [H, 4H]
+# accumulator plus the block matmul's f32 product sit beside them: 26 MiB at
+# Tc=8 for B=64 H=512 bf16 (43 MiB at f32), over the compiler's 16 MiB
+# default, so every call states its ``vmem_limit_bytes`` from the same
+# reckoning. The budget is the smaller of ``_SEQ_VMEM_BUDGET_BYTES`` and half
+# the platform's VMEM (128 MiB a core on the v5e): ``_seq_time_block`` takes the
+# largest block under it, ``_seq_fits`` (kernel selection's hard check) gives
+# way to the scan where not even one step fits.
 
-_SEQ_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+_SEQ_VMEM_BUDGET_BYTES = 64 * 1024 * 1024
+# Tc*B >= 128 fills the MXU's contraction depth; beyond that a longer block
+# only spreads the grid step's fixed cost thinner. Measured on the v5e (B=64
+# T=256 H=512 bf16, PERF.md PR 27): the backward reads 0.76 ms an event at a
+# block of 1, 0.52 at 2, 0.50 at 4, 0.49 at 8, 0.50 at 16 and 0.62 at 32, and
+# the unrolled body's compile time doubles with the block
+_SEQ_MAX_TIME_BLOCK = 8
+_SEQ_MIN_VMEM_LIMIT_BYTES = 16 * 1024 * 1024  # the compiler's own default
+
+
+def _seq_vmem_budget() -> int:
+    budget = _SEQ_VMEM_BUDGET_BYTES
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+        budget = min(budget, pltpu.get_tpu_info().vmem_capacity_bytes // 2)
+    return budget
+
+
+def _seq_footprint(Tc: int, B: int, H: int, itemsize: int) -> int:
+    """VMEM bytes of the BACKWARD kernel at a block of ``Tc`` steps; the
+    forward (10 streamed [B, H] a step, no accumulator) is strictly smaller.
+    An upper bound: it counts every value the body names as if VMEM held it,
+    and the v5e's compiler takes this kernel under 0.6 of it (B=64 H=512 bf16,
+    a block of 8: accepted at a 15.8 MiB limit, refused at 13.2)."""
+    row, mat = B * H, H * 4 * H
+    streamed = (Tc * 11 * row          # dy a f o i c ys in, dzx [B, 4H] out
+                + Tc * B * 128         # mask, one lane tile a row
+                + 2 * row) * itemsize  # ys/c at t0 - 1
+    constant = (2 * mat + 6 * row) * itemsize   # RW, dRW; dhT dcT h0 c0 dh0 dc0
+    scratch = mat * 4 + 2 * row * itemsize      # f32 dRW accumulator; dh/dc
+    # values the body holds: the block matmul's f32 product, its [Tc*B, H]
+    # lhs, a step's f32 gate block and matmul results, three peephole partials
+    working = mat * 4 + Tc * row * itemsize + (3 * 4 * row + 3 * row) * 4
+    return 2 * streamed + constant + scratch + working
 
 
 def _seq_fits(B: int, H: int, itemsize: int) -> bool:
-    # Model the BACKWARD kernel — its footprint dominates: RW plus the f32
-    # (H, 4H) dRW accumulator are resident, dh/dc carries in scratch, and
-    # per-step it streams dy + 5 residuals + c_prev/h_prev + dzx blocks
-    # (double-buffered). The forward (RW + 2 carries + 7 streamed blocks)
-    # is strictly smaller.
-    resident = (H * 4 * H * itemsize      # RW
-                + H * 4 * H * 4           # f32 dRW accumulator
-                + 2 * B * H * itemsize    # dh/dc carries
-                + 3 * H * 4)              # peephole accumulators
-    streamed = 2 * (8 * B * H + B * 4 * H) * itemsize
-    return resident + streamed < _SEQ_VMEM_BUDGET_BYTES
+    return _seq_footprint(1, B, H, itemsize) <= _seq_vmem_budget()
+
+
+def _seq_time_block(T: int, B: int, H: int, itemsize: int) -> int:
+    """Time steps a grid step of the seq kernels: the largest divisor of ``T``
+    not above ``_SEQ_MAX_TIME_BLOCK`` whose footprint fits the VMEM budget; 1
+    where none does, or where ``B`` rows are not whole sublane tiles (the block
+    matmul collapses ``(Tc, B)`` into rows, free only on tile boundaries)."""
+    if B % max(32 // itemsize, 1):
+        return 1
+    budget = _seq_vmem_budget()
+    for tc in range(min(T, _SEQ_MAX_TIME_BLOCK), 1, -1):
+        if T % tc == 0 and _seq_footprint(tc, B, H, itemsize) <= budget:
+            return tc
+    return 1
+
+
+def _seq_compiler_params(Tc, B, H, itemsize):
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=max(_seq_footprint(Tc, B, H, itemsize),
+                             _SEQ_MIN_VMEM_LIMIT_BYTES))
+
+
+def _seq_specs(Tc, B):
+    """BlockSpec makers shared by the seq kernels: a ``(Tc, B, w)`` block of a
+    streamed operand under ``index_map``, and a whole constant-index operand
+    (fetched once, so single-buffered)."""
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+
+    def block(w, index_map, steps=Tc):
+        return pl.BlockSpec((steps, B, w), index_map)
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda k: (0,) * len(shape),
+                            pipeline_mode=pl.Buffered(1))
+
+    return block, whole
 
 
 @jit_entry
-def _seq_fwd_kernel(act, gate,
-                    zx_ref, h0_ref, c0_ref, rw_ref, pf_ref, pi_ref, po_ref,
-                    y_out, a_out, f_out, o_out, i_out, c_out, hT_out, cT_out,
-                    h_scr, c_scr):
+def _seq_fwd_kernel(act, gate, Tc, masked, residuals, *refs):
+    """Forward of ``Tc`` steps a grid step. ``masked`` adds the [Tc, B, 1]
+    mask operand (masked steps hold h/c); ``residuals`` adds the five gate
+    outputs the backward reads (the lean primal emits ys/hT/cT only)."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
 
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_scr[:] = h0_ref[:]
-        c_scr[:] = c0_ref[:]
-
-    h, c, a, f, o, i, _cact = _cell_math(
-        zx_ref[0], h_scr[:], c_scr[:], rw_ref[:],
-        pf_ref[:], pi_ref[:], po_ref[:], act, gate,
-    )
-    y_out[0], a_out[0], f_out[0], o_out[0], i_out[0], c_out[0] = h, a, f, o, i, c
-    h_scr[:], c_scr[:] = h, c
-    # constant-index outputs: written every step, the last write is h_T/c_T
-    hT_out[:], cT_out[:] = h, c
-
-
-@jit_entry
-def _seq_bwd_kernel(act, dact, dgate, T,
-                    dy_ref, dhT_ref, dcT_ref,
-                    a_ref, f_ref, o_ref, i_ref, cprev_ref, hprev_ref,
-                    rw_ref, pf_ref, pi_ref, po_ref, h0_ref, c0_ref,
-                    dzx_out, dh0_out, dc0_out, drw_out, dpf_out, dpi_out,
-                    dpo_out,
-                    dh_scr, dc_scr, drw_scr, dpf_scr, dpi_scr, dpo_scr):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-
-    k = pl.program_id(0)          # reverse-time grid: time t = T-1-k
+    it = iter(refs)
+    zx_ref = next(it)
+    m_ref = next(it) if masked else None  # static — dl4jtpu: ignore[DT104]
+    h0_ref, c0_ref, rw_ref, pf_ref, pi_ref, po_ref, y_out = (
+        next(it) for _ in range(7))
+    res_out = [next(it) for _ in range(5 if residuals else 0)]  # a f o i c
+    hT_out, cT_out, h_scr, c_scr = it
+    k = pl.program_id(0)
 
     @pl.when(k == 0)
     def _init():
-        dh_scr[:] = dhT_ref[:]
-        dc_scr[:] = dcT_ref[:]
-        drw_scr[:] = jnp.zeros(drw_scr.shape, drw_scr.dtype)
-        dpf_scr[:] = jnp.zeros(dpf_scr.shape, dpf_scr.dtype)
-        dpi_scr[:] = jnp.zeros(dpi_scr.shape, dpi_scr.dtype)
-        dpo_scr[:] = jnp.zeros(dpo_scr.shape, dpo_scr.dtype)
+        h_scr[:] = h0_ref[:]
+        c_scr[:] = c0_ref[:]
 
-    a, f, o, i = a_ref[0], f_ref[0], o_ref[0], i_ref[0]
-    first = k == T - 1            # t == 0: previous state is the initial one
-    c_prev = jnp.where(first, c0_ref[:], cprev_ref[0])
-    h_prev = jnp.where(first, h0_ref[:], hprev_ref[0])
-    # c_t recomputed from the gates (VPU-cheap) — only the prev-indexed c
-    # stream is read, saving a T×B×H HBM stream (same as the masked kernel)
-    c = f * c_prev + i * a
-    cact = act(c)                 # recomputed, not stored
     pF, pI, pO = pf_ref[:], pi_ref[:], po_ref[:]
-
-    dh = dy_ref[0] + dh_scr[:]
-    dc = dc_scr[:]
-    do = dh * cact * dgate(o)
-    dc_tot = dc + dh * o * dact(cact) + do * pO
-    df = dc_tot * c_prev * dgate(f)
-    di = dc_tot * a * dgate(i)
-    da = dc_tot * i * dact(a)
-    dzx = jnp.concatenate([da, df, do, di], axis=-1)
-    dzx_out[0] = dzx
-    dh_scr[:] = jnp.dot(
-        dzx, rw_ref[:].T, preferred_element_type=_acc_dtype(dzx.dtype)
-    ).astype(dzx.dtype)
-    dc_scr[:] = dc_tot * f + df * pF + di * pI
-    f32 = drw_scr.dtype
-    drw_scr[:] += jnp.dot(h_prev.T, dzx, preferred_element_type=f32)
-    dpf_scr[:] += jnp.sum(df * c_prev, axis=0, dtype=f32, keepdims=True)
-    dpi_scr[:] += jnp.sum(di * c_prev, axis=0, dtype=f32, keepdims=True)
-    dpo_scr[:] += jnp.sum(do * c, axis=0, dtype=f32, keepdims=True)
-    # constant-index outputs: last (t==0) write carries the full sums
-    dt = dzx.dtype
-    dh0_out[:] = dh_scr[:]
-    dc0_out[:] = dc_scr[:]
-    drw_out[:] = drw_scr[:].astype(dt)
-    dpf_out[:] = dpf_scr[:].astype(dt)
-    dpi_out[:] = dpi_scr[:].astype(dt)
-    dpo_out[:] = dpo_scr[:].astype(dt)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def fused_lstm_sequence(zx, h0, c0, RW, pF, pI, pO,
-                        act_name: str = "tanh", gate_name: str = "sigmoid"):
-    """Whole-sequence fused LSTM: ``zx`` [T, B, 4H] (precomputed x@W + b),
-    returns (ys [T, B, H], h_T, c_T). Unmasked, forward-direction.
-
-    The primal (inference) path runs a LEAN kernel that emits only
-    ys/hT/cT; the five gate residuals stream to HBM only under jax.grad
-    (the VJP's forward rule) where the backward actually consumes them."""
-    return _seq_lean_impl(zx, None, h0, c0, RW, pF, pI, pO,
-                          act_name, gate_name)
-
-
-@jit_entry
-def _seq_lean_kernel(act, gate, masked, *refs):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-
-    if masked:  # static via partial — dl4jtpu: ignore[DT104]
-        (zx_ref, m_ref, h0_ref, c0_ref, rw_ref, pf_ref, pi_ref, po_ref,
-         y_out, hT_out, cT_out, h_scr, c_scr) = refs
-    else:
-        (zx_ref, h0_ref, c0_ref, rw_ref, pf_ref, pi_ref, po_ref,
-         y_out, hT_out, cT_out, h_scr, c_scr) = refs
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_scr[:] = h0_ref[:]
-        c_scr[:] = c0_ref[:]
-
-    h_prev, c_prev = h_scr[:], c_scr[:]
-    h, c, *_ = _cell_math(zx_ref[0], h_prev, c_prev, rw_ref[:],
-                          pf_ref[:], pi_ref[:], po_ref[:], act, gate)
-    if masked:  # static via partial — dl4jtpu: ignore[DT104]
-        m = m_ref[0]
-        h = m * h + (1.0 - m) * h_prev
-        c = m * c + (1.0 - m) * c_prev
-    y_out[0] = h
+    h, c = h_scr[:], c_scr[:]
+    for j in range(Tc):
+        h_new, c_new, a, f, o, i, _cact = _cell_math(
+            zx_ref[j], h, c, rw_ref[:], pF, pI, pO, act, gate)
+        if masked:  # static via partial — dl4jtpu: ignore[DT104]
+            m = m_ref[j]
+            h_new = m * h_new + (1.0 - m) * h
+            c_new = m * c_new + (1.0 - m) * c
+        y_out[j] = h_new
+        for ref, v in zip(res_out, (a, f, o, i, c_new)):
+            ref[j] = v
+        h, c = h_new, c_new
     h_scr[:], c_scr[:] = h, c
-    hT_out[:], cT_out[:] = h, c
+
+    @pl.when(k == pl.num_programs(0) - 1)
+    def _last():
+        hT_out[:], cT_out[:] = h, c
 
 
-def _seq_lean_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name):
+def _seq_fwd_call(zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name,
+                  residuals: bool):
+    """ys, [a, f, o, i, c,] hT, cT of the whole sequence in one kernel."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
@@ -504,207 +505,44 @@ def _seq_lean_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name):
     T, B, H4 = zx.shape
     H = H4 // 4
     dt = zx.dtype
-    step = lambda t: (t, 0, 0)  # noqa: E731
-    const = lambda t: (0, 0)    # noqa: E731
-    in_specs = [pl.BlockSpec((1, B, H4), step)]
-    args = [zx]
-    if mask is not None:
-        in_specs.append(pl.BlockSpec((1, B, 1), step))
-        args.append(mask.astype(dt))
-    in_specs += [
-        pl.BlockSpec((B, H), const),
-        pl.BlockSpec((B, H), const),
-        pl.BlockSpec((H, H4), const),
-        pl.BlockSpec((1, H), lambda t: (0, 0)),
-        pl.BlockSpec((1, H), lambda t: (0, 0)),
-        pl.BlockSpec((1, H), lambda t: (0, 0)),
-    ]
-    args += [h0, c0, RW, *_rows(pF, pI, pO)]
+    Tc = _seq_time_block(T, B, H, dt.itemsize)
+    block, whole = _seq_specs(Tc, B)
+    fwd = lambda k: (k, 0, 0)  # noqa: E731
+    masked = mask is not None
+    n_seq = 6 if residuals else 1   # ys, then a f o i c
     return pl.pallas_call(
-        functools.partial(_seq_lean_kernel, act, gate, mask is not None),
-        grid=(T,),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, B, H), step),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((B, H), const),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((T, B, H), dt),
-            jax.ShapeDtypeStruct((B, H), dt),
-            jax.ShapeDtypeStruct((B, H), dt),
-        ),
+        functools.partial(_seq_fwd_kernel, act, gate, Tc, masked, residuals),
+        grid=(T // Tc,),
+        in_specs=[block(H4, fwd), *([block(1, fwd)] if masked else []),
+                  whole(B, H), whole(B, H), whole(H, H4),
+                  whole(1, H), whole(1, H), whole(1, H)],
+        out_specs=(*[block(H, fwd)] * n_seq, whole(B, H), whole(B, H)),
+        out_shape=(*[jax.ShapeDtypeStruct((T, B, H), dt)] * n_seq,
+                   jax.ShapeDtypeStruct((B, H), dt),     # hT
+                   jax.ShapeDtypeStruct((B, H), dt)),    # cT
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
+        compiler_params=_seq_compiler_params(Tc, B, H, dt.itemsize),
         interpret=_interpret(),
-        name="lstm_seq_lean",
-    )(*args)
-
-
-def _seq_fwd_impl(zx, h0, c0, RW, pF, pI, pO, act_name, gate_name):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
-    act, _ = _ACT_KERNEL[act_name]
-    gate, _ = _ACT_KERNEL[gate_name]
-    T, B, H4 = zx.shape
-    H = H4 // 4
-    dt = zx.dtype
-    step = lambda t: (t, 0, 0)  # noqa: E731
-    const3 = lambda t: (0, 0)   # noqa: E731
-    seq_spec = lambda w: pl.BlockSpec((1, B, w), step)  # noqa: E731
-    out_shape = (
-        jax.ShapeDtypeStruct((T, B, H), dt),  # ys
-        *[jax.ShapeDtypeStruct((T, B, H), dt) for _ in range(5)],  # a f o i c
-        jax.ShapeDtypeStruct((B, H), dt),     # hT
-        jax.ShapeDtypeStruct((B, H), dt),     # cT
-    )
-    return pl.pallas_call(
-        functools.partial(_seq_fwd_kernel, act, gate),
-        grid=(T,),
-        in_specs=[
-            seq_spec(H4),
-            pl.BlockSpec((B, H), const3),
-            pl.BlockSpec((B, H), const3),
-            pl.BlockSpec((H, H4), const3),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-        ],
-        out_specs=(
-            seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H),
-            seq_spec(H),
-            pl.BlockSpec((B, H), const3),
-            pl.BlockSpec((B, H), const3),
-        ),
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
-        interpret=_interpret(),
-        name="lstm_seq_fwd",
-    )(zx, h0, c0, RW, *_rows(pF, pI, pO))
-
-
-def _seq_fwd(zx, h0, c0, RW, pF, pI, pO, act_name, gate_name):
-    ys, a, f, o, i, c, hT, cT = _seq_fwd_impl(
-        zx, h0, c0, RW, pF, pI, pO, act_name, gate_name
-    )
-    residuals = (ys, a, f, o, i, c, h0, c0, RW, pF, pI, pO)
-    return (ys, hT, cT), residuals
-
-
-def _seq_bwd(act_name, gate_name, residuals, grads):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
-    ys, a, f, o, i, c, h0, c0, RW, pF, pI, pO = residuals
-    dys, dhT, dcT = grads
-    act, dact = _ACT_KERNEL[act_name]
-    _, dgate = _ACT_KERNEL[gate_name]
-    T, B, H = ys.shape
-    dt = ys.dtype
-    rev = lambda k: (T - 1 - k, 0, 0)   # noqa: E731
-    # previous-step state: block t-1, clamped at 0 (t==0 substitutes the
-    # initial state inside the kernel)
-    prev = lambda k: (jnp.maximum(T - 2 - k, 0), 0, 0)  # noqa: E731
-    const = lambda k: (0, 0)            # noqa: E731
-    seq = lambda ix: pl.BlockSpec((1, B, H), ix)  # noqa: E731
-    out_shape = (
-        jax.ShapeDtypeStruct((T, B, 4 * H), dt),  # dzx
-        jax.ShapeDtypeStruct((B, H), dt),         # dh0
-        jax.ShapeDtypeStruct((B, H), dt),         # dc0
-        jax.ShapeDtypeStruct((H, 4 * H), dt),     # dRW
-        jax.ShapeDtypeStruct((1, H), dt),         # dpF
-        jax.ShapeDtypeStruct((1, H), dt),         # dpI
-        jax.ShapeDtypeStruct((1, H), dt),         # dpO
-    )
-    dzx, dh0, dc0, dRW, dpF, dpI, dpO = pl.pallas_call(
-        functools.partial(_seq_bwd_kernel, act, dact, dgate, T),
-        grid=(T,),
-        in_specs=[
-            seq(rev),                       # dys
-            pl.BlockSpec((B, H), const),    # dhT
-            pl.BlockSpec((B, H), const),    # dcT
-            seq(rev), seq(rev), seq(rev), seq(rev),  # a f o i
-            seq(prev),                      # c_{t-1} (from c)
-            seq(prev),                      # h_{t-1} (from ys)
-            pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((B, H), const),    # h0
-            pl.BlockSpec((B, H), const),    # c0
-        ],
-        out_specs=(
-            pl.BlockSpec((1, B, 4 * H), rev),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-        ),
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt),
-            pltpu.VMEM((H, 4 * H), jnp.float32),
-            pltpu.VMEM((1, H), jnp.float32), pltpu.VMEM((1, H), jnp.float32),
-            pltpu.VMEM((1, H), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="lstm_seq_bwd",
-    )(dys, dhT, dcT, a, f, o, i, c, ys, RW, *_rows(pF, pI, pO), h0, c0)
-    return dzx, dh0, dc0, dRW, dpF[0], dpI[0], dpO[0]
-
-
-fused_lstm_sequence.defvjp(_seq_fwd, _seq_bwd)
-
-
-# -- masked variant: padded/bucketed sequences ride the fused loop too ------
-#
-# Masked steps carry h/c through unchanged (h_t = m·h̃ + (1−m)·h_{t-1} — the
-# scan path's semantics exactly). The backward recomputes the pre-mask cell
-# state c̃ = f·c_prev + i·a from the stored gates, so the residual set stays
-# the same five tensors plus the [T, B, 1] mask.
+        name=("lstm_seq_lean" if not residuals
+              else "lstm_seq_masked_fwd" if masked else "lstm_seq_fwd"),
+    )(zx, *([mask.astype(dt)] if masked else []), h0, c0, RW,
+      *_rows(pF, pI, pO))
 
 
 @jit_entry
-def _seq_fwd_kernel_masked(act, gate,
-                           zx_ref, m_ref, h0_ref, c0_ref, rw_ref, pf_ref,
-                           pi_ref, po_ref,
-                           y_out, a_out, f_out, o_out, i_out, c_out,
-                           hT_out, cT_out, h_scr, c_scr):
+def _seq_bwd_kernel(act, dact, dgate, Tc, masked, *refs):
+    """Backward of ``Tc`` steps a grid step, blocks in reverse time order
+    (grid step k covers t0 = (n-1-k)*Tc .. t0+Tc-1, walked last step first).
+    Masked steps pass dh/dc straight through to t-1."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
 
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_scr[:] = h0_ref[:]
-        c_scr[:] = c0_ref[:]
-
-    h_prev, c_prev = h_scr[:], c_scr[:]
-    h_tilde, c_tilde, a, f, o, i, _cact = _cell_math(
-        zx_ref[0], h_prev, c_prev, rw_ref[:],
-        pf_ref[:], pi_ref[:], po_ref[:], act, gate,
-    )
-    m = m_ref[0]
-    h = m * h_tilde + (1.0 - m) * h_prev
-    c = m * c_tilde + (1.0 - m) * c_prev
-    y_out[0], a_out[0], f_out[0], o_out[0], i_out[0], c_out[0] = h, a, f, o, i, c
-    h_scr[:], c_scr[:] = h, c
-    hT_out[:], cT_out[:] = h, c
-
-
-@jit_entry
-def _seq_bwd_kernel_masked(act, dact, dgate, T,
-                           dy_ref, dhT_ref, dcT_ref, m_ref,
-                           a_ref, f_ref, o_ref, i_ref, cprev_ref,
-                           hprev_ref, rw_ref, pf_ref, pi_ref, po_ref,
-                           h0_ref, c0_ref,
-                           dzx_out, dh0_out, dc0_out, drw_out, dpf_out,
-                           dpi_out, dpo_out,
-                           dh_scr, dc_scr, drw_scr, dpf_scr, dpi_scr, dpo_scr):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-
+    it = iter(refs)
+    dy_ref, dhT_ref, dcT_ref = (next(it) for _ in range(3))
+    m_ref = next(it) if masked else None  # static — dl4jtpu: ignore[DT104]
+    (a_ref, f_ref, o_ref, i_ref, c_ref, y_ref, cb_ref, hb_ref, rw_ref,
+     pf_ref, pi_ref, po_ref, h0_ref, c0_ref,
+     dzx_out, dh0_out, dc0_out, drw_out, dpf_out, dpi_out, dpo_out,
+     dh_scr, dc_scr, drw_scr, dpf_scr, dpi_scr, dpo_scr) = it
     k = pl.program_id(0)
 
     @pl.when(k == 0)
@@ -716,44 +554,152 @@ def _seq_bwd_kernel_masked(act, dact, dgate, T,
         dpi_scr[:] = jnp.zeros(dpi_scr.shape, dpi_scr.dtype)
         dpo_scr[:] = jnp.zeros(dpo_scr.shape, dpo_scr.dtype)
 
-    a, f, o, i = a_ref[0], f_ref[0], o_ref[0], i_ref[0]
-    first = k == T - 1
-    c_prev = jnp.where(first, c0_ref[:], cprev_ref[0])
-    h_prev = jnp.where(first, h0_ref[:], hprev_ref[0])
-    m = m_ref[0]
-    c_tilde = f * c_prev + i * a        # pre-mask cell state, recomputed
-    cact = act(c_tilde)
+    first = k == pl.num_programs(0) - 1   # t0 == 0: the initial state
+    c_before = jnp.where(first, c0_ref[:], cb_ref[0])
+    h_before = jnp.where(first, h0_ref[:], hb_ref[0])
     pF, pI, pO = pf_ref[:], pi_ref[:], po_ref[:]
-
-    dh_t = dy_ref[0] + dh_scr[:]
-    dc_t = dc_scr[:]
-    dh = m * dh_t                        # gradient into the cell outputs
-    dc = m * dc_t
-    do = dh * cact * dgate(o)
-    dc_tot = dc + dh * o * dact(cact) + do * pO
-    df = dc_tot * c_prev * dgate(f)
-    di = dc_tot * a * dgate(i)
-    da = dc_tot * i * dact(a)
-    dzx = jnp.concatenate([da, df, do, di], axis=-1)
-    dzx_out[0] = dzx
-    # carry-through paths: masked steps pass dh/dc straight to t-1
-    dh_scr[:] = (jnp.dot(dzx, rw_ref[:].T,
-                         preferred_element_type=_acc_dtype(dzx.dtype)
-                         ).astype(dzx.dtype)
-                 + (1.0 - m) * dh_t)
-    dc_scr[:] = dc_tot * f + df * pF + di * pI + (1.0 - m) * dc_t
+    B, H = c_before.shape
     f32 = drw_scr.dtype
-    drw_scr[:] += jnp.dot(h_prev.T, dzx, preferred_element_type=f32)
-    dpf_scr[:] += jnp.sum(df * c_prev, axis=0, dtype=f32, keepdims=True)
-    dpi_scr[:] += jnp.sum(di * c_prev, axis=0, dtype=f32, keepdims=True)
-    dpo_scr[:] += jnp.sum(do * c_tilde, axis=0, dtype=f32, keepdims=True)
-    dt = dzx.dtype
-    dh0_out[:] = dh_scr[:]
-    dc0_out[:] = dc_scr[:]
-    drw_out[:] = drw_scr[:].astype(dt)
-    dpf_out[:] = dpf_scr[:].astype(dt)
-    dpi_out[:] = dpi_scr[:].astype(dt)
-    dpo_out[:] = dpo_scr[:].astype(dt)
+    dh_next, dc_next = dh_scr[:], dc_scr[:]
+    dpf = dpi = dpo = jnp.zeros((B, H), f32)
+    for j in reversed(range(Tc)):
+        a, f, o, i = a_ref[j], f_ref[j], o_ref[j], i_ref[j]
+        c_prev = c_ref[j - 1] if j else c_before
+        # c_t (pre-mask) recomputed from the gates (VPU-cheap), not stored
+        c = f * c_prev + i * a
+        cact = act(c)
+        dh = dh_t = dy_ref[j] + dh_next
+        dc = dc_t = dc_next
+        if masked:  # static via partial — dl4jtpu: ignore[DT104]
+            m = m_ref[j]
+            dh, dc = m * dh_t, m * dc_t   # gradient into the cell outputs
+        do = dh * cact * dgate(o)
+        dc_tot = dc + dh * o * dact(cact) + do * pO
+        df = dc_tot * c_prev * dgate(f)
+        di = dc_tot * a * dgate(i)
+        da = dc_tot * i * dact(a)
+        dzx = jnp.concatenate([da, df, do, di], axis=-1)
+        dzx_out[j] = dzx
+        # the one matmul on the sequential path
+        dh_next = jnp.dot(
+            dzx, rw_ref[:].T, preferred_element_type=_acc_dtype(dzx.dtype)
+        ).astype(dzx.dtype)
+        dc_next = dc_tot * f + df * pF + di * pI
+        if masked:  # static via partial — dl4jtpu: ignore[DT104]
+            dh_next = dh_next + (1.0 - m) * dh_t
+            dc_next = dc_next + (1.0 - m) * dc_t
+        dpf += (df * c_prev).astype(f32)
+        dpi += (di * c_prev).astype(f32)
+        dpo += (do * c).astype(f32)
+    dh_scr[:], dc_scr[:] = dh_next, dc_next
+
+    # once a block: nothing at t-1 reads these. dRW contracts over Tc*B rows
+    h_prev = h_before
+    if Tc > 1:  # static via partial — dl4jtpu: ignore[DT104]
+        h_prev = jnp.concatenate(
+            [h_before, y_ref[:Tc - 1].reshape((Tc - 1) * B, H)], axis=0)
+    drw_scr[:] += jnp.dot(h_prev.T, dzx_out[:].reshape(Tc * B, 4 * H),
+                          preferred_element_type=f32)
+    dpf_scr[:] += jnp.sum(dpf, axis=0, keepdims=True)
+    dpi_scr[:] += jnp.sum(dpi, axis=0, keepdims=True)
+    dpo_scr[:] += jnp.sum(dpo, axis=0, keepdims=True)
+
+    @pl.when(first)
+    def _last():
+        dt = dzx_out.dtype
+        dh0_out[:] = dh_next
+        dc0_out[:] = dc_next
+        drw_out[:] = drw_scr[:].astype(dt)
+        dpf_out[:] = dpf_scr[:].astype(dt)
+        dpi_out[:] = dpi_scr[:].astype(dt)
+        dpo_out[:] = dpo_scr[:].astype(dt)
+
+
+def _seq_bwd_call(act_name, gate_name, mask, residuals, grads):
+    """dzx, dh0, dc0, dRW, dpF, dpI, dpO of the whole sequence in one kernel."""
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    ys, a, f, o, i, c, h0, c0, RW, pF, pI, pO = residuals
+    dys, dhT, dcT = grads
+    act, dact = _ACT_KERNEL[act_name]
+    _, dgate = _ACT_KERNEL[gate_name]
+    T, B, H = ys.shape
+    dt = ys.dtype
+    Tc = _seq_time_block(T, B, H, dt.itemsize)
+    n = T // Tc
+    block, whole = _seq_specs(Tc, B)
+    rev = lambda k: (n - 1 - k, 0, 0)   # noqa: E731
+    # the step before the block: element t0 - 1 of a one-step block, clamped
+    # at 0 (t0 == 0 substitutes the initial state inside the kernel)
+    before = lambda k: (jnp.maximum((n - 1 - k) * Tc - 1, 0), 0, 0)  # noqa: E731
+    masked = mask is not None
+    dzx, dh0, dc0, dRW, dpF, dpI, dpO = pl.pallas_call(
+        functools.partial(_seq_bwd_kernel, act, dact, dgate, Tc, masked),
+        grid=(n,),
+        in_specs=[
+            block(H, rev), whole(B, H), whole(B, H),       # dys dhT dcT
+            *([block(1, rev)] if masked else []),
+            *[block(H, rev)] * 6,                          # a f o i c ys
+            block(H, before, 1), block(H, before, 1),      # c, ys at t0 - 1
+            whole(H, 4 * H), whole(1, H), whole(1, H), whole(1, H),
+            whole(B, H), whole(B, H),                      # h0 c0
+        ],
+        out_specs=(block(4 * H, rev), whole(B, H), whole(B, H),
+                   whole(H, 4 * H), whole(1, H), whole(1, H), whole(1, H)),
+        out_shape=(
+            jax.ShapeDtypeStruct((T, B, 4 * H), dt),  # dzx
+            jax.ShapeDtypeStruct((B, H), dt),         # dh0
+            jax.ShapeDtypeStruct((B, H), dt),         # dc0
+            jax.ShapeDtypeStruct((H, 4 * H), dt),     # dRW
+            *[jax.ShapeDtypeStruct((1, H), dt)] * 3,  # dpF dpI dpO
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt),
+            pltpu.VMEM((H, 4 * H), jnp.float32),
+            *[pltpu.VMEM((1, H), jnp.float32)] * 3,
+        ],
+        compiler_params=_seq_compiler_params(Tc, B, H, dt.itemsize),
+        interpret=_interpret(),
+        name="lstm_seq_masked_bwd" if masked else "lstm_seq_bwd",
+    )(dys, dhT, dcT, *([mask.astype(dt)] if masked else []),
+      a, f, o, i, c, ys, c, ys, RW, *_rows(pF, pI, pO), h0, c0)
+    return dzx, dh0, dc0, dRW, dpF[0], dpI[0], dpO[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def fused_lstm_sequence(zx, h0, c0, RW, pF, pI, pO,
+                        act_name: str = "tanh", gate_name: str = "sigmoid"):
+    """Whole-sequence fused LSTM: ``zx`` [T, B, 4H] (precomputed x@W + b),
+    returns (ys [T, B, H], h_T, c_T). Unmasked, forward-direction.
+
+    The primal (inference) path runs the LEAN kernel that emits only
+    ys/hT/cT; the five gate residuals stream to HBM only under jax.grad
+    (the VJP's forward rule) where the backward actually consumes them."""
+    return _seq_fwd_call(zx, None, h0, c0, RW, pF, pI, pO,
+                         act_name, gate_name, residuals=False)
+
+
+def _seq_fwd(zx, h0, c0, RW, pF, pI, pO, act_name, gate_name):
+    ys, a, f, o, i, c, hT, cT = _seq_fwd_call(
+        zx, None, h0, c0, RW, pF, pI, pO, act_name, gate_name, residuals=True)
+    return (ys, hT, cT), (ys, a, f, o, i, c, h0, c0, RW, pF, pI, pO)
+
+
+def _seq_bwd(act_name, gate_name, residuals, grads):
+    return _seq_bwd_call(act_name, gate_name, None, residuals, grads)
+
+
+fused_lstm_sequence.defvjp(_seq_fwd, _seq_bwd)
+
+
+# -- masked variant: padded/bucketed sequences ride the fused loop too ------
+#
+# Masked steps carry h/c through unchanged (h_t = m·h̃ + (1−m)·h_{t-1} — the
+# scan path's semantics exactly): the same two kernel bodies under their
+# static ``masked`` flag. The backward recomputes the pre-mask cell state
+# c̃ = f·c_prev + i·a from the stored gates, so the residual set stays the
+# same five tensors plus the [T, B, 1] mask.
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
@@ -763,127 +709,20 @@ def fused_lstm_sequence_masked(zx, mask, h0, c0, RW, pF, pI, pO,
     """Masked whole-sequence fused LSTM: ``mask`` [T, B, 1]; masked steps
     hold h/c (scan-path semantics). Returns (ys, h_T, c_T). The primal runs
     the lean (no-residual) kernel; see fused_lstm_sequence."""
-    return _seq_lean_impl(zx, mask, h0, c0, RW, pF, pI, pO,
-                          act_name, gate_name)
-
-
-def _seq_masked_fwd_impl(zx, mask, h0, c0, RW, pF, pI, pO, act_name,
-                         gate_name):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
-    act, _ = _ACT_KERNEL[act_name]
-    gate, _ = _ACT_KERNEL[gate_name]
-    T, B, H4 = zx.shape
-    H = H4 // 4
-    dt = zx.dtype
-    step = lambda t: (t, 0, 0)  # noqa: E731
-    const = lambda t: (0, 0)    # noqa: E731
-    seq_spec = lambda w: pl.BlockSpec((1, B, w), step)  # noqa: E731
-    out_shape = (
-        jax.ShapeDtypeStruct((T, B, H), dt),
-        *[jax.ShapeDtypeStruct((T, B, H), dt) for _ in range(5)],
-        jax.ShapeDtypeStruct((B, H), dt),
-        jax.ShapeDtypeStruct((B, H), dt),
-    )
-    return pl.pallas_call(
-        functools.partial(_seq_fwd_kernel_masked, act, gate),
-        grid=(T,),
-        in_specs=[
-            seq_spec(H4),
-            pl.BlockSpec((1, B, 1), step),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((H, H4), const),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-        ],
-        out_specs=(
-            seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H), seq_spec(H),
-            seq_spec(H),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((B, H), const),
-        ),
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
-        interpret=_interpret(),
-        name="lstm_seq_masked_fwd",
-    )(zx, mask.astype(dt), h0, c0, RW, *_rows(pF, pI, pO))
+    return _seq_fwd_call(zx, mask, h0, c0, RW, pF, pI, pO,
+                         act_name, gate_name, residuals=False)
 
 
 def _seq_masked_fwd(zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name):
-    ys, a, f, o, i, c, hT, cT = _seq_masked_fwd_impl(
-        zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name
-    )
-    residuals = (ys, a, f, o, i, c, mask, h0, c0, RW, pF, pI, pO)
-    return (ys, hT, cT), residuals
+    ys, a, f, o, i, c, hT, cT = _seq_fwd_call(
+        zx, mask, h0, c0, RW, pF, pI, pO, act_name, gate_name, residuals=True)
+    return (ys, hT, cT), (mask, (ys, a, f, o, i, c, h0, c0, RW, pF, pI, pO))
 
 
 def _seq_masked_bwd(act_name, gate_name, residuals, grads):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
-    ys, a, f, o, i, c, mask, h0, c0, RW, pF, pI, pO = residuals
-    dys, dhT, dcT = grads
-    act, dact = _ACT_KERNEL[act_name]
-    _, dgate = _ACT_KERNEL[gate_name]
-    T, B, H = ys.shape
-    dt = ys.dtype
-    rev = lambda k: (T - 1 - k, 0, 0)   # noqa: E731
-    prev = lambda k: (jnp.maximum(T - 2 - k, 0), 0, 0)  # noqa: E731
-    const = lambda k: (0, 0)            # noqa: E731
-    seq = lambda ix: pl.BlockSpec((1, B, H), ix)  # noqa: E731
-    out_shape = (
-        jax.ShapeDtypeStruct((T, B, 4 * H), dt),
-        jax.ShapeDtypeStruct((B, H), dt),
-        jax.ShapeDtypeStruct((B, H), dt),
-        jax.ShapeDtypeStruct((H, 4 * H), dt),
-        jax.ShapeDtypeStruct((1, H), dt),
-        jax.ShapeDtypeStruct((1, H), dt),
-        jax.ShapeDtypeStruct((1, H), dt),
-    )
-    dzx, dh0, dc0, dRW, dpF, dpI, dpO = pl.pallas_call(
-        functools.partial(_seq_bwd_kernel_masked, act, dact, dgate, T),
-        grid=(T,),
-        in_specs=[
-            seq(rev),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((1, B, 1), rev),
-            seq(rev), seq(rev), seq(rev), seq(rev),
-            # the kernel recomputes c_tilde from the gates, so only the
-            # prev-indexed c stream is read (one T×B×H HBM stream saved)
-            seq(prev),
-            seq(prev),
-            pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((B, H), const),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, B, 4 * H), rev),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((B, H), const),
-            pl.BlockSpec((H, 4 * H), const),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-            pl.BlockSpec((1, H), lambda k: (0, 0)),
-        ),
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt),
-            pltpu.VMEM((H, 4 * H), jnp.float32),
-            pltpu.VMEM((1, H), jnp.float32), pltpu.VMEM((1, H), jnp.float32),
-            pltpu.VMEM((1, H), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="lstm_seq_masked_bwd",
-    )(dys, dhT, dcT, mask.astype(dt), a, f, o, i, c, ys,
-      RW, *_rows(pF, pI, pO), h0, c0)
-    return dzx, None, dh0, dc0, dRW, dpF[0], dpI[0], dpO[0]
+    mask, residuals = residuals
+    dzx, *rest = _seq_bwd_call(act_name, gate_name, mask, residuals, grads)
+    return (dzx, None, *rest)
 
 
 fused_lstm_sequence_masked.defvjp(_seq_masked_fwd, _seq_masked_bwd)

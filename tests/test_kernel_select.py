@@ -189,6 +189,29 @@ class TestSelectionCore:
         assert set(st["by_site"]["lrn"]) <= {"fused", "reference"}
         assert "calibration" in st and "factor" in st["calibration"]
 
+    @pytest.mark.parametrize("ctx,block", [
+        (dict(), 8),                  # T=256 B=64 H=512 bf16: the cap
+        (dict(T=50), 5),              # a TBPTT segment
+        (dict(T=251), 1),             # prime T: the blocking does not engage
+        (dict(B=4, itemsize=4), 1),   # rows short of a sublane tile
+    ])
+    def test_lstm_seq_record_carries_its_time_block(self, ctx, block):
+        from deeplearning4j_tpu.ops.pallas_kernels import _seq_time_block
+
+        ks.set_force_available(True)
+        ctx = _charrnn_ctx(**ctx)
+        assert ks.select("lstm_seq", ctx, forced="seqfused") == "seqfused"
+        rec = ks.selection_log()[-1]
+        assert rec["time_block"] == block == _seq_time_block(
+            ctx["T"], ctx["B"], ctx["H"], ctx["itemsize"])
+        assert ks.stats()["recent"][-1]["time_block"] == block
+
+    def test_time_block_is_the_seqfused_variant_s_alone(self):
+        ks.set_force_available(True)
+        ks.select("lstm_seq", _charrnn_ctx(), forced="reference")
+        ks.select("softmax_xent", {"N": 1 << 14, "C": 96, "itemsize": 4})
+        assert all("time_block" not in r for r in ks.selection_log())
+
 
 class TestCalibration:
     def test_update_and_factor(self):

@@ -17,6 +17,16 @@ from deeplearning4j_tpu.ops.pallas_kernels import (
 )
 
 
+@pytest.fixture(autouse=True)
+def _no_selection_outlives_its_test():
+    """Tests here force kernels through DL4J_TPU_PALLAS; the selection log is
+    the process's, and the benchmark's rehearsals count its fused sites."""
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    yield
+    ks.reset()
+
+
 def _cell_inputs(seed=0, B=4, H=8):
     rng = np.random.default_rng(seed)
     r = lambda *s: jnp.asarray(rng.normal(size=s) * 0.5, jnp.float32)  # noqa: E731
@@ -52,143 +62,157 @@ def test_fused_lstm_cell_gradients_match_autodiff():
         )
 
 
-def _seq_inputs(seed=0, T=6, B=4, H=8):
+# T -> the time block the seq kernels take at B=8 (whole f32 sublane tiles):
+# a prime T above the cap leaves one step a grid step, 24 runs three blocks
+# of 8 (the row before a block comes from the one-step boundary stream), 8 is
+# one block covering all of T, 12 two blocks under the cap
+_SEQ_T_AND_BLOCK = [(17, 1), (24, 8), (8, 8), (12, 6)]
+_SEQ_B, _SEQ_H = 8, 16
+
+
+def _seq_inputs(seed=0, T=6, B=_SEQ_B, H=_SEQ_H):
+    """h0/c0 are non-zero: the first block's boundary row is the initial
+    state, every later block's is the step before it."""
     rng = np.random.default_rng(seed)
     r = lambda *s: jnp.asarray(rng.normal(size=s) * 0.4, jnp.float32)  # noqa: E731
     return (r(T, B, 4 * H), r(B, H), r(B, H), r(H, 4 * H),
             r(H) * 0.2, r(H) * 0.2, r(H) * 0.2)
 
 
-def _seq_ref(zx, h0, c0, RW, pF, pI, pO, act="tanh", gate="sigmoid"):
-    a_fn, g_fn = _ACT[act][0], _ACT[gate][0]
+def _seq_mask(seed, T, B=_SEQ_B):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray((rng.random((T, B, 1)) > 0.3).astype(np.float32))
 
-    def step(carry, z):
+
+def _seq_ref(zx, h0, c0, RW, pF, pI, pO, act="tanh", gate="sigmoid",
+             mask=None):
+    a_fn, g_fn = _ACT[act][0], _ACT[gate][0]
+    if mask is None:
+        mask = jnp.ones((zx.shape[0], 1, 1), zx.dtype)
+
+    def step(carry, inp):
+        z, m = inp
         h, c = carry
         h2, c2, *_ = _cell_math(z, h, c, RW, pF, pI, pO, a_fn, g_fn)
+        h2, c2 = m * h2 + (1 - m) * h, m * c2 + (1 - m) * c  # masked: hold
         return (h2, c2), h2
 
-    (hT, cT), ys = jax.lax.scan(step, (h0, c0), zx)
+    (hT, cT), ys = jax.lax.scan(step, (h0, c0), (zx, mask))
     return ys, hT, cT
 
 
+def _seq_fused(mask):
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        fused_lstm_sequence,
+        fused_lstm_sequence_masked,
+    )
+
+    if mask is None:
+        return fused_lstm_sequence
+    return lambda zx, *rest: fused_lstm_sequence_masked(zx, mask, *rest)
+
+
+@pytest.mark.parametrize("T,block", _SEQ_T_AND_BLOCK)
+def test_seq_time_block_of_the_test_shapes(T, block):
+    from deeplearning4j_tpu.ops.pallas_kernels import _seq_time_block
+
+    assert _seq_time_block(T, _SEQ_B, _SEQ_H, 4) == block
+
+
+@pytest.mark.parametrize("T,B,H,itemsize", [
+    (256, 64, 512, 2), (256, 64, 512, 4), (50, 32, 256, 2), (16, 16, 128, 2),
+    (16, 16, 128, 4), (100, 128, 1024, 2), (251, 64, 512, 2), (6, 4, 8, 4),
+    (256, 64, 2048, 4), (64, 8, 128, 2), (48, 256, 1024, 4),
+])
+def test_seq_time_block_divides_T_fits_vmem_and_fills_the_mxu(T, B, H, itemsize):
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    tc = pk._seq_time_block(T, B, H, itemsize)
+    budget = pk._seq_vmem_budget()
+    cap = pk._SEQ_MAX_TIME_BLOCK
+    assert 1 <= tc <= cap and T % tc == 0
+    fitting = [d for d in range(1, min(T, cap) + 1) if T % d == 0
+               and pk._seq_footprint(d, B, H, itemsize) <= budget]
+    if B % (32 // itemsize):
+        assert tc == 1     # rows short of a sublane tile: no block matmul
+    elif fitting:
+        assert tc == max(fitting)
+        # the MXU's contraction is filled whenever a divisor allows
+        assert tc * B >= 128 or not any(d * B >= 128 for d in fitting)
+        # a wider item never gets a longer block
+        assert pk._seq_time_block(T, B, H, 2 * itemsize) <= tc
+    else:
+        assert tc == 1 and not pk._seq_fits(B, H, itemsize)
+
+
+def test_seq_time_block_shrinks_with_the_vmem_budget(monkeypatch):
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    T, B, H = 256, 64, 512
+    assert pk._seq_time_block(T, B, H, 2) == pk._SEQ_MAX_TIME_BLOCK == 8
+    seen = []
+    for mib in (64, 32, 24, 17, 8):
+        monkeypatch.setattr(pk, "_SEQ_VMEM_BUDGET_BYTES", mib << 20)
+        tc = pk._seq_time_block(T, B, H, 2)
+        assert tc == 1 or pk._seq_footprint(tc, B, H, 2) <= mib << 20
+        seen.append(tc)
+    assert seen == [8, 8, 4, 1, 1]
+    # where not even one step fits the block is 1 and selection gives way
+    assert seen[-1] == 1 and not pk._seq_fits(B, H, 2)
+    # f32 doubles every streamed block: a shorter block under a budget that
+    # holds 8 bf16 steps but not 8 f32 ones
+    monkeypatch.setattr(pk, "_SEQ_VMEM_BUDGET_BYTES", 32 << 20)
+    assert pk._seq_time_block(T, B, H, 4) == 4 < pk._seq_time_block(T, B, H, 2)
+
+
+@pytest.mark.parametrize("T,block", _SEQ_T_AND_BLOCK)
 @pytest.mark.parametrize("act,gate", [("tanh", "sigmoid"), ("tanh", "hardsigmoid")])
-def test_fused_lstm_sequence_forward_matches_scan(act, gate):
-    from deeplearning4j_tpu.ops.pallas_kernels import fused_lstm_sequence
+@pytest.mark.parametrize("masked", [False, True])
+def test_fused_lstm_sequence_forward_matches_scan(act, gate, masked, T, block):
+    args = _seq_inputs(seed=3, T=T)
+    mask = _seq_mask(2, T) if masked else None
+    got = _seq_fused(mask)(*args, act, gate)
+    want = _seq_ref(*args, act=act, gate=gate, mask=mask)
+    for g, w, name in zip(got, want, ["ys", "hT", "cT"]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-6,
+                                   err_msg=name)
 
-    args = _seq_inputs(seed=3)
-    ys_k, hT_k, cT_k = fused_lstm_sequence(*args, act, gate)
-    ys_r, hT_r, cT_r = _seq_ref(*args, act=act, gate=gate)
-    np.testing.assert_allclose(np.asarray(ys_k), np.asarray(ys_r), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(hT_k), np.asarray(hT_r), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(cT_k), np.asarray(cT_r), atol=1e-6)
 
+@pytest.mark.parametrize("T,block", _SEQ_T_AND_BLOCK)
+@pytest.mark.parametrize("masked", [False, True])
+def test_fused_lstm_sequence_gradients_match_autodiff(masked, T, block):
+    """The whole-loop custom VJP (reverse-time blocks, VMEM carries, the
+    block's own rows shifted by one for c_{t-1}/h_{t-1}, dRW and the
+    peephole sums once a block) against autodiff-through-scan, all seven
+    cotangents."""
+    args = _seq_inputs(seed=4, T=T)
+    mask = _seq_mask(5, T) if masked else None
+    fused = _seq_fused(mask)
 
-def test_fused_lstm_sequence_gradients_match_autodiff():
-    """The whole-loop custom VJP (reverse time grid, VMEM carries, shifted
-    c_{t-1}/h_{t-1} reads) against autodiff-through-scan, every input."""
-    from deeplearning4j_tpu.ops.pallas_kernels import fused_lstm_sequence
+    def loss_of(fn):
+        def loss(*a):
+            ys, hT, cT = fn(*a)
+            return jnp.sum(ys * ys) + jnp.sum(hT) + 0.5 * jnp.sum(jnp.sin(cT))
+        return loss
 
-    args = _seq_inputs(seed=4)
-
-    def loss_k(*a):
-        ys, hT, cT = fused_lstm_sequence(*a, "tanh", "sigmoid")
-        return jnp.sum(ys * ys) + jnp.sum(hT) + 0.5 * jnp.sum(jnp.sin(cT))
-
-    def loss_r(*a):
-        ys, hT, cT = _seq_ref(*a)
-        return jnp.sum(ys * ys) + jnp.sum(hT) + 0.5 * jnp.sum(jnp.sin(cT))
-
-    gk = jax.grad(loss_k, argnums=tuple(range(7)))(*args)
-    gr = jax.grad(loss_r, argnums=tuple(range(7)))(*args)
+    gk = jax.grad(loss_of(lambda *a: fused(*a, "tanh", "sigmoid")),
+                  argnums=tuple(range(7)))(*args)
+    gr = jax.grad(loss_of(lambda *a: _seq_ref(*a, mask=mask)),
+                  argnums=tuple(range(7)))(*args)
     for a, b, name in zip(gk, gr, ["zx", "h0", "c0", "RW", "pF", "pI", "pO"]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
                                    err_msg=f"grad {name}")
 
 
-def test_fused_lstm_sequence_layer_end_to_end(monkeypatch):
-    """DL4J_TPU_PALLAS=seq routes the GravesLSTM layer through the sequence
-    kernel; 3 adam steps must match the scan path bit-close."""
+@pytest.mark.parametrize("T,block", _SEQ_T_AND_BLOCK[:3])
+@pytest.mark.parametrize("kind", ["plain", "masked", "bidirectional"])
+def test_fused_lstm_sequence_layer_end_to_end(monkeypatch, kind, T, block):
+    """DL4J_TPU_PALLAS=seq routes GravesLSTM (padded batches through the
+    masked kernels) and both directions of GravesBidirectionalLSTM
+    (reverse = the forward kernel on time-flipped input) through the
+    sequence kernels; 3 adam steps must match the scan path bit-close."""
     from deeplearning4j_tpu import (
-        GravesLSTM,
-        InputType,
-        MultiLayerConfiguration,
-        MultiLayerNetwork,
-        RnnOutputLayer,
-        UpdaterConfig,
-    )
-
-    def make():
-        conf = MultiLayerConfiguration(
-            layers=[GravesLSTM(n_out=16, activation="tanh"),
-                    RnnOutputLayer(n_out=5, activation="softmax", loss="mcxent")],
-            input_type=InputType.recurrent(7),
-            updater=UpdaterConfig(updater="adam", learning_rate=1e-2),
-            seed=3,
-        )
-        return MultiLayerNetwork(conf).init()
-
-    rng = np.random.default_rng(0)
-    x = rng.normal(size=(6, 11, 7)).astype(np.float32)
-    y = np.eye(5, dtype=np.float32)[rng.integers(0, 5, (6, 11))]
-    monkeypatch.setenv("DL4J_TPU_PALLAS", "seq")
-    seq = make()
-    for _ in range(3):
-        seq.fit((x, y))
-    monkeypatch.setenv("DL4J_TPU_PALLAS", "0")
-    ref = make()
-    for _ in range(3):
-        ref.fit((x, y))
-    for a, b in zip(jax.tree_util.tree_leaves(seq.params),
-                    jax.tree_util.tree_leaves(ref.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
-
-
-def test_fused_lstm_sequence_masked_matches_masked_scan():
-    from deeplearning4j_tpu.ops.pallas_kernels import fused_lstm_sequence_masked
-
-    T, B, H = 6, 4, 8
-    rng = np.random.default_rng(2)
-    zx, h0, c0, RW, pF, pI, pO = _seq_inputs(seed=2, T=T, B=B, H=H)
-    mask = jnp.asarray((rng.random((T, B, 1)) > 0.3).astype(np.float32))
-    a_fn, g_fn = _ACT["tanh"][0], _ACT["sigmoid"][0]
-
-    def ref(zx, mask, h0, c0):
-        def step(carry, inp):
-            z, m = inp
-            h, c = carry
-            h2, c2, *_ = _cell_math(z, h, c, RW, pF, pI, pO, a_fn, g_fn)
-            return (m * h2 + (1 - m) * h, m * c2 + (1 - m) * c), \
-                m * h2 + (1 - m) * h
-        (hT, cT), ys = jax.lax.scan(step, (h0, c0), (zx, mask))
-        return ys, hT, cT
-
-    ys_k, hT_k, cT_k = fused_lstm_sequence_masked(
-        zx, mask, h0, c0, RW, pF, pI, pO, "tanh", "sigmoid")
-    ys_r, hT_r, cT_r = ref(zx, mask, h0, c0)
-    np.testing.assert_allclose(np.asarray(ys_k), np.asarray(ys_r), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(hT_k), np.asarray(hT_r), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(cT_k), np.asarray(cT_r), atol=1e-6)
-
-    def loss_k(zx, h0, c0):
-        ys, hT, cT = fused_lstm_sequence_masked(
-            zx, mask, h0, c0, RW, pF, pI, pO, "tanh", "sigmoid")
-        return jnp.sum(ys * ys) + jnp.sum(hT * cT)
-
-    def loss_r(zx, h0, c0):
-        ys, hT, cT = ref(zx, mask, h0, c0)
-        return jnp.sum(ys * ys) + jnp.sum(hT * cT)
-
-    gk = jax.grad(loss_k, argnums=(0, 1, 2))(zx, h0, c0)
-    gr = jax.grad(loss_r, argnums=(0, 1, 2))(zx, h0, c0)
-    for a, b, name in zip(gk, gr, ["dzx", "dh0", "dc0"]):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
-                                   err_msg=f"grad {name}")
-
-
-def test_fused_lstm_sequence_masked_layer_end_to_end(monkeypatch):
-    """Padded (bucketed) training rides the masked sequence kernel under
-    DL4J_TPU_PALLAS=seq and matches the masked scan path."""
-    from deeplearning4j_tpu import (
+        GravesBidirectionalLSTM,
         GravesLSTM,
         InputType,
         MultiLayerConfiguration,
@@ -197,80 +221,52 @@ def test_fused_lstm_sequence_masked_layer_end_to_end(monkeypatch):
         UpdaterConfig,
     )
     from deeplearning4j_tpu.datasets.iterators import DataSet
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    layer = GravesBidirectionalLSTM if kind == "bidirectional" else GravesLSTM
 
     def make():
         conf = MultiLayerConfiguration(
-            layers=[GravesLSTM(n_out=12, activation="tanh"),
-                    RnnOutputLayer(n_out=4, activation="softmax", loss="mcxent")],
-            input_type=InputType.recurrent(5),
+            layers=[layer(n_out=16, activation="tanh"),
+                    RnnOutputLayer(n_out=5, activation="softmax", loss="mcxent")],
+            input_type=InputType.recurrent(7),
             updater=UpdaterConfig(updater="adam", learning_rate=1e-2),
-            seed=4,
+            seed=3,
         )
         return MultiLayerNetwork(conf).init()
 
-    rng = np.random.default_rng(3)
-    x = rng.normal(size=(4, 9, 5)).astype(np.float32)
-    y = np.eye(4, dtype=np.float32)[rng.integers(0, 4, (4, 9))]
-    fm = np.ones((4, 9), np.float32)
-    fm[1, 6:] = 0.0
-    fm[3, 4:] = 0.0
-    ds = DataSet(x, y, fm, fm)
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(_SEQ_B, T, 7)).astype(np.float32)
+    y = np.eye(5, dtype=np.float32)[rng.integers(0, 5, (_SEQ_B, T))]
+    data = (x, y)
+    if kind == "masked":
+        fm = np.ones((_SEQ_B, T), np.float32)
+        fm[1, T - 5:] = 0.0
+        fm[3, T // 2:] = 0.0
+        data = DataSet(x, y, fm, fm)
+    ks.reset()
     monkeypatch.setenv("DL4J_TPU_PALLAS", "seq")
     seq = make()
     for _ in range(3):
-        seq.fit(ds)
+        seq.fit(data)
+    chosen = [r for r in ks.selection_log() if r["site"] == "lstm_seq"]
+    assert chosen and all(r["variant"] == "seqfused"
+                          and r["time_block"] == block for r in chosen)
     monkeypatch.setenv("DL4J_TPU_PALLAS", "0")
     ref = make()
     for _ in range(3):
-        ref.fit(ds)
+        ref.fit(data)
     for a, b in zip(jax.tree_util.tree_leaves(seq.params),
                     jax.tree_util.tree_leaves(ref.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
-def test_fused_lstm_sequence_bidirectional(monkeypatch):
-    """reverse=True rides the forward kernel on time-flipped input; the
-    bidirectional layer must match the scan path under DL4J_TPU_PALLAS=seq."""
-    from deeplearning4j_tpu import (
-        GravesBidirectionalLSTM,
-        InputType,
-        MultiLayerConfiguration,
-        MultiLayerNetwork,
-        RnnOutputLayer,
-        UpdaterConfig,
-    )
-
-    def make():
-        conf = MultiLayerConfiguration(
-            layers=[GravesBidirectionalLSTM(n_out=12, activation="tanh"),
-                    RnnOutputLayer(n_out=4, activation="softmax", loss="mcxent")],
-            input_type=InputType.recurrent(5),
-            updater=UpdaterConfig(updater="adam", learning_rate=1e-2),
-            seed=8,
-        )
-        return MultiLayerNetwork(conf).init()
-
-    rng = np.random.default_rng(1)
-    x = rng.normal(size=(4, 9, 5)).astype(np.float32)
-    y = np.eye(4, dtype=np.float32)[rng.integers(0, 4, (4, 9))]
-    monkeypatch.setenv("DL4J_TPU_PALLAS", "seq")
-    seq = make()
-    for _ in range(3):
-        seq.fit((x, y))
-    monkeypatch.setenv("DL4J_TPU_PALLAS", "0")
-    ref = make()
-    for _ in range(3):
-        ref.fit((x, y))
-    for a, b in zip(jax.tree_util.tree_leaves(seq.params),
-                    jax.tree_util.tree_leaves(ref.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
-
-
-def test_fused_lstm_sequence_inside_fit_on_device(monkeypatch):
+@pytest.mark.parametrize("T", [9, 24])   # B=4: one step a grid step; B=8: 8
+def test_fused_lstm_sequence_inside_fit_on_device(monkeypatch, T):
     """The charrnn bench path: the sequence kernel nested inside the
     one-dispatch lax.scan training loop (stacked 2-layer char-RNN) must
-    match the scan path — this is exactly what the charrnn_seqfused probe
-    step runs on hardware."""
+    match the scan path — this is exactly what the benchmark's
+    charrnn_train_1chip cell runs on hardware."""
     from deeplearning4j_tpu import MultiLayerNetwork
     from deeplearning4j_tpu.models.char_rnn import char_rnn
 
@@ -280,7 +276,7 @@ def test_fused_lstm_sequence_inside_fit_on_device(monkeypatch):
         return MultiLayerNetwork(conf).init()
 
     rng = np.random.default_rng(0)
-    idx = rng.integers(0, 12, size=(4, 10))
+    idx = rng.integers(0, 12, size=(4 if T == 9 else _SEQ_B, T + 1))
     xs = np.eye(12, dtype=np.float32)[idx[None, :, :-1]]
     ys = np.eye(12, dtype=np.float32)[idx[None, :, 1:]]
     monkeypatch.setenv("DL4J_TPU_PALLAS", "seq")
