@@ -130,3 +130,13 @@ def peaks_row(device_kind: str) -> dict:
         raise KeyError(f"no peaks row for device_kind {device_kind!r}; "
                        f"known: {sorted(table)}")
     return table[device_kind]
+
+
+def share_of_peak(flops_per_sample: float, samples_per_s_per_chip: float,
+                  peaks: dict) -> float:
+    """Per cent of one chip's bf16 peak that ``samples_per_s_per_chip`` is at
+    ``flops_per_sample`` model FLOPs a sample: what the per-layer metric
+    ``train_step_mfu`` reports and what a training generator logs in every
+    run, so that the two cannot drift."""
+    return 100.0 * flops_per_sample * samples_per_s_per_chip \
+        / peaks["bf16_flops_per_s"]

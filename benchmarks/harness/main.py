@@ -67,6 +67,8 @@ class Run:
     window_compile: dict    # Monitors delta over the measured window
     memory_peak_bytes: int  # see run_cell
     spans: Spans
+    trace_dir: str          # where the traced window's files lie
+    peaks: dict             # the device's row of harness/peaks.json
 
 
 def parse_args(argv):
@@ -78,11 +80,16 @@ def parse_args(argv):
     return ap.parse_args(argv)
 
 
-def final_line(*, correct, attempted, failed, metrics, device, breakdown=None):
+def final_line(*, correct, attempted, failed, metrics, device, compared,
+               breakdown=None):
+    """The contract's keys; ``compared`` (each number ``correct`` was decided
+    from, beside its limit) is the harness's own key and comes last."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = {name: {"value": float(value), "limit": float(limit)}
+                        for name, (value, limit) in compared.items()}
     return json.dumps(line)
 
 
@@ -92,17 +99,27 @@ def metric_values(entries, values: dict) -> dict:
             for m in entries if values.get(m["name"]) is not None}
 
 
-def trace_dir(cell: Cell) -> str:
-    return os.path.join(gate.repo_root(), ".bench_out", "trace", cell.name)
+def trace_dir(cell: Cell, root: str | None = None) -> str:
+    """Where a traced run of ``cell`` leaves its files: under ``root``, or
+    under the checkout's ``.bench_out/trace`` (the command's own runs, which
+    ``python3 -m benchmarks.harness.scopes <cell>`` reads afterwards)."""
+    root = root or os.path.join(gate.repo_root(), ".bench_out", "trace")
+    return os.path.join(root, cell.name)
 
 
 def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
              t0: float, lead: float = 0.0, peaks: dict | None = None,
-             cpu_rehearsal: bool = False) -> str:
+             cpu_rehearsal: bool = False,
+             trace_root: str | None = None) -> str:
     """Set-up, window, checks and metrics of one cell on ``devices``; returns
     the final line. ``main`` gates on the TPU first; the tests call this at
     a tiny preset on the CPU, with ``cpu_rehearsal`` (and their own
     ``peaks``), which alone lets a trace without a device plane be read.
+
+    A traced run empties ``trace_dir(cell, trace_root)`` and writes there.
+    Two runs that trace into one directory at once delete each other's
+    files, so every caller but the command itself hands in a ``trace_root``
+    of its own (the tests: a fresh temporary directory per call).
 
     ``memory_peak_bytes`` is the larger of what the fullest chip held when
     the window began and when it ended, the cell's arrays still alive (see
@@ -125,7 +142,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
 
             seconds = min(seconds, float(
                 cell.params.get("trace_seconds", DEFAULT_TRACE_SECONDS)))
-            tracing = trace_dir(cell)
+            tracing = trace_dir(cell, trace_root)
             shutil.rmtree(tracing, ignore_errors=True)
             tr.start(tracing)
         held = [gate.memory_held_bytes(devices)]
@@ -145,13 +162,15 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
         window_compile = gate.delta(window_compile, setup_compile)
         trace_data = tr.load(tracing, max_devices=len(devices),
                              allow_cpu_backend=cpu_rehearsal) if trace else None
-        checks = gen.verify(ctx, state, result)
+        compared = gen.verify(ctx, state, result)
     finally:
         gen.close(ctx, state)
 
-    checks["no_compile_in_window"] = window_compile["backend_compiles"] == 0
-    for name, ok in checks.items():
-        ctx.log(f"check {name}: {'ok' if ok else 'FAILED'}")
+    # what decides ``correct``: every number the generator compared and the
+    # window's compiles, each held as value <= limit (a NaN holds nothing)
+    compared["compiles_in_window"] = (window_compile["backend_compiles"], 0)
+    over = [name for name, (value, limit) in compared.items()
+            if not value <= limit]
     mem = max(held)
     ctx.log(f"held on the fullest chip: {held[0]} bytes before the window, "
             f"{held[1]} after")
@@ -161,9 +180,12 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
 
     breakdown = None
     if trace:
+        from . import program_spans
+
         run = Run(cell=cell, result=result, trace=trace_data,
                   setup_compile=setup_compile, window_compile=window_compile,
-                  memory_peak_bytes=mem, spans=ctx.spans)
+                  memory_peak_bytes=mem, spans=ctx.spans, trace_dir=tracing,
+                  peaks=peaks)
         values = {}
         for m in cell.per_layer:
             value = cell.metric_reader(m["name"])(run)
@@ -172,13 +194,20 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
         metrics = metric_values(cell.per_layer, values)
         device["busy_s"] = trace_data.busy_s()
         device["window_s"] = trace_data.window_s()
-        breakdown = trace_data.breakdown()
+        breakdown = trace_data.breakdown(
+            program_spans=program_spans.of_run(run))
     else:
         values = dict(result["end_to_end"], setup_s=setup_s)
         metrics = metric_values(cell.end_to_end, values)
-    return final_line(correct=all(checks.values()),
+    # the last lines on standard error: what was compared, beside its limit
+    for name, (value, limit) in compared.items():
+        print(f"compared {name} {value:.6g} limit {limit:.6g}", file=sys.stderr)
+    print(f"correct {not over}" + (f": FAILED {over}" if over else ""),
+          file=sys.stderr, flush=True)
+    return final_line(correct=not over,
                       attempted=result["attempted"], failed=result["failed"],
-                      metrics=metrics, device=device, breakdown=breakdown)
+                      metrics=metrics, device=device, breakdown=breakdown,
+                      compared=compared)
 
 
 def main(argv, t0: float) -> int:

@@ -27,7 +27,6 @@ import functools
 import os
 from dataclasses import dataclass
 
-from . import main
 from . import trace as tr
 
 PREFIX = "dl4j."
@@ -68,7 +67,7 @@ def load(directory: str) -> tuple:
 
 
 def of_run(run) -> tuple:
-    return load(main.trace_dir(run.cell))
+    return load(run.trace_dir)
 
 
 def idle_inside(trace, spans, names, device_index: int = 0) -> tuple:

@@ -160,7 +160,7 @@ def of_run(run) -> Scopes | None:
     if run.trace is None or texts is None:
         return None
     scopes = Scopes.join(run.trace, texts)
-    scopes.write(main.trace_dir(run.cell))
+    scopes.write(run.trace_dir)
     return scopes
 
 

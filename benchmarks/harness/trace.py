@@ -172,6 +172,33 @@ def subtract(a, b):
     return out
 
 
+def innermost(spans) -> dict:
+    """``{name: [(start, end)]}``: for each name the parts of its spans that
+    no span started later covers. Spans of one thread nest, so that is the
+    innermost one; where two threads' spans overlap the later start wins."""
+    out, stack, cursor = {}, [], 0.0
+
+    def close(upto):
+        # what lies between the cursor and ``upto`` is the top span's own
+        nonlocal cursor
+        if upto > cursor:
+            out.setdefault(stack[-1].name, []).append((cursor, upto))
+            cursor = upto
+
+    for s in sorted(spans, key=lambda s: (s.start, -s.end)):
+        while stack and stack[-1].end <= s.start:
+            close(stack[-1].end)
+            stack.pop()
+        if stack:
+            close(s.start)
+        cursor = max(cursor, s.start)
+        stack.append(s)
+    while stack:
+        close(stack[-1].end)
+        stack.pop()
+    return out
+
+
 # -------------------------------------------------------------------- model
 @dataclass
 class Op:
@@ -292,7 +319,28 @@ class TraceData:
             out.append(total(subtract([(lo, hi)], busy)) / 1e9)
         return out
 
-    def breakdown(self, top: int = 10) -> dict:
+    def gap_seconds_by_program_span(self, program_spans,
+                                    device_index: int = 0) -> dict:
+        """Idle seconds of one device under the innermost span of the
+        program that covers them (``program_spans``: anything with ``name``,
+        ``start`` and ``end`` on this clock, as ``program_spans.load``
+        gives). Idle time under no span of the program keeps the harness's
+        name for it (``gap_seconds_by_span``), so the parts still add up to
+        the window's idle time."""
+        gaps = self.gaps(device_index)
+        out = {}
+        for name, iv in innermost(program_spans).items():
+            iv = union(clip(iv, *self.window))
+            idle = total(iv) - total(subtract(iv, gaps))
+            if idle > 0:
+                out[name] = idle / 1e9
+        covered = union([(s.start, s.end) for s in program_spans])
+        for a, b in subtract(gaps, covered):
+            name = self.span_at((a + b) / 2)
+            out[name] = out.get(name, 0.0) + (b - a) / 1e9
+        return out
+
+    def breakdown(self, top: int = 10, program_spans=()) -> dict:
         per = {}
         for d in self.devices:
             for o in d.ops:
@@ -305,7 +353,7 @@ class TraceData:
         n = len(self.devices) * 1e9
         ops = sorted(((k, v / n) for k, v in per.items()),
                      key=lambda kv: -kv[1])[:top]
-        gaps = sorted(self.gap_seconds_by_span().items(),
+        gaps = sorted(self.gap_seconds_by_program_span(program_spans).items(),
                       key=lambda kv: -kv[1])[:top]
         return {"device_ops": [[k, v] for k, v in ops],
                 "idle_gaps": [[k, v] for k, v in gaps]}

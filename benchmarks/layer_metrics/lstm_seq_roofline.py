@@ -1,8 +1,9 @@
 """The seq-fused LSTM kernels against their roofline: the sum over the
 traced ``lstm_seq_*`` events of the least time each could take, over the sum
-of their traced durations. 0.0 where no such event is in the window (the CPU
-and a mesh take the XLA path); nothing where the window's Mosaic calls have
-no names (``lstm_seq_time_share``). Source: device trace.
+of their traced durations. Nothing where no such event is in the window (the
+CPU and a mesh take the XLA path: a share of a roofline is never 0), and
+nothing where the window's Mosaic calls have no names
+(``lstm_seq_time_share``). Source: device trace.
 
 One event is one layer's whole sequence: T time steps of the recurrent
 ``h @ RW`` ([B,H] x [H,4H]) and the cell's elementwise math; ``x @ W`` runs
@@ -48,8 +49,9 @@ def least_seconds(kernel, B, T, H, itemsize, peaks) -> float:
                moved / peaks["hbm_bytes_per_s"])
 
 
-def share(trace, B, T, H, itemsize, peaks) -> float:
-    """Least over traced seconds of the window's ``lstm_seq_*`` events."""
+def share(trace, B, T, H, itemsize, peaks):
+    """Least over traced seconds of the window's ``lstm_seq_*`` events;
+    ``None`` where the window has none."""
     lo, hi = trace.window
     least = traced = 0.0
     for dev in trace.devices:
@@ -57,18 +59,19 @@ def share(trace, B, T, H, itemsize, peaks) -> float:
             if lo <= op.start < hi:
                 least += least_seconds(kernel, B, T, H, itemsize, peaks)
                 traced += (op.end - op.start) / 1e9
-    return least / traced if traced else 0.0
+    return least / traced if traced else None
 
 
 def read(run):
     if run.trace is None or not told_apart(run.trace):
         return None
     if not any(True for dev in run.trace.devices for _ in kernel_ops(dev)):
-        return 0.0  # and no peaks row is asked of a device without one
+        return None  # and no peaks row is asked of a device without one
     import jax
 
     p, sizes = run.cell.params, run.cell.sizes
-    return 100.0 * share(
+    got = share(
         run.trace, int(p["batch_per_chip"]), int(p["seq_len"]),
         int(sizes["rnn_size"]), ITEMSIZE[sizes["dtype"]],
         peaks_row(jax.devices()[0].device_kind))
+    return None if got is None else 100.0 * got

@@ -11,25 +11,14 @@ import sys
 
 import pytest
 
-from bench_presets import REPO, manifest_with_serving_cell, rehearse
+from bench_presets import (ADDED_CELL, ADDED_METRIC, ADDED_ROOFLINE, REPO,
+                           bench_dir_of, manifest_with_a_later_prs_additions,
+                           rehearse, tiny_cell)
 from benchmarks.harness import discovery, gate, main, stats
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-
-
-def manifest():
-    return discovery.load_json(os.path.join(REPO, "BENCHMARK.json"))
-
-
-@pytest.fixture(params=["as_committed", "with_the_serving_cell"])
-def manifest_path(request, tmp_path):
-    """The manifest, and the manifest once a PR has added the decode cell's
-    entries to it (the mix and its readers are here, the cell is not)."""
-    if request.param == "as_committed":
-        return os.path.join(REPO, "BENCHMARK.json")
-    return manifest_with_serving_cell(str(tmp_path))
 
 
 # ------------------------------------------------------------- percentiles
@@ -68,20 +57,31 @@ def test_samples_beyond_and_spread():
 
 # -------------------------------------------------------------- final line
 def test_final_line_has_the_contract_keys_and_nothing_else():
+    """... but ``compared``, the harness's own key, which every line ends
+    in: each number ``correct`` was decided from, beside its limit."""
     line = main.final_line(
         correct=True, attempted=4, failed=0,
         metrics={"setup_s": {"value": 1.5, "unit": "s"}},
         device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
-                "memory_peak_bytes": 1})
+                "memory_peak_bytes": 1},
+        compared={"compiles_in_window": (0, 0)})
     assert "\n" not in line
-    assert set(json.loads(line)) == {"correct", "attempted", "failed",
-                                     "metrics", "device"}
+    assert list(json.loads(line)) == ["correct", "attempted", "failed",
+                                      "metrics", "device", "compared"]
     traced = json.loads(main.final_line(
         correct=False, attempted=1, failed=1, metrics={}, device={},
-        breakdown={"device_ops": [], "idle_gaps": []}))
-    assert set(traced) == {"correct", "attempted", "failed", "metrics",
-                           "device", "breakdown"}
+        breakdown={"device_ops": [], "idle_gaps": []},
+        compared={"first_loss_off_plain_reference": (2e-4, 1e-4),
+                  "compiles_in_window": (0, 0)}))
+    assert list(traced) == ["correct", "attempted", "failed", "metrics",
+                            "device", "breakdown", "compared"]
     assert traced["correct"] is False
+    assert traced["compared"] == {
+        "first_loss_off_plain_reference": {"value": 2e-4, "limit": 1e-4},
+        "compiles_in_window": {"value": 0.0, "limit": 0.0}}
+    with pytest.raises(TypeError):      # no line without what was compared
+        main.final_line(correct=True, attempted=1, failed=0, metrics={},
+                        device={})
 
 
 def test_metric_values_leave_out_what_was_not_read():
@@ -98,8 +98,9 @@ def test_peaks_table_knows_the_v5e_and_refuses_an_unknown_device():
 
 
 # ---------------------------------------------------------------- manifest
-def test_manifest_has_exactly_the_contract_keys_and_limits():
-    m = manifest()
+def test_manifest_has_exactly_the_contract_keys_and_limits(manifest_path):
+    m = discovery.load_json(manifest_path)
+    root = os.path.dirname(manifest_path)
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
@@ -109,14 +110,16 @@ def test_manifest_has_exactly_the_contract_keys_and_limits():
     assert m["command"][:2] == ["python3", "benchmarks/run.py"]
     for p in m["paths"]:
         assert os.path.isdir(os.path.join(REPO, p)) and not p.startswith("/")
+    assert os.path.isdir(os.path.join(root, m["paths"][0]))
     assert 2 <= len(m["workloads"]) <= 24
     four = [w for w in m["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(m["workloads"]) // 4)
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
+    assert os.path.getsize(manifest_path) <= 64 * 1024
 
 
 def test_manifest_names_units_and_references(manifest_path):
     m = discovery.load_json(manifest_path)
+    root = os.path.dirname(manifest_path)
     configs = {c["name"] for c in m["configs"]}
     cells = {w["name"] for w in m["workloads"]}
     e2e = {x["name"] for x in m["end_to_end"]}
@@ -125,7 +128,7 @@ def test_manifest_names_units_and_references(manifest_path):
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and len(c["source"]) <= 200
         assert c["file"].startswith(tuple(p + "/" for p in m["paths"]))
-        assert os.path.isfile(os.path.join(REPO, c["file"]))
+        assert os.path.isfile(os.path.join(root, c["file"]))
     pairs = set()
     for w in m["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -157,7 +160,8 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(
         manifest_path):
     m = discovery.load_json(manifest_path)
     for w in m["workloads"]:
-        cell = discovery.resolve_cell(w["name"], manifest_path=manifest_path)
+        cell = discovery.resolve_cell(w["name"], manifest_path=manifest_path,
+                                      bench_dir=bench_dir_of(manifest_path))
         e2e = {x["name"] for x in cell.end_to_end}
         assert "setup_s" in e2e and len(e2e) >= 2
         assert cell.per_layer
@@ -169,8 +173,8 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(
         assert os.path.isfile(cell.path("configs", cell.config + ".py"))
 
 
-def test_a_kernels_roofline_share_is_named_for_it():
-    for x in manifest()["per_layer"]:
+def test_a_kernels_roofline_share_is_named_for_it(manifest_path):
+    for x in discovery.load_json(manifest_path)["per_layer"]:
         if "roofline" in x["name"]:
             assert x["name"].endswith("_roofline") and x["unit"] == "%"
 
@@ -183,55 +187,49 @@ def test_unknown_workload_is_named_in_the_error():
 
 def test_a_cell_mix_config_and_metric_added_as_files_are_found(tmp_path):
     """A later PR adds files and manifest entries and edits nothing: the
-    same harness code then runs the new cell and reads the new metric."""
-    bench = tmp_path / "benchmarks"
-    shutil.copytree(os.path.join(REPO, "benchmarks"), bench,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(bench / "configs" / "charrnn_2x512.py",
-                bench / "configs" / "charrnn_1x16.py")
-    (bench / "configs" / "charrnn_1x16.json").write_text(json.dumps(dict(
-        discovery.load_json(str(bench / "configs" / "charrnn_2x512.json")),
-        name="charrnn_1x16", rnn_size=16, num_layers=1, vocab_size=12,
-        classes=12)))
-    (bench / "traffic" / "train_staged_short.json").write_text(json.dumps({
-        "generator": "staged_training",
-        "params": {"wrapper": "none", "first_loss_rtol": 0.25,
-                   "reference_rtol": 0.03, "twin_rtol": [0.03],
-                   "trace_seconds": 1, "batch_per_chip": 2, "seq_len": 4,
-                   "slots": 2}}))
-    (bench / "workloads" / "charrnn_1x16_short.json").write_text(json.dumps({
-        "why": "added by a test", "params": {"steps_per_dispatch": 2}}))
-    (bench / "layer_metrics" / "dispatches_in_window.py").write_text(
-        "def read(run):\n    return run.result['dispatches']\n")
-    m = manifest()
-    m["configs"].append({"name": "charrnn_1x16", "source": "test",
-                         "file": "benchmarks/configs/charrnn_1x16.json",
-                         "reduced": [], "why": "test"})
-    m["workloads"].append({"name": "charrnn_1x16_short",
-                           "config": "charrnn_1x16",
-                           "traffic": "train_staged_short", "chips": 1,
-                           "why": "test"})
-    m["end_to_end"][0]["workloads"].append("charrnn_1x16_short")
-    m["per_layer"].append({
-        "name": "dispatches_in_window", "unit": "dispatches",
-        "better": "higher", "source": "program_counter",
-        "layer": "entry points: fit_on_device and ParallelWrapper",
-        "moves": "train_samples_per_s_per_chip",
-        "workloads": ["charrnn_1x16_short"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(m))
-
-    cell = discovery.resolve_cell(
-        "charrnn_1x16_short", manifest_path=str(tmp_path / "BENCHMARK.json"),
-        bench_dir=str(bench))
+    same harness code then runs the new cell and reads the new metric, and
+    the same tests rehearse it through the two presets it added."""
+    path = manifest_with_a_later_prs_additions(tmp_path)
+    cell = discovery.resolve_cell(ADDED_CELL, manifest_path=path,
+                                  bench_dir=bench_dir_of(path))
     assert cell.generator == "staged_training"
-    assert cell.sizes["rnn_size"] == 16
-    assert cell.params["steps_per_dispatch"] == 2 and cell.params["slots"] == 2
-    line = rehearse(cell, trace=True, seconds=0.3)
+    assert cell.bench_dir == str(tmp_path / "benchmarks")
+    assert cell.sizes["rnn_size"] == 128 and cell.sizes["num_layers"] == 1
+    assert cell.params["steps_per_dispatch"] == 8 and cell.params["slots"] == 8
+    tiny = tiny_cell(ADDED_CELL, manifest_path=path)
+    assert tiny.sizes["rnn_size"] == 16 and tiny.sizes["num_layers"] == 1
+    assert tiny.params["steps_per_dispatch"] == 2 and tiny.params["slots"] == 2
+    assert tiny.params["first_loss_rtol"] == 0.25    # the mix's, untouched
+
+    line = rehearse(tiny, seconds=0.3)
     assert line["correct"] is True
-    assert line["metrics"]["dispatches_in_window"]["value"] >= 1
-    assert line["metrics"]["dispatches_in_window"]["unit"] == "dispatches"
+    assert set(line["metrics"]) == {"train_samples_per_s_per_chip", "setup_s"}
+    line = rehearse(tiny, trace=True, seconds=0.3)
+    assert line["correct"] is True
+    assert line["metrics"][ADDED_METRIC]["value"] >= 1
+    assert line["metrics"][ADDED_METRIC]["unit"] == "dispatches"
+    assert 0 < line["metrics"]["train_step_mfu"]["value"] < 100
+    # its kernel's roofline is listed, and silent where the kernel did not run
+    assert ADDED_ROOFLINE in {m["name"] for m in tiny.per_layer}
+    assert ADDED_ROOFLINE not in line["metrics"]
     # metrics of other cells are not reported here
     assert "pallas_time_share" not in line["metrics"]
+    assert "lstm_seq_time_block" not in line["metrics"]
+    # and what the new cell brought is not reported in the cells that were there
+    old = tiny_cell("charrnn_train_1chip", manifest_path=path)
+    assert ADDED_METRIC not in {m["name"] for m in old.per_layer}
+
+
+def test_a_cell_without_its_presets_names_the_files_to_add(tmp_path):
+    path = manifest_with_a_later_prs_additions(tmp_path)
+    os.remove(tmp_path / "presets" / "cells" / (ADDED_CELL + ".json"))
+    with pytest.raises(discovery.BenchmarkError,
+                       match=rf"presets/cells/{ADDED_CELL}\.json"):
+        tiny_cell(ADDED_CELL, manifest_path=path)
+    os.remove(tmp_path / "presets" / "configs" / "charrnn_2x512.json")
+    with pytest.raises(discovery.BenchmarkError,
+                       match=r"presets/configs/charrnn_2x512\.json"):
+        tiny_cell("charrnn_train_1chip", manifest_path=path)
 
 
 # ------------------------------------------------------------ non-TPU exit

@@ -30,6 +30,11 @@ RECORDED = os.path.join(HERE, "fixture_charrnn")
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 FIT = ["dl4j.fit.prepare", "dl4j.fit.launch", "dl4j.fit.fetch",
        "dl4j.fit.listeners"]
+PR23_METRICS = [
+    "dispatch_gap_ms", "compile_s", "compiles_in_window",
+    "persistent_cache_hits", "fused_sites", "pallas_time_share",
+    "copy_time_share", "conv_time_share", "collective_exposed_share",
+    "collective_time_share", "device_idle_share", "peak_hbm_gb"]
 NEW_METRICS = {
     "idle_in_put_ms", "idle_in_launch_ms", "idle_in_fetch_ms", "net_init_s",
     "package_import_s", "cm_lower_s", "cm_load_or_compile_s",
@@ -141,6 +146,38 @@ def test_idle_parts_of_a_synthetic_steady_run_add_up_to_dispatch_gap_ms():
                        window=(0, 100))
     assert one.gaps_between("dispatch") == []
     assert ps.idle_inside(one, spans, {"dl4j.fit.fetch"}) == (5, 1)
+    # the line's breakdown names the innermost span of the program over each
+    # idle stretch; what lies under none of them keeps the harness's name
+    # (its loop between two dispatches); the parts are the window's idle time
+    by_span = td.gap_seconds_by_program_span(spans)
+    assert by_span == {
+        "dl4j.parallel_wrapper.data": pytest.approx(30e-9),
+        "dl4j.fit.prepare": pytest.approx(12e-9),
+        "dl4j.fit.launch": pytest.approx(6e-9),
+        "dl4j.fit.fetch": pytest.approx(15e-9),
+        "dl4j.fit.listeners": pytest.approx(3e-9),
+        "outside_spans": pytest.approx(18e-9)}
+    assert sum(by_span.values()) == pytest.approx(td.window_s() - td.busy_s())
+    gaps = td.breakdown(program_spans=spans)["idle_gaps"]
+    assert gaps[0] == ["dl4j.parallel_wrapper.data", pytest.approx(30e-9)]
+    assert "dispatch" not in [name for name, _ in gaps]
+    # a program without spans (a parent from before PR 26): as it was
+    assert td.gap_seconds_by_program_span(()) == td.gap_seconds_by_span()
+    assert td.breakdown()["idle_gaps"][0][0] == "dispatch"
+
+
+def test_innermost_gives_each_moment_to_the_span_that_started_last():
+    S = ps.ProgramSpan
+    nested = [S("outer", 0, 100), S("a", 10, 40), S("a.x", 20, 30),
+              S("b", 40, 90)]
+    assert tr.innermost(nested) == {
+        "outer": [(0, 10), (90, 100)], "a": [(10, 20), (30, 40)],
+        "a.x": [(20, 30)], "b": [(40, 90)]}
+    # another thread's span that straddles an end: the later start has it
+    assert tr.innermost([S("main", 0, 50), S("other", 30, 80),
+                         S("main", 60, 70)]) == {
+        "main": [(0, 30), (60, 70)], "other": [(30, 60), (70, 80)]}
+    assert tr.innermost([]) == {}
 
 
 def test_a_trace_without_the_programs_spans_reads_as_nothing_not_zero():
@@ -290,6 +327,20 @@ def test_recorded_trace_by_scope_names_every_mosaic_kernel(
     assert not [k for k in labels if "jvp" in k]
 
 
+def test_recorded_idle_gaps_name_the_programs_spans(recorded, recorded_spans):
+    gaps = dict(recorded.breakdown(program_spans=recorded_spans)["idle_gaps"])
+    assert set(FIT[:3]) <= set(gaps) and "dispatch" in gaps
+    assert ps.DISPATCH in gaps    # the root's own code between its children
+    assert sum(gaps.values()) == pytest.approx(
+        recorded.window_s() - recorded.busy_s(), rel=1e-6)
+    # most of the idle time of a 3 ms dispatch lies under the program's own
+    # spans; without them all of it read ``dispatch``
+    under = sum(v for k, v in gaps.items() if k.startswith("dl4j."))
+    assert under > 0.85 * sum(gaps.values()) - gaps.get("outside_spans", 0.0)
+    assert dict(recorded.breakdown()["idle_gaps"]).keys() \
+        <= {"dispatch", "outside_spans"}
+
+
 def test_the_command_prints_the_window_by_scope(tmp_path, monkeypatch, capsys):
     import shutil
 
@@ -345,10 +396,12 @@ def test_recorded_lstm_kernels_share_of_time_and_of_their_roofline(recorded):
                  + roof.least_seconds("lstm_seq_bwd", 16, 8, 128, 2, V5E))
     assert got == pytest.approx(least / spent)
     assert 0.0 < got < 1.0
-    # no such kernel in the window: 0.0, as pallas_time_share reads
+    # no such kernel in the window: 0.0 of the busy time, as
+    # pallas_time_share reads, and no share of a roofline at all
     old = tr.load(os.path.join(HERE, "fixtures"))
     assert metric("lstm_seq_time_share").read(Run(old)) == 0.0
-    assert roof.share(old, 16, 8, 128, 2, V5E) == 0.0
+    assert roof.share(old, 16, 8, 128, 2, V5E) is None
+    assert roof.read(Run(old)) is None
     # a Mosaic call without a name (a program from before ``name=``) cannot
     # be told from the others: nothing is reported, not 0
     unnamed = tr.Op(0, 10, '%jvp__.20 = f32[16384,1]{1,0} custom-call(f32[16384,96]{1,0} %a), custom_call_target="tpu_custom_call"', "pallas")
@@ -372,25 +425,27 @@ def test_the_traced_rehearsal_reads_every_new_metric_the_cell_lists(
     assert len(listed) >= 8
     line = rehearse(cell, trace=True, seconds=1.0)
     assert line["correct"] is True
-    for metric_name in sorted(listed):
+    # the CPU takes the XLA path: no Mosaic kernel in the window, so the
+    # kernels' share of the busy time is 0 and their roofline says nothing
+    silent = listed & {"lstm_seq_roofline"}
+    assert not silent & set(line["metrics"])
+    for metric_name in sorted(listed - silent):
         value = line["metrics"][metric_name]["value"]  # a number, never absent
         assert math.isfinite(value) and value >= 0.0, metric_name
     m = line["metrics"]
     # set-up ran through the spans: a net was initialised, a program compiled
     assert m["net_init_s"]["value"] > 0 and m["cm_lower_s"]["value"] > 0
     assert m["package_import_s"]["value"] > 0
-    # the CPU takes the XLA path: no Mosaic kernel in the window
     if "lstm_seq_time_share" in listed:
         assert m["lstm_seq_time_share"]["value"] == 0.0
-        assert m["lstm_seq_roofline"]["value"] == 0.0
     assert 0.0 <= m["scope_attributed_share"]["value"] <= 100.0
     # idle under the program's spans is idle of the window
     idle_ms = sum(m[k]["value"] for k in listed if k.startswith("idle_in_"))
     assert idle_ms <= 1e3 * line["device"]["window_s"]
 
 
-def test_every_new_entry_lists_its_cells_and_has_its_reader():
-    manifest = load_json(os.path.join(REPO, "BENCHMARK.json"))
+def test_every_new_entry_lists_its_cells_and_has_its_reader(manifest_path):
+    manifest = load_json(manifest_path)
     entries = {m["name"]: m for m in manifest["per_layer"]}
     assert NEW_METRICS <= set(entries)
     cells = {w["name"] for w in manifest["workloads"]}
@@ -400,6 +455,10 @@ def test_every_new_entry_lists_its_cells_and_has_its_reader():
         assert set(entry["workloads"]) <= cells and entry["workloads"]
         assert os.path.isfile(os.path.join(
             REPO, "benchmarks", "layer_metrics", name + ".py"))
-    # added at the end: what PR 23 declared stands first, in its order
+    # the head is pinned: what PR 23 declared stands first, in its order,
+    # and PR 26's eleven follow it. The tail is free: every later PR appends
     names = [m["name"] for m in manifest["per_layer"]]
-    assert set(names[-len(NEW_METRICS):]) == NEW_METRICS
+    assert names[:12] == PR23_METRICS
+    assert set(names[12:23]) == NEW_METRICS
+    assert names[23:25] == ["lstm_seq_time_block", "train_step_mfu"]
+    assert len(set(names)) == len(names)

@@ -3,25 +3,41 @@ tiny preset that lives in the tests only: the generator's set-up, window and
 checks, the per-layer readers, the final line. No time or rate read here
 means anything; what is asserted is control flow and counts."""
 
+import os
+
 import pytest
 
-from bench_presets import manifest_with_serving_cell, rehearse
+from bench_presets import (ADDED_CELL, ADDED_METRIC, ADDED_ROOFLINE, REPO,
+                           manifest_with_a_later_prs_additions,
+                           manifest_with_serving_cell, reads_nothing_on_cpu,
+                           rehearse)
 from bench_presets import tiny_cell as _tiny_cell
 from benchmarks.harness.discovery import load_json, load_module, resolve_cell
 
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
+# every cell the manifest has, and the two that only the tests add: the
+# decode cell, and the cell of a PR that brings a configuration with a
+# kernel's roofline. A PR that appends a cell (and adds its two presets) has
+# it rehearsed here as that last one is
+CELLS = [w["name"] for w in load_json(os.path.join(REPO, "BENCHMARK.json"))[
+    "workloads"]] + ["charrnn_decode_c8", ADDED_CELL]
+
+
+def manifest_that_has(name, tmp_path) -> str:
+    if name == ADDED_CELL:
+        return manifest_with_a_later_prs_additions(tmp_path)
+    return manifest_with_serving_cell(str(tmp_path))
 
 
 @pytest.fixture
 def tiny_cell(tmp_path):
-    """A cell of the manifest, or the decode cell that only the tests add to
-    it, at its tiny preset."""
+    """A cell of the manifest, or one that only the tests add to it, at its
+    tiny preset."""
     return lambda name: _tiny_cell(
-        name, manifest_path=manifest_with_serving_cell(str(tmp_path)))
+        name, manifest_path=manifest_that_has(name, tmp_path))
 
 
-@pytest.mark.parametrize("name", ["resnet50_train_1chip", "resnet50_train_dp4",
-                                  "charrnn_train_1chip", "charrnn_decode_c8"])
+@pytest.mark.parametrize("name", CELLS)
 def test_cell_end_to_end_run(name, tiny_cell):
     cell = tiny_cell(name)
     line = rehearse(cell)
@@ -32,18 +48,26 @@ def test_cell_end_to_end_run(name, tiny_cell):
     assert set(line["device"]) == DEVICE_KEYS
     assert line["device"]["count"] == cell.chips
     assert "breakdown" not in line
+    assert all(c["value"] <= c["limit"] for c in line["compared"].values())
+    assert line["compared"]["compiles_in_window"] == {"value": 0, "limit": 0}
 
 
-@pytest.mark.parametrize("name", ["resnet50_train_dp4", "charrnn_train_1chip",
-                                  "charrnn_decode_c8"])
-def test_cell_traced_run(name, tiny_cell):
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_traced_run(name, tiny_cell, tmp_path):
     cell = tiny_cell(name)
     line = rehearse(cell, trace=True, seconds=1.0)
     assert line["correct"] is True
     declared = {m["name"] for m in cell.per_layer}
     assert set(line["metrics"]) <= declared
-    # a loaded CPU may fit one dispatch in the window: no boundary to read
-    assert declared - set(line["metrics"]) <= {"dispatch_gap_ms"}
+    # a reader that finds nothing to read returns nothing and its metric is
+    # left out of the line (a kernel's roofline where the CPU takes the XLA
+    # path; a boundary where a loaded CPU fits one dispatch): the cell's
+    # preset names those, and every other metric it lists is read
+    silent = reads_nothing_on_cpu(name, manifest_that_has(name, tmp_path))
+    assert silent <= declared
+    assert declared - set(line["metrics"]) <= silent
+    rooflines = {m for m in declared if m.endswith("_roofline")}
+    assert rooflines <= silent and not rooflines & set(line["metrics"])
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     assert line["device"]["busy_s"] > 0
     assert line["device"]["window_s"] >= line["device"]["busy_s"]
@@ -52,10 +76,15 @@ def test_cell_traced_run(name, tiny_cell):
     if name == "charrnn_decode_c8":
         assert 1 <= line["metrics"]["decode_tick_rows_mean"]["value"] <= 8
         assert line["metrics"]["decode_ticks_per_s"]["value"] > 0
-    else:
+    if "fused_sites" in declared:
         assert line["metrics"]["fused_sites"]["value"] == 0  # CPU: XLA paths
+    if "train_step_mfu" in declared:
+        assert 0 < line["metrics"]["train_step_mfu"]["value"] < 100
     if name == "resnet50_train_dp4":
         assert line["metrics"]["collective_time_share"]["value"] > 0
+    if name == ADDED_CELL:
+        assert line["metrics"][ADDED_METRIC]["value"] >= 1
+        assert ADDED_ROOFLINE in declared
 
 
 @pytest.mark.parametrize("name,param,value", [
@@ -67,10 +96,53 @@ def test_cell_traced_run(name, tiny_cell):
     ("charrnn_decode_c8", "replay_reference_atol", 0.0),
 ])
 def test_a_difference_beyond_a_tolerance_makes_the_run_incorrect(
-        name, param, value, tiny_cell):
+        name, param, value, tiny_cell, capfd):
     cell = tiny_cell(name)
     cell.params[param] = value
-    assert rehearse(cell)["correct"] is False
+    line = rehearse(cell)
+    assert line["correct"] is False
+    # the number at fault is in the line beside its limit, and in the last
+    # lines of standard error, which is all the driver's record keeps
+    over = {k for k, c in line["compared"].items() if c["value"] > c["limit"]}
+    assert len(over) == 1 and list(line)[-1] == "compared"
+    err = capfd.readouterr().err.splitlines()
+    assert err[-1].startswith("correct False: FAILED [")
+    (at_fault,) = over
+    assert [l for l in err[-1 - len(line["compared"]):-1]
+            if l.startswith(f"compared {at_fault} ")]
+
+
+def test_a_traced_run_writes_where_its_caller_says_and_only_there(
+        tiny_cell, tmp_path):
+    """The command's runs trace into ``<checkout>/.bench_out/trace/<cell>``
+    (``scopes`` reads there); every other caller hands in a root of its own,
+    so two rehearsals of one cell on two test workers share no directory
+    (they used to delete each other's files) and tier-1 leaves nothing in
+    the checkout."""
+    import time
+
+    import jax
+
+    from bench_presets import FAKE_PEAKS
+    from benchmarks.harness import main, scopes, trace
+
+    cell = tiny_cell("charrnn_train_1chip")
+    default = os.path.join(REPO, ".bench_out", "trace", cell.name)
+    assert main.trace_dir(cell) == default
+    assert main.trace_dir(cell, str(tmp_path)) == str(tmp_path / cell.name)
+    checkout_had = os.path.isdir(os.path.join(REPO, ".bench_out"))
+    stale = tmp_path / cell.name / "left_by_an_earlier_run.xplane.pb"
+    stale.parent.mkdir()
+    stale.write_bytes(b"not a trace")
+    main.run_cell(cell, seed=5, seconds=0.3, trace=True,
+                  devices=jax.devices()[:1], t0=time.perf_counter(),
+                  peaks=FAKE_PEAKS, cpu_rehearsal=True,
+                  trace_root=str(tmp_path))
+    assert not stale.exists()     # a run empties its own directory first
+    assert len(trace.find_xplane_files(str(tmp_path / cell.name))) == 1
+    # the scopes the traced run joined lie beside its trace
+    assert os.path.isfile(tmp_path / cell.name / scopes.SCOPES_FILE)
+    assert os.path.isdir(os.path.join(REPO, ".bench_out")) == checkout_had
 
 
 def test_a_traced_run_without_a_device_plane_is_refused(tiny_cell):
@@ -93,6 +165,40 @@ def test_model_flops_come_from_the_shapes(config, flops):
 
 def _bench():
     return resolve_cell("charrnn_train_1chip").bench_dir
+
+
+@pytest.mark.parametrize("name,rate,share", [
+    # 23.15 GFLOP an image at PR 23's 2,886 images/s; 20.35 MFLOP a character
+    # at PR 27's 6.07M characters/s, over the v5e's 197 TFLOP/s
+    ("resnet50_train_1chip", 2886.0, 33.91),
+    ("resnet50_train_dp4", 2698.9, 31.71),
+    ("charrnn_train_1chip", 6.0738e6, 62.74),
+])
+def test_train_step_mfu_is_model_flops_times_throughput_over_the_peak(
+        name, rate, share):
+    import types
+
+    from benchmarks.harness import gate
+
+    cell = resolve_cell(name)
+    assert "train_step_mfu" in {m["name"] for m in cell.per_layer}
+    read = cell.metric_reader("train_step_mfu")
+    peaks = gate.peaks_row("TPU v5 lite")
+
+    def run(end_to_end):
+        return types.SimpleNamespace(cell=cell, peaks=peaks,
+                                     result={"end_to_end": end_to_end})
+
+    rate_key = "train_samples_per_s_per_chip"
+    assert read(run({rate_key: rate})) == pytest.approx(share, abs=0.01)
+    flops = cell.config_module().model_flops_per_sample(cell.sizes)
+    assert gate.share_of_peak(flops, rate, peaks) \
+        == read(run({rate_key: rate}))
+    # over the peak: a wrong FLOP count is raised, never reported or clipped
+    with pytest.raises(ValueError, match=r"train_step_mfu reads [12]\d\d\.\d%"):
+        read(run({rate_key: 3.2 * rate}))
+    # a cell without the rate (a serving one) has nothing to read
+    assert read(run({"serve_ops_per_s": 1.0})) is None
 
 
 def test_memory_held_is_in_use_plus_reserved_on_the_fullest_chip():

@@ -1,7 +1,6 @@
 """``lstm_seq_time_block`` (PR 27): the time block the seq-fused LSTM kernels
 run at, read from the program's selection log as ``fused_sites`` is."""
 
-import json
 import os
 import types
 
@@ -11,22 +10,10 @@ from bench_presets import REPO, rehearse, tiny_cell
 from benchmarks.harness.discovery import load_json, load_module
 
 NAME = "lstm_seq_time_block"
-ENTRY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
-                     NAME + "_entry.json")
 
 
-def manifest_with_the_entry(tmp_dir) -> str:
-    """A copy of ``BENCHMARK.json`` in ``tmp_dir`` with the metric's entry at
-    the end of ``per_layer``, as the PR that admits it will leave it
-    (``fixtures/lstm_seq_time_block_entry.json`` says why this PR cannot)."""
-    m = load_json(os.path.join(REPO, "BENCHMARK.json"))
-    m["per_layer"] = m["per_layer"] + load_json(ENTRY)["per_layer"]
-    os.symlink(os.path.join(REPO, "benchmarks"),
-               os.path.join(tmp_dir, "benchmarks"))
-    path = os.path.join(tmp_dir, "BENCHMARK.json")
-    with open(path, "w") as f:
-        json.dump(m, f)
-    return path
+def manifest():
+    return load_json(os.path.join(REPO, "BENCHMARK.json"))
 
 
 @pytest.fixture(scope="module")
@@ -66,28 +53,28 @@ def test_the_block_is_the_lstm_seq_selections_own(read, log, value):
 
 
 def test_the_entry_lists_the_cell_that_runs_the_kernels():
-    manifest = load_json(os.path.join(REPO, "BENCHMARK.json"))
-    (entry,) = load_json(ENTRY)["per_layer"]
-    fused = next(m for m in manifest["per_layer"] if m["name"] == "fused_sites")
+    per_layer = manifest()["per_layer"]
+    names = [m["name"] for m in per_layer]
+    # admitted by PR 29, after what PR 23 and PR 26 declared
+    assert names.count(NAME) == 1 and names.index(NAME) >= 23
+    entry = per_layer[names.index(NAME)]
+    fused = next(m for m in per_layer if m["name"] == "fused_sites")
     assert entry == {
         "name": NAME, "unit": "steps", "better": "higher",
         "source": "program_counter", "layer": "Pallas kernels",
         "moves": fused["moves"], "workloads": ["charrnn_train_1chip"]}
-    roofline = next(m for m in manifest["per_layer"]
-                    if m["name"] == "lstm_seq_roofline")
+    roofline = next(m for m in per_layer if m["name"] == "lstm_seq_roofline")
     assert (entry["layer"], entry["moves"], entry["workloads"]) == (
         roofline["layer"], roofline["moves"], roofline["workloads"])
-    assert NAME not in {m["name"] for m in manifest["per_layer"]}  # not yet
 
 
-def test_the_traced_rehearsal_reports_it_beside_fused_sites(tmp_path):
+def test_the_traced_rehearsal_reports_it_beside_fused_sites():
     """On the CPU ``auto`` takes the XLA path: no site is fused, no block."""
     from deeplearning4j_tpu.ops import kernel_select as ks
 
     ks.reset()  # the log is the process's: earlier tests' selections go
-    cell = tiny_cell("charrnn_train_1chip",
-                     manifest_path=manifest_with_the_entry(str(tmp_path)))
-    assert cell.per_layer[-1]["name"] == NAME
+    cell = tiny_cell("charrnn_train_1chip")
+    assert NAME in {m["name"] for m in cell.per_layer}
     line = rehearse(cell, trace=True, seconds=0.5)
     assert line["correct"] is True
     assert line["metrics"]["fused_sites"]["value"] == 0
@@ -95,8 +82,8 @@ def test_the_traced_rehearsal_reports_it_beside_fused_sites(tmp_path):
 
 
 @pytest.mark.parametrize("name", ["resnet50_train_1chip", "resnet50_train_dp4"])
-def test_cells_without_the_kernels_do_not_list_it(name, tmp_path):
-    cell = tiny_cell(name, manifest_path=manifest_with_the_entry(str(tmp_path)))
+def test_cells_without_the_kernels_do_not_list_it(name):
+    cell = tiny_cell(name)
     assert NAME not in {m["name"] for m in cell.per_layer}
 
 
