@@ -19,6 +19,14 @@ full width of the models the repo is measured on, and checks what comes out:
 - **Leg E — four chips** (only when ``len(jax.devices()) >= 4``; otherwise
   reported as *not run*, never as passed): data-parallel and data x fsdp
   ``ParallelWrapper.fit_on_device``.
+- **Leg F — the hybrid state-space / attention / expert blocks at the
+  published widths** (``benchmarks/configs/nemotron3_nano_30b_a3b``, one
+  sequence of 8192 positions): one Mamba-2, one grouped-query attention and
+  one expert block and the head's loss, each as the engine runs it (bfloat16
+  compute over float32 masters) against the float32 plain reference: forward
+  outputs, every parameter's gradient and the input's. Then a control: the
+  Mamba block once more with the scan's decays and state in bfloat16, which
+  has to FAIL its tolerance, or the comparison would pass lower precision.
 
 Legs are plain functions taking sizes: ``tests/test_chip_smoke.py`` calls them
 tiny on the CPU (interpret-mode kernels); ``__main__`` runs them at full width
@@ -45,7 +53,7 @@ import urllib.request
 
 import numpy as np
 
-LEGS = ("A", "B", "C", "D", "E")
+LEGS = ("A", "B", "C", "D", "E", "F")
 
 
 class LegFailure(AssertionError):
@@ -875,11 +883,188 @@ def leg_e_four_chips(vocab: int = 96, hidden: int = 512, layers: int = 2,
     return out
 
 
+# -------------------------------------------------------------------- leg F
+# Tolerances of leg F at the published widths, on the relative L2 distance
+# between the engine's bfloat16 result and the float32 plain reference (worst
+# over a block's output and gradients), each about three times what the v5e
+# read (my chip runs, PR 30, two runs alike; PERF.md section 6):
+#   M    0.00933 (d_A_log; output 0.00514)   the control, the scan's decays
+#        and state in bfloat16, read 1.47 (d_A_log; output 0.0177): fails
+#   A    0.00626 (d_in; output 0.00473)
+#   E    0.00451 (d_Ws_down; output 0.00446, d_in 0.0044; before rows outside
+#        every group were selected away, d_in read 9.66)
+#   head 0.00166 (d_in; the loss itself 1.0e-6)
+LEG_F_TOLERANCES = {"M": 0.03, "A": 0.02, "E": 0.015, "head": 0.005}
+
+
+def _rel_l2(a, b) -> float:
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+
+def _hybrid_config():
+    from benchmarks.harness.discovery import load_json, load_module
+
+    base = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmarks", "configs", "nemotron3_nano_30b_a3b")
+    return load_module(base + ".py"), load_json(base + ".json")
+
+
+@contextlib.contextmanager
+def _scan_state_in(dtype):
+    """The scan's decays, running sums and state in ``dtype`` (``None``: as
+    the program has them, float32), through the jax.numpy variant: the
+    control's lower precision, never the program's."""
+    if dtype is None:
+        yield
+        return
+    from deeplearning4j_tpu.ops import kernel_select as ks
+    from deeplearning4j_tpu.ops import ssd_scan as ssd
+
+    was = ssd._state_dtype
+    ssd._state_dtype = lambda dt: np.dtype(dtype)
+    try:
+        with ks.forced_mode("reference"):
+            yield
+    finally:
+        ssd._state_dtype = was
+
+
+def leg_f_hybrid_blocks(sizes: dict | None = None, seq_len: int = 8192,
+                        batch: int = 1, dtype: str = "bfloat16",
+                        tolerances: dict | None = None, kinds="MAEH",
+                        control: str | None = "bfloat16",
+                        seed: int = 0) -> dict:
+    """One block of each kind and the head's loss, as ``ComputationGraph``
+    runs them (``dtype`` compute over float32 masters, through the engine's
+    own cast), against the plain reference: the relative L2 distance of the
+    forward output, of every parameter's gradient and of the input's, the
+    worst of a kind held under its tolerance. ``control``: the Mamba block
+    again with its scan state in that dtype, which must not pass."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models.nemotron_h import nemotron_h_conf
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.multilayer import _cast_layer_params
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    t_leg = time.perf_counter()
+    cfg, published = _hybrid_config()
+    sizes = dict(published, **(sizes or {}))
+    tolerances = dict(LEG_F_TOLERANCES, **(tolerances or {}))
+    conf = nemotron_h_conf("M*E", dtype=dtype, **cfg.builder_kwargs(sizes))
+    layers = {"M": conf.vertices["b0M_mixer"].layer,
+              "A": conf.vertices["b1A_mixer"].layer,
+              "E": conf.vertices["b2E_mixer"].layer,
+              "H": conf.vertices["head"].layer}
+    plain = {"M": cfg.reference_mamba, "A": cfg.reference_attention,
+             "E": cfg.reference_experts}
+    width = sizes["hidden_size"]
+    it = InputType.recurrent(width, seq_len)
+    cdt = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 16)
+    # a block's input is a normed residual stream: unit scale, rounded to the
+    # compute dtype once, so both sides route and attend over the same values
+    x = jax.random.normal(keys[0], (batch, seq_len, width), jnp.float32)
+    x = x.astype(cdt).astype(jnp.float32)
+    w = jax.random.normal(keys[1], (batch, seq_len, width), jnp.float32)
+
+    def f32(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jnp.asarray(a, jnp.float32), tree)
+
+    def block(kind):
+        layer = layers[kind]
+        params = f32(layer.init_params(keys[2 + "MAE".index(kind)], it))
+        state = layer.init_state(it) if hasattr(layer, "init_state") else {}
+
+        def program(p, x):
+            out, _ = layer.apply(_cast_layer_params(dtype, layer, p),
+                                 x.astype(cdt), state, train=True)
+            return out.astype(jnp.float32)
+
+        def reference(p, x):
+            with jax.default_matmul_precision("highest"):
+                return plain[kind](p, x, sizes)
+
+        def both(fn):
+            def run(p, x):
+                out, pull = jax.vjp(fn, p, x)
+                return (out,) + pull(w)
+            return jax.jit(run)
+
+        return params, both(program), both(reference)
+
+    def compare(kind, got, want) -> dict:
+        (o, gp, gx), (o_r, gp_r, gx_r) = got, want
+        errs = {"out": _rel_l2(o, o_r), "d_in": _rel_l2(gx, gx_r)}
+        for name, g in gp_r.items():
+            if float(jnp.max(jnp.abs(g))) > 0.0:   # e_bias only selects
+                errs["d_" + name] = _rel_l2(gp[name], g)
+        return errs
+
+    results, failed = {}, []
+
+    def held(kind, errs, label=None):
+        label = label or kind
+        worst = max(errs.values())
+        tol = tolerances[kind]
+        line = {k: float(f"{v:.3g}") for k, v in errs.items()}
+        ok = bool(np.isfinite(worst) and worst <= tol)
+        print(f"  {'ok  ' if ok else 'OVER'} {label}: worst {worst:.3g} "
+              f"(tol {tol:g}) {json.dumps(line)}", flush=True)
+        results[label] = float(f"{worst:.3g}")
+        return ok
+
+    for kind in (k for k in "MAE" if k in kinds):
+        params, program, reference = block(kind)
+        want = jax.block_until_ready(reference(params, x))
+        got = jax.block_until_ready(program(params, x))
+        if not held(kind, compare(kind, got, want)):
+            failed.append(kind)
+        if kind == "M" and control:
+            with _scan_state_in(control):
+                lower = jax.block_until_ready(block("M")[1](params, x))
+            if held("M", compare("M", lower, want),
+                    f"M with the scan state in {control} (control)"):
+                failed.append("control: a lower-precision scan passed")
+        del params, program, reference, want, got
+
+    if "H" in kinds:
+        head = layers["H"]
+        hp = f32(head.init_params(keys[8], it))
+        ids = jax.random.randint(keys[9], (batch, seq_len), 0,
+                                 sizes["vocab_size"])
+
+        def program(p, h):   # as ComputationGraph._loss hands them over
+            return head.compute_loss(p, h, ids, None, train=True)
+
+        def reference(p, h):
+            with jax.default_matmul_precision("highest"):
+                return jnp.mean(cfg.reference_token_losses(p["W"], h, ids))
+
+        vg = lambda fn: jax.jit(jax.value_and_grad(fn, argnums=(0, 1)))  # noqa: E731
+        (l, (gp, gh)), (l_r, (gp_r, gh_r)) = vg(program)(hp, x), \
+            vg(reference)(hp, x)
+        errs = {"loss": abs(float(l) - float(l_r)) / abs(float(l_r)),
+                "d_W": _rel_l2(gp["W"], gp_r["W"]), "d_in": _rel_l2(gh, gh_r)}
+        if not held("head", errs):
+            failed.append("head")
+
+    sites = {r["site"]: r["variant"] for r in ks.selection_log()
+             if r.get("mode") != "reference"}
+    print(f"  selection: {json.dumps(sites)}")
+    check(not failed, f"leg F: over tolerance or control passed: {failed}")
+    return {"worst": results, "selection": sites,
+            "leg_seconds": round(time.perf_counter() - t_leg, 1)}
+
+
 # --------------------------------------------------------------------- main
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma list out of A,B,C,D,E (default: all)")
+                    help="comma list out of A,B,C,D,E,F (default: all)")
     legs = [l.strip().upper() for l in ap.parse_args(argv).legs.split(",")
             if l.strip()]
     unknown = [l for l in legs if l not in LEGS]
@@ -930,6 +1115,7 @@ def main(argv=None) -> int:
         results["E"] = "not run"
     else:
         run("E", leg_e_four_chips)
+    run("F", leg_f_hybrid_blocks)
 
     print(f"compile totals: {json.dumps(monitors().snapshot())}")
     print(f"compile manager: {json.dumps(_admission_state())}")

@@ -59,7 +59,8 @@ from .nn.layers.recurrent import (
 )
 from .nn.layers.normalization import BatchNormalization, LocalResponseNormalization
 from .nn.layers.attention import LayerNormLayer, SelfAttentionLayer
-from .nn.layers.moe import MixtureOfExpertsLayer
+from .nn.layers.moe import DroplessExpertsLayer, MixtureOfExpertsLayer
+from .nn.layers.state_space import Mamba2Layer, RMSNormLayer
 from .nn.layers.center_loss import CenterLossOutputLayer
 from .datasets.iterators import (
     DataSet,

@@ -25,7 +25,7 @@ from ...telemetry.spans import span
 from ..multilayer import (
     _carry_params_dtype,
     _cast_input,
-    _cast_params,
+    _cast_layer_params,
     _format_summary_table,
 )
 from ..updaters import (optimizer_update, scaled_loss, unscale_grads,
@@ -215,7 +215,9 @@ class ComputationGraph:
         (reference: ComputationGraph feed-forward loop :1051-1060)
         """
         conf = self.conf
-        params = _cast_params(conf.dtype, params)
+        params = {name: _cast_layer_params(
+            conf.dtype, getattr(conf.vertices.get(name), "layer", None), p)
+            for name, p in params.items()}
         cast = [_cast_input(conf.dtype, params, x) for x in inputs]
         acts: Dict[str, jnp.ndarray] = dict(zip(conf.network_inputs, cast))
         if masks is None:
@@ -331,6 +333,12 @@ class ComputationGraph:
 
         def dl4j_graph_train_step(params, opt_state, state, inputs, labels,
                                   rng, labels_masks, masks):
+            from ...telemetry import device as _tdev  # noqa: PLC0415
+
+            # a counting layer's state holds the last step's counts here
+            state = {n: _tdev.zero_layer_counters(s)
+                     for n, s in state.items()}
+
             def loss_of(p):
                 loss, new_state, _ = self._loss(
                     p, state, inputs, labels, rng, True, labels_masks, masks
@@ -388,6 +396,9 @@ class ComputationGraph:
             losses0 = jnp.zeros((steps_cap,), jnp.float32)
             mvecs0 = (jnp.zeros((steps_cap, _tdev.NUM_SLOTS), jnp.float32)
                       if with_telemetry else None)
+            # what layers count, they count from the dispatch's start
+            state = {n: _tdev.zero_layer_counters(s)
+                     for n, s in state.items()}
 
             def pick(arr, idx):
                 return jax.lax.dynamic_index_in_dim(arr, idx, 0,
@@ -610,6 +621,7 @@ class ComputationGraph:
                 losses = np.asarray(losses)[:n_steps]
                 if mvecs is not None:
                     mvecs = np.asarray(mvecs)[:n_steps]
+                self._publish_layer_counters()
             elapsed = time.perf_counter() - t0
             if tel is not None:
                 if tel.flight is not None:
@@ -636,6 +648,16 @@ class ComputationGraph:
                 finally:
                     self.staged_step_time = None
         return losses
+
+    def _publish_layer_counters(self) -> None:
+        """The dispatch's layer counters into the default registry (see
+        ``telemetry/device.py``); nothing for a graph without a counting
+        layer."""
+        from ...telemetry import device as _tdev  # noqa: PLC0415
+
+        _tdev.publish_layer_counters(
+            (n, v.layer, self.state.get(n))
+            for n, v in self.conf.vertices.items() if hasattr(v, "layer"))
 
     def fit(self, data, epochs: int = 1,
             stage_on_device: Optional[int] = None,

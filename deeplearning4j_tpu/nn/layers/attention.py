@@ -63,10 +63,22 @@ class SelfAttentionLayer(BaseLayer):
     installed one via :func:`set_attention_mesh`: "ring" (K/V circulate the
     ICI ring — arbitrarily long sequences) or "all_to_all" (Ulysses-style
     head swap). With no mesh installed the local fused kernel runs.
+
+    Head layouts. ``n_kv_heads`` (0: as many as ``n_heads``) key/value heads
+    are shared by ``n_heads // n_kv_heads`` query heads each (grouped-query
+    attention; 1 is multi-query); ``head_dim`` (0: ``n_out // n_heads``)
+    frees the head size from ``n_out``. Every layout reaches the flash
+    kernel, which reads a shared key/value head in place (one K/V strip per
+    group, no repeated copy) and whose limit is the K/V strip's VMEM; the XLA
+    path and the two mesh paths repeat the shared heads first. The defaults
+    keep the parameter shapes of a plain multi-head layer.
     """
 
     n_out: int = 0
     n_heads: int = 4
+    n_kv_heads: int = 0   # 0: n_heads (no sharing)
+    head_dim: int = 0     # 0: n_out // n_heads
+    has_bias: bool = True  # the output projection's bias
     causal: bool = False
     sequence_parallel: str = "ring"  # ring | all_to_all
     # local-kernel choice: "auto" (cost-model-guided — ops.kernel_select
@@ -95,16 +107,26 @@ class SelfAttentionLayer(BaseLayer):
     def init_params(self, key, input_type) -> Params:
         n_in = input_type.size
         d = self.n_out
-        if d % self.n_heads:
+        if not self.head_dim and d % self.n_heads:
             raise ValueError(f"n_out {d} not divisible by n_heads {self.n_heads}")
+        H, Hkv, D = self._heads()
+        if H % Hkv:
+            raise ValueError(f"n_heads {H} not divisible by n_kv_heads {Hkv}")
         kq, kk, kv, ko = jax.random.split(key, 4)
-        return {
-            "Wq": self._init_weight(kq, (n_in, d), n_in, d),
-            "Wk": self._init_weight(kk, (n_in, d), n_in, d),
-            "Wv": self._init_weight(kv, (n_in, d), n_in, d),
-            "Wo": self._init_weight(ko, (d, d), d, d),
-            "bo": self._init_bias((d,)),
+        p = {
+            "Wq": self._init_weight(kq, (n_in, H * D), n_in, H * D),
+            "Wk": self._init_weight(kk, (n_in, Hkv * D), n_in, Hkv * D),
+            "Wv": self._init_weight(kv, (n_in, Hkv * D), n_in, Hkv * D),
+            "Wo": self._init_weight(ko, (H * D, d), H * D, d),
         }
+        if self.has_bias:
+            p["bo"] = self._init_bias((d,))
+        return p
+
+    def _heads(self):
+        """(query heads, key/value heads, head size)."""
+        return (self.n_heads, self.n_kv_heads or self.n_heads,
+                self.head_dim or self.n_out // self.n_heads)
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         from ...parallel.ring_attention import (  # noqa: PLC0415
@@ -114,13 +136,16 @@ class SelfAttentionLayer(BaseLayer):
         )
 
         B, T, _unused = x.shape
-        H = self.n_heads
-        D = self.n_out // H
+        H, Hkv, D = self._heads()
 
-        def split(w):
-            return (x @ w).reshape(B, T, H, D).transpose(0, 2, 1, 3)
+        def split(w, heads):
+            return (x @ w).reshape(B, T, heads, D).transpose(0, 2, 1, 3)
 
-        q, k, v = split(params["Wq"]), split(params["Wk"]), split(params["Wv"])
+        q = split(params["Wq"], H)
+        k, v = split(params["Wk"], Hkv), split(params["Wv"], Hkv)
+
+        def whole(kv):   # every path but flash reads one K/V head a query head
+            return kv if Hkv == H else jnp.repeat(kv, H // Hkv, axis=1)
         # padded keys are excluded with -inf scores inside the kernel
         key_mask = None if mask is None else mask.astype(x.dtype)
 
@@ -130,22 +155,26 @@ class SelfAttentionLayer(BaseLayer):
 
             variant = _ops.select_attention_variant(
                 B, H, T, D, x.dtype.itemsize, impl=self.attention_impl,
-                causal=self.causal)
+                causal=self.causal, kv_heads=Hkv)
             if variant == "flash":
                 from ...ops.flash_attention import flash_attention  # noqa: PLC0415
 
                 out = flash_attention(q, k, v, causal=self.causal,
                                       key_mask=key_mask)
             else:
-                out = attention(q, k, v, causal=self.causal, key_mask=key_mask)
+                out = attention(q, whole(k), whole(v), causal=self.causal,
+                                key_mask=key_mask)
         else:
             mesh, axis, batch_axes = mesh_ctx
             fn = (ring_attention if self.sequence_parallel == "ring"
                   else all_to_all_attention)
-            out = fn(q, k, v, mesh, seq_axis=axis, causal=self.causal,
-                     key_mask=key_mask, batch_axes=batch_axes)
-        out = out.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
-        out = out @ params["Wo"] + params["bo"]
+            out = fn(q, whole(k), whole(v), mesh, seq_axis=axis,
+                     causal=self.causal, key_mask=key_mask,
+                     batch_axes=batch_axes)
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+        out = out @ params["Wo"]
+        if self.has_bias:
+            out = out + params["bo"]
         out = maybe_dropout(out, self.dropout, train, rng)
         return self._activate(out), state
 

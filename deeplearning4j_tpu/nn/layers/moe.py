@@ -16,17 +16,37 @@ hand-written collectives (SURVEY.md §5.8's design rule).
 Tokens routed past an expert's capacity are dropped by the combine (their MoE
 contribution is zero); the default residual connection keeps their
 representation flowing — the standard Switch-Transformer treatment.
+
+``DroplessExpertsLayer`` stands beside it, not in it: sigmoid scores with a
+selection bias, no capacity and no dropped token, experts computed as grouped
+matrix products over rows sorted by expert, and a chip's share of the experts
+(``experts_held_*``). Nothing of the capacity path's dispatch survives in it
+(the [N, E, C] one-hots, the softmax gates, the per-expert biases, the GSPMD
+expert axis), so one class with both would be two bodies under a flag; the two
+share :func:`expert_row_counts`, what their diagnostics count rows with.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
 from ..conf.inputs import InputType
-from .base import BaseLayer, Params, register_layer, maybe_dropout
+from .base import BaseLayer, Params, State, register_layer, maybe_dropout
+
+
+def expert_row_counts(assignments, n_experts: int):
+    """Rows each of ``n_experts`` experts is given by ``assignments`` (any
+    shape of expert indices; an index outside ``[0, n_experts)`` counts for
+    no expert): the one counting function under ``load_balance_stats`` and
+    the dropless layer's on-device counters."""
+    flat = jnp.asarray(assignments).reshape(-1)
+    inside = (flat >= 0) & (flat < n_experts)
+    return jnp.bincount(jnp.where(inside, flat, n_experts),
+                        length=n_experts + 1)[:n_experts].astype(jnp.int32)
 
 
 @register_layer
@@ -141,10 +161,227 @@ class MixtureOfExpertsLayer(BaseLayer):
         remaining = probs
         for _ in range(self.top_k):
             idx = jnp.argmax(remaining, axis=-1)
-            counts = counts + jnp.bincount(idx, length=self.n_experts)
+            counts = counts + expert_row_counts(idx, self.n_experts)
             remaining = remaining * (1 - jax.nn.one_hot(idx, self.n_experts,
                                                         dtype=remaining.dtype))
         cap = self._capacity(tokens.shape[0])
         dropped = jnp.maximum(counts - cap, 0).sum()
         return {"expert_fraction": counts / tokens.shape[0],
                 "dropped_tokens": int(dropped), "capacity": cap}
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+@register_layer
+@dataclass
+class DroplessExpertsLayer(BaseLayer):
+    """Sigmoid-routed experts without capacity, holding a share of them.
+
+    Over ``tokens`` [N, F] (any leading dims are flattened):
+
+        s = sigmoid(float32(tokens) @ float32(Wr))            [N, n_experts]
+        chosen = the top_k experts by s + e_bias              (bias selects only)
+        w = s[chosen] / sum(s[chosen]) * routed_scaling       (norm_topk_prob)
+        out = sum_k w_k * expert_k(token) + shared_expert(token)
+
+    with ``expert(x) = act(x @ W_up) @ W_down`` (not gated, no bias).
+    ``experts_held_first`` / ``experts_held_count`` (0: all) name the experts
+    whose weights live here: the router scores all ``n_experts``, and only the
+    held experts' part of the result is computed; what the others would add is
+    left out (the sum over every share, with the shared expert counted once,
+    is the whole layer). There is no capacity: the rows that landed here are
+    sorted by expert and both products run as grouped matrix products over
+    them (site ``grouped_matmul``: Mosaic kernels over row tiles that each
+    belong to one expert, or ``jax.lax.ragged_dot``). Shapes stay static by
+    sizing the row buffer for four times the even share and, when more rows
+    land (``lax.cond``), for the most that can.
+
+    ``state["counters"]`` (int32 [4]) counts, since the dispatch began: rows
+    that landed on held experts, the fullest held expert's rows (summed over
+    steps), tokens routed, rows dropped (always 0). ``fit_on_device`` zeroes
+    it before a dispatch and publishes it after (``telemetry/device.py``).
+    """
+
+    n_out: int = 0
+    n_experts: int = 128
+    top_k: int = 6
+    hidden: int = 0               # a routed expert's width
+    shared_hidden: int = 0        # the shared expert's width; 0: none
+    experts_held_first: int = 0
+    experts_held_count: int = 0   # 0: all n_experts
+    routed_scaling: float = 1.0
+    norm_topk_prob: bool = True
+    expert_activation: str = "relu2"
+    init_std: float = 0.02
+    rescale_layers: int = 0       # > 0: down projections at init_std / sqrt(it)
+
+    PARAM_ROLES = {"Ws_up": "ffn_up", "Ws_down": "ffn_down"}
+    FLOAT32_PARAMS = ("Wr", "e_bias")   # the router scores in float32
+    COUNTERS = ("rows_held", "rows_fullest", "tokens", "rows_dropped")
+
+    @property
+    def is_recurrent(self) -> bool:
+        return False  # shape-agnostic over leading dims
+
+    @property
+    def held(self):
+        """(first, count) of the experts whose weights are here."""
+        return (self.experts_held_first,
+                self.experts_held_count or self.n_experts)
+
+    def get_output_type(self, input_type: InputType) -> InputType:
+        if input_type.kind == "rnn":
+            return InputType.recurrent(self.n_out, input_type.timesteps)
+        return InputType.feed_forward(self.n_out)
+
+    def init_params(self, key, input_type) -> Params:
+        n_in = input_type.size
+        if n_in != self.n_out:
+            raise ValueError(f"experts keep the width: n_in {n_in} != n_out "
+                             f"{self.n_out}")
+        first, count = self.held
+        if not 0 <= first <= first + count <= self.n_experts:
+            raise ValueError(f"experts held [{first}, {first + count}) are not "
+                             f"among the {self.n_experts} routed")
+        dt = jnp.result_type(float)
+        kr, ku, kd, ksu, ksd = jax.random.split(key, 5)
+        h = self.hidden or 4 * self.n_out
+        down = self.init_std / math.sqrt(self.rescale_layers or 1)
+        normal = jax.random.normal
+        p = {
+            "Wr": self.init_std * normal(kr, (n_in, self.n_experts), dt),
+            "e_bias": jnp.zeros((self.n_experts,), dt),
+            "W_up": self.init_std * normal(ku, (count, n_in, h), dt),
+            "W_down": down * normal(kd, (count, h, self.n_out), dt),
+        }
+        if self.shared_hidden:
+            p["Ws_up"] = self.init_std * normal(
+                ksu, (n_in, self.shared_hidden), dt)
+            p["Ws_down"] = down * normal(
+                ksd, (self.shared_hidden, self.n_out), dt)
+        return p
+
+    def init_state(self, input_type) -> State:
+        return {"counters": jnp.zeros((len(self.COUNTERS),), jnp.int32)}
+
+    def _act(self):
+        from ..activations import get_activation  # noqa: PLC0415
+
+        return _relu2 if self.expert_activation == "relu2" \
+            else get_activation(self.expert_activation)
+
+    def route(self, params, tokens):
+        """(chosen experts [N, top_k], their weights [N, top_k] float32)."""
+        f = jnp.promote_types(tokens.dtype, jnp.float32)
+        logits = jnp.dot(tokens.astype(f), params["Wr"].astype(f),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(s + params["e_bias"].astype(f), self.top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        if self.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return chosen, w * self.routed_scaling
+
+    def routed(self, params, tokens, token_mask=None):
+        """The held experts' part of the routed sum, [N, n_out], and the
+        counters of this call."""
+        n, _ = tokens.shape
+        first, count = self.held
+        k = self.top_k
+        with jax.named_scope("router"):
+            chosen, w = self.route(params, tokens)
+        from ...ops import select_grouped_matmul_variant  # noqa: PLC0415
+        from ...ops.grouped_matmul import (ROW_TILE, aligned_layout,
+                                           grouped_matmul)
+
+        hidden = params["W_up"].shape[-1]
+        # four times the even share and a tile of padding a group, whole
+        # 512-row tiles; all that can land here when more do
+        usual, most = (-(-(r + count * ROW_TILE) // 512) * 512 for r in
+                       (4 * n * k * count // self.n_experts,
+                        n * min(k, count)))
+        variant = select_grouped_matmul_variant(
+            min(usual, most), self.n_out, hidden, count,
+            tokens.dtype.itemsize)
+        align = ROW_TILE if variant == "fused" else 1
+        with jax.named_scope("dispatch"):
+            local = chosen.reshape(-1) - first                 # [N * k]
+            here = (local >= 0) & (local < count)
+            if token_mask is not None:   # a padded token is routed nowhere
+                here = here & (jnp.repeat(token_mask.reshape(-1), k) > 0)
+            key = jnp.where(here, local, count)                # elsewhere: last
+            order = jnp.argsort(key, stable=True)
+            sizes = expert_row_counts(key, count)
+            rows = jnp.sum(sizes)
+        act = self._act()
+
+        def compute(m):
+            """Both products over a buffer of ``m`` rows, each group's rows
+            laid out from a multiple of ``align``."""
+            with jax.named_scope("dispatch"):
+                group, index, valid, padded = aligned_layout(sizes, align, m)
+                pick = order[index]
+                token_of = pick // k
+                xs = jnp.where(valid[:, None],
+                               jnp.take(tokens, token_of, axis=0), 0)
+                w_rows = jnp.where(valid, w.reshape(-1)[pick], 0)
+            with jax.named_scope("experts"):
+                acc = jnp.promote_types(tokens.dtype, jnp.float32)
+                hid = act(grouped_matmul(xs, params["W_up"], group, padded,
+                                         variant, acc))
+                out = grouped_matmul(hid.astype(tokens.dtype),
+                                     params["W_down"], group, padded,
+                                     variant, acc)
+            with jax.named_scope("combine"):
+                out = jnp.where(valid[:, None], out, 0) \
+                    * w_rows[:, None].astype(acc)
+                y = jnp.zeros((n, self.n_out), acc).at[token_of].add(out)
+            return y.astype(tokens.dtype), jnp.sum(valid).astype(jnp.int32)
+
+        if usual >= most:
+            y, computed = compute(most)
+        else:
+            needed = jnp.sum(aligned_layout(sizes, align, 1)[3])
+            y, computed = jax.lax.cond(needed <= usual,
+                                       lambda: compute(usual),
+                                       lambda: compute(most))
+        tokens_routed = n if token_mask is None \
+            else jnp.sum(token_mask > 0).astype(jnp.int32)
+        counters = jnp.stack([rows, jnp.max(sizes),
+                              jnp.asarray(tokens_routed, jnp.int32),
+                              rows - computed]).astype(jnp.int32)
+        return y, counters
+
+    def shared(self, params, tokens):
+        """The shared expert, which every share computes alike."""
+        with jax.named_scope("shared_expert"):
+            return self._act()(tokens @ params["Ws_up"]) @ params["Ws_down"]
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        lead = x.shape[:-1]
+        tokens = x.reshape(-1, x.shape[-1])
+        token_mask = None
+        if mask is not None and x.ndim == 3 and mask.ndim == 2:
+            token_mask = mask.reshape(-1)
+        out, counters = self.routed(params, tokens, token_mask)
+        if self.shared_hidden:
+            out = out + self.shared(params, tokens)
+        out = maybe_dropout(out.reshape(lead + (self.n_out,)), self.dropout,
+                            train, rng)
+        new_state = dict(state)
+        if "counters" in state:
+            new_state["counters"] = state["counters"] + counters
+        return self._activate(out), new_state
+
+    def load_balance_stats(self, params, x) -> dict:
+        """Routing diagnostics of one batch, outside jit: the share of the
+        top_k assignments each of the ``n_experts`` experts is given."""
+        tokens = jnp.asarray(x).reshape(-1, x.shape[-1])
+        chosen, _ = self.route(params, tokens)
+        counts = expert_row_counts(chosen, self.n_experts)
+        first, count = self.held
+        return {"expert_fraction": counts / tokens.shape[0],
+                "rows_held": int(jnp.sum(counts[first:first + count])),
+                "dropped_tokens": 0}

@@ -308,7 +308,11 @@ class RnnOutputLayer(DenseLayer):
         preout = self.pre_output(params, x)  # [B, T, C]
         C = preout.shape[-1]
         preout2d = preout.reshape(-1, C)
-        labels2d = jnp.asarray(labels).reshape(-1, C)
+        labels2d = jnp.asarray(labels)
+        # integer class ids [B, T] flatten to [B*T]; one-hot rows to [B*T, C]
+        ids = (jnp.issubdtype(labels2d.dtype, jnp.integer)
+               and labels2d.ndim == preout.ndim - 1)
+        labels2d = labels2d.reshape(-1) if ids else labels2d.reshape(-1, C)
         mask1d = None if mask is None else jnp.asarray(mask).reshape(-1)
         return get_loss(self.loss)(labels2d, preout2d, self.activation, mask1d)
 

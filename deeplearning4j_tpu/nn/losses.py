@@ -64,9 +64,15 @@ def mcxent(labels, preout, activation="softmax", mask=None):
     the max/exp/sum/log HBM round trips) when the ``softmax_xent``
     kernel-selection site picks the fused variant for these shapes — see
     ops.kernel_select. Both net classes' output layers route here, so every
-    softmax loss head inherits the selection."""
+    softmax loss head inherits the selection.
+
+    ``labels`` may be integer class ids with one axis fewer than ``preout``
+    (a language model's targets): the same loss as their one-hot form, and no
+    one-hot array is built on either path."""
+    lab = jnp.asarray(labels)
+    if jnp.issubdtype(lab.dtype, jnp.integer) and lab.ndim == preout.ndim - 1:
+        return _mcxent_ids(lab, preout, activation, mask)
     if activation == "softmax":
-        lab = jnp.asarray(labels)
         if preout.ndim == 2 and lab.shape == preout.shape:
             from .. import ops as _ops  # noqa: PLC0415
 
@@ -81,6 +87,23 @@ def mcxent(labels, preout, activation="softmax", mask=None):
         cdt = jnp.promote_types(act.dtype, jnp.float32)
         logp = jnp.log(jnp.clip(act.astype(cdt), EPS, 1.0))
     scores = -(jnp.asarray(labels).astype(logp.dtype) * logp)
+    return _apply_mask(_per_example(scores), mask)
+
+
+def _mcxent_ids(ids, preout, activation, mask):
+    """``mcxent`` for integer class ids ``[...]`` against ``[..., C]``."""
+    if activation == "softmax":   # rows of [N, C]: the softmax_xent site's
+        from .. import ops as _ops  # noqa: PLC0415
+
+        scores = _ops.softmax_xent_rows_ids(
+            ids.reshape(-1), preout.reshape(-1, preout.shape[-1])
+        ).reshape(ids.shape)
+    else:
+        act = _activated(preout, activation)
+        cdt = jnp.promote_types(act.dtype, jnp.float32)
+        logp = jnp.log(jnp.clip(act.astype(cdt), EPS, 1.0))
+        scores = -jnp.take_along_axis(
+            logp, ids.astype(jnp.int32)[..., None], axis=-1)[..., 0]
     return _apply_mask(_per_example(scores), mask)
 
 

@@ -53,6 +53,19 @@ def _cast_params(conf_dtype: str, params):
         if getattr(a, "dtype", None) == jnp.bfloat16 else a, params)
 
 
+def _cast_layer_params(conf_dtype: str, layer, params):
+    """One layer's params for compute: :func:`_cast_params`, less what the
+    layer declares under ``FLOAT32_PARAMS`` (a state-space layer's decay
+    parameters, a router's weights), which reach it as the master holds
+    them and not rounded to bfloat16 on the way. ``ComputationGraph`` casts
+    through here; a ``MultiLayerNetwork`` rounds every leaf alike."""
+    cast = _cast_params(conf_dtype, params)
+    keep = getattr(layer, "FLOAT32_PARAMS", ())
+    if conf_dtype != "bfloat16" or not keep:
+        return cast
+    return {**cast, **{k: params[k] for k in keep if k in params}}
+
+
 def _carry_params_dtype(conf, params):
     """Apply conf.params_dtype to freshly-initialized params (the round-5
     weight-copy lever): "bfloat16" carries params in the compute dtype;

@@ -30,6 +30,7 @@ from .pallas_kernels import (
     fused_lrn,
     fused_lstm_cell,
     fused_softmax_xent,
+    fused_softmax_xent_ids,
     supported_lstm_activations,
 )
 from .flash_attention import flash_attention
@@ -155,6 +156,22 @@ def softmax_xent_rows(labels2d, preout2d):
     return -jnp.sum(labels2d.astype(cdt) * logp, axis=-1)
 
 
+def softmax_xent_rows_ids(ids1d, preout2d):
+    """Per-row softmax cross-entropy of [N, C] logits against [N] integer
+    class ids: the ``softmax_xent`` site's choice, as
+    :func:`softmax_xent_rows` for one-hot labels, and no [N, C] label array
+    on either path."""
+    N, C = preout2d.shape
+    if select_softmax_xent_variant(N, C, preout2d.dtype.itemsize) == "fused":
+        return fused_softmax_xent_ids(preout2d, ids1d)
+    import jax.numpy as jnp  # noqa: PLC0415
+
+    cdt = jnp.promote_types(preout2d.dtype, jnp.float32)
+    logp = jax.nn.log_softmax(preout2d.astype(cdt), axis=-1)
+    return -jnp.take_along_axis(
+        logp, ids1d.astype(jnp.int32)[:, None], axis=-1)[:, 0]
+
+
 # ------------------------------------------------------ selection wrappers
 # Each wrapper maps this module's legacy forcing knobs onto kernel_select's
 # ``forced`` argument (exact historical semantics), then lets the roofline
@@ -184,7 +201,7 @@ def select_lstm_variant(T: int, B: int, H: int, itemsize: int,
 
 def select_attention_variant(B: int, heads: int, T: int, D: int,
                              itemsize: int, impl: str = "auto",
-                             causal: bool = False) -> str:
+                             causal: bool = False, kv_heads: int = 0) -> str:
     """'flash' | 'xla' for a local attention call; an explicit
     ``attention_impl`` ("flash"/"xla") is the per-site escape hatch."""
     forced = impl if impl in ("flash", "xla") else None
@@ -192,6 +209,8 @@ def select_attention_variant(B: int, heads: int, T: int, D: int,
         forced = "xla"
     ctx = {"B": int(B), "heads": int(heads), "T": int(T), "D": int(D),
            "itemsize": int(itemsize), "causal": bool(causal)}
+    if kv_heads and kv_heads != heads:   # grouped-query heads only
+        ctx["kv_heads"] = int(kv_heads)
     return kernel_select.select("attention", ctx, forced=forced)
 
 
@@ -217,6 +236,25 @@ def select_softmax_xent_variant(N: int, C: int, itemsize: int) -> str:
     return kernel_select.select("softmax_xent", ctx, forced=forced)
 
 
+def select_ssd_scan_variant(B: int, T: int, H: int, P: int, G: int, N: int,
+                            chunk: int, itemsize: int) -> str:
+    """'fused' | 'reference' for one chunked state-space scan."""
+    forced = "reference" if _FORCED is False else None
+    ctx = {"B": int(B), "T": int(T), "H": int(H), "P": int(P), "G": int(G),
+           "N": int(N), "chunk": int(chunk), "itemsize": int(itemsize)}
+    return kernel_select.select("ssd_scan", ctx, forced=forced)
+
+
+def select_grouped_matmul_variant(M: int, K: int, N: int, E: int,
+                                  itemsize: int) -> str:
+    """'fused' | 'reference' for the grouped products of ``M`` buffered rows
+    with ``E`` matrices [K, N] (and [N, K] back)."""
+    forced = "reference" if _FORCED is False else None
+    ctx = {"M": int(M), "K": int(K), "N": int(N), "E": int(E),
+           "itemsize": int(itemsize)}
+    return kernel_select.select("grouped_matmul", ctx, forced=forced)
+
+
 def select_optimizer_variant(n_elems: int, itemsize: int, updater: str,
                              n_leaves: int = 1) -> str:
     forced = "reference" if _FORCED is False else None
@@ -231,17 +269,21 @@ __all__ = [
     "fused_lrn",
     "fused_lstm_cell",
     "fused_softmax_xent",
+    "fused_softmax_xent_ids",
     "helpers_enabled",
     "kernel_select",
     "lrn",
     "lstm_cell",
     "lstm_helper_enabled",
     "select_attention_variant",
+    "select_grouped_matmul_variant",
     "select_lrn_variant",
     "select_lstm_variant",
     "select_optimizer_variant",
     "select_softmax_xent_variant",
+    "select_ssd_scan_variant",
     "set_helpers_enabled",
     "softmax_xent_rows",
+    "softmax_xent_rows_ids",
     "supported_lstm_activations",
 ]

@@ -177,20 +177,24 @@ def _pad_to(x, axis: int, mult: int):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_core(q, k, v, mask, causal, scale, block_q, block_k):
+    """``q`` [B*H, T, D] against ``k``/``v`` [B*Hkv, T, D]: query head ``i``
+    reads key/value head ``i // (H // Hkv)`` through the block index, so a
+    shared head is never copied."""
     out, _ = _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k)
     return out
 
 
 def _flash_call(q, k, v, mask, causal, scale, block_q, block_k):
     bh, t, d = q.shape
+    grp = bh // k.shape[0]     # query heads a key/value head
     grid = (bh, t // block_q)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, block_k, causal, scale),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda b, i: (b // grp, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda b, i: (b // grp, 0, 0)),
             pl.BlockSpec((1, 1, t), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
@@ -214,14 +218,15 @@ def _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k):
 def _flash_bwd(causal, scale, block_q, block_k, residuals, g):
     q, k, v, mask, out, lse = residuals
     bh, t, d = q.shape
+    grp = bh // k.shape[0]
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, None, :]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k, causal, scale),
         grid=(bh, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda b, i: (b // grp, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda b, i: (b // grp, 0, 0)),
             pl.BlockSpec((1, 1, t), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
@@ -237,8 +242,8 @@ def _flash_bwd(causal, scale, block_q, block_k, residuals, g):
         grid=(bh, t // block_k),
         in_specs=[
             pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j: (b // grp, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j: (b // grp, j, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, j: (b, 0, j)),
             pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, 1, t), lambda b, j: (b, 0, 0)),
@@ -255,6 +260,9 @@ def _flash_bwd(causal, scale, block_q, block_k, residuals, g):
         interpret=_interpret(),
         name="flash_bwd_dkv",
     )(q, k, v, mask, g, lse, delta)
+    if grp > 1:   # a shared head's gradient is the sum over its query heads
+        dk = dk.reshape(bh // grp, grp, t, d).sum(axis=1).astype(k.dtype)
+        dv = dv.reshape(bh // grp, grp, t, d).sum(axis=1).astype(v.dtype)
     return dq, dk, dv, None
 
 
@@ -264,14 +272,19 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, key_mask=None,
                     block_q: int = 128, block_k: int = 128):
-    """Blockwise flash attention. q/k/v: [B, H, T, D]; key_mask: [B, T]
-    (1 = real key). Same contract as ``ring_attention.attention``.
+    """Blockwise flash attention. q: [B, H, T, D]; k/v: [B, Hkv, T, D] with
+    ``Hkv`` dividing ``H`` (grouped-query heads: query head ``i`` reads
+    key/value head ``i // (H // Hkv)`` in place); key_mask: [B, T] (1 = real
+    key). With ``Hkv == H`` the contract of ``ring_attention.attention``.
 
     T is padded internally to a block multiple (padded keys masked out,
     padded query rows sliced off), so any sequence length works; block sizes
     shrink automatically for short sequences.
     """
     b, h, t, d = q.shape
+    hkv = k.shape[1]
+    if h % hkv:
+        raise ValueError(f"{h} query heads do not share {hkv} key/value heads")
     scale = float(scale if scale is not None else d ** -0.5)
     # K+V strip per grid program must fit VMEM (see module docstring);
     # past the budget the XLA reference path is used instead — same
@@ -279,14 +292,16 @@ def flash_attention(q, k, v, causal: bool = False,
     if 2 * t * d * q.dtype.itemsize > _KV_VMEM_BUDGET_BYTES:
         from ..parallel.ring_attention import attention as _xla_attention
 
+        if hkv != h:
+            k, v = (jnp.repeat(a, h // hkv, axis=1) for a in (k, v))
         return _xla_attention(q, k, v, causal=causal, scale=scale,
                               key_mask=key_mask)
     block_q = min(block_q, max(t, 1))
     block_k = min(block_k, max(t, 1))
 
     qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
+    kf = k.reshape(b * hkv, t, d)
+    vf = v.reshape(b * hkv, t, d)
     if key_mask is None:
         mask = jnp.ones((b, t), jnp.float32)
     else:

@@ -736,6 +736,112 @@ register_site(Site(
 ))
 
 
+def _ssd_flops(ctx) -> float:
+    """Forward products of the chunked algorithm (a group's ``C B^T`` scores
+    once, then a head's ``M x``, ``C S`` and the state's update), three times
+    for forward + backward."""
+    B, T, H, P, G, N, L = (ctx[k] for k in ("B", "T", "H", "P", "G", "N",
+                                            "chunk"))
+    fwd = B * (T / L) * (G * 2.0 * L * L * N
+                         + H * (2.0 * L * L * P + 4.0 * L * N * P))
+    return 3.0 * fwd
+
+
+def _ssd_streams(ctx) -> float:
+    B, T, H, P, G, N = (ctx[k] for k in ("B", "T", "H", "P", "G", "N"))
+    return B * T * (H * P + 2.0 * G * N)
+
+
+def _ssd_fused_cost(ctx):
+    # forward: x B C in, y out; backward: those and dy in, dx dB dC out; the
+    # chunk-start states (float32) written once and read once
+    states = 4.0 * 2.0 * ctx["B"] * (ctx["T"] / ctx["chunk"]) \
+        * ctx["H"] * ctx["P"] * ctx["N"]
+    nbytes = ctx["itemsize"] * 5.0 * _ssd_streams(ctx) + states
+    return _ssd_flops(ctx), nbytes, 2 * _LAUNCH_S
+
+
+def _ssd_reference_cost(ctx):
+    # un-fused counting: the [L, L] scores, decay mask and their product a
+    # head are materialized in float32, forward and again in the adjoint
+    B, T, H, L = ctx["B"], ctx["T"], ctx["H"], ctx["chunk"]
+    nbytes = ctx["itemsize"] * 10.0 * _ssd_streams(ctx) \
+        + 4.0 * 12.0 * B * T * H * L
+    return _ssd_flops(ctx), nbytes, 0.0
+
+
+def _ssd_fits_ctx(ctx) -> bool:
+    from .ssd_scan import ssd_fits, ssd_layout_ok  # noqa: PLC0415
+
+    hpg = ctx["H"] // ctx["G"]
+    return ssd_layout_ok(ctx["chunk"], ctx["P"], ctx["N"], hpg) and ssd_fits(
+        ctx["chunk"], ctx["P"], ctx["N"], hpg, ctx["itemsize"])
+
+
+register_site(Site(
+    name="ssd_scan",
+    reference="reference",
+    preferred_fused="fused",
+    variants={
+        "fused": Variant("fused", fused=True, cost=_ssd_fused_cost,
+                         available=_ssd_fits_ctx,
+                         detail=lambda ctx: {"chunk": ctx["chunk"]}),
+        "reference": Variant("reference", fused=False,
+                             cost=_ssd_reference_cost, unfused_bytes=True,
+                             detail=lambda ctx: {"chunk": ctx["chunk"]}),
+    },
+))
+
+
+_RAGGED_DOT_MXU_SHARE = 0.07   # XLA's grouped product on the v5e (PERF.md, PR 30)
+
+
+def _gmm_flops(ctx) -> float:
+    # forward, dlhs and drhs over the rows the buffer is sized for four times
+    return 3.0 * 2.0 * (ctx["M"] / 4.0) * ctx["K"] * ctx["N"]
+
+
+def _gmm_bytes(ctx) -> float:
+    return ctx["itemsize"] * 3.0 * (ctx["E"] * ctx["K"] * ctx["N"]
+                                    + (ctx["M"] / 4.0) * (ctx["K"] + ctx["N"]))
+
+
+def _gmm_fused_cost(ctx):
+    return _gmm_flops(ctx), _gmm_bytes(ctx), 3 * _LAUNCH_S
+
+
+def _gmm_reference_cost(ctx):
+    # measured, not modelled: 6.4 us a row at the published widths
+    return _gmm_flops(ctx) / _RAGGED_DOT_MXU_SHARE, _gmm_bytes(ctx), 0.0
+
+
+def _gmm_fits_ctx(ctx) -> bool:
+    from .grouped_matmul import gmm_fits, gmm_layout_ok  # noqa: PLC0415
+
+    return gmm_layout_ok(ctx["M"], ctx["K"], ctx["N"]) and gmm_fits(
+        ctx["K"], ctx["N"], ctx["itemsize"])
+
+
+def _gmm_detail(ctx) -> dict:
+    from .grouped_matmul import ROW_TILE  # noqa: PLC0415
+
+    return {"row_tile": ROW_TILE}
+
+
+register_site(Site(
+    name="grouped_matmul",
+    reference="reference",
+    preferred_fused="fused",
+    variants={
+        "fused": Variant("fused", fused=True, cost=_gmm_fused_cost,
+                         available=_gmm_fits_ctx, detail=_gmm_detail),
+        "reference": Variant("reference", fused=False,
+                             cost=_gmm_reference_cost,
+                             detail=lambda ctx: {"row_tile": 1}),
+    },
+))
+
+
 def _opt_fused_cost(ctx):
     n, itemsize = ctx["n_elems"], ctx["itemsize"]
     # read g/m/v, write u/m/v in one pass per leaf

@@ -855,6 +855,89 @@ def _sxent_bwd(residuals, g):
 fused_softmax_xent.defvjp(_sxent_fwd, _sxent_bwd)
 
 
+# -- integer labels: the same pass without a one-hot [N, C] label array ------
+#
+# A language model's head has 16k-260k classes; its labels are class ids. The
+# kernels below are the two above with the label block replaced by an [R, 1]
+# column of ids, compared against a lane iota in the tile. ``_sxent_tile``
+# sizes their blocks as it does the one-hot pair's (fewer [R, C] blocks are
+# live here, so its reckoning is an upper bound).
+
+
+@jit_entry
+def _sxent_ids_fwd_kernel(x_ref, id_ref, loss_ref):
+    cdt = _sxent_compute_dt(x_ref.dtype)
+    x = x_ref[:].astype(cdt)
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) == id_ref[:]
+    m = jnp.max(x, axis=-1, keepdims=True)
+    lse = jnp.log(jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True)) + m
+    picked = jnp.sum(jnp.where(hit, x, 0.0), axis=-1, keepdims=True)
+    loss_ref[:] = (lse - picked).astype(loss_ref.dtype)
+
+
+@jit_entry
+def _sxent_ids_bwd_kernel(x_ref, id_ref, g_ref, dx_ref):
+    cdt = _sxent_compute_dt(x_ref.dtype)
+    x = x_ref[:].astype(cdt)
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) == id_ref[:]
+    m = jnp.max(x, axis=-1, keepdims=True)
+    ex = jnp.exp(x - m)
+    p = ex / jnp.sum(ex, axis=-1, keepdims=True)
+    dx_ref[:] = ((p - jnp.where(hit, 1.0, 0.0)) * g_ref[:].astype(cdt)
+                 ).astype(dx_ref.dtype)
+
+
+@jax.custom_vjp
+def fused_softmax_xent_ids(preout, ids):
+    """Per-row ``-log_softmax(preout)[ids]`` for [N, C] logits and [N] integer
+    class ids, one fused VMEM pass; [N] row losses in the promoted dtype."""
+    return _sxent_ids_fwd_impl(preout, ids)
+
+
+def _sxent_ids_fwd_impl(preout, ids):
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+
+    N, C = preout.shape
+    grid, mat, col = _sxent_specs(N, C)
+    out = pl.pallas_call(
+        _sxent_ids_fwd_kernel,
+        grid=grid,
+        in_specs=[mat, col],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((N, 1), _sxent_compute_dt(preout.dtype)),
+        interpret=_interpret(),
+        name="softmax_xent_ids_fwd",
+    )(preout, ids.reshape(N, 1).astype(jnp.int32))
+    return out[:, 0]
+
+
+def _sxent_ids_fwd(preout, ids):
+    return _sxent_ids_fwd_impl(preout, ids), (preout, ids)
+
+
+def _sxent_ids_bwd(residuals, g):
+    import numpy as np  # noqa: PLC0415
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+
+    preout, ids = residuals
+    N, C = preout.shape
+    grid, mat, col = _sxent_specs(N, C)
+    dx = pl.pallas_call(
+        _sxent_ids_bwd_kernel,
+        grid=grid,
+        in_specs=[mat, col, col],
+        out_specs=mat,
+        out_shape=jax.ShapeDtypeStruct((N, C), preout.dtype),
+        interpret=_interpret(),
+        name="softmax_xent_ids_bwd",
+    )(preout, ids.reshape(N, 1).astype(jnp.int32),
+      g.reshape(N, 1).astype(_sxent_compute_dt(preout.dtype)))
+    return dx, np.zeros(ids.shape, jax.dtypes.float0)   # ids carry no gradient
+
+
+fused_softmax_xent_ids.defvjp(_sxent_ids_fwd, _sxent_ids_bwd)
+
+
 # ---------------------------------------------------------------------------
 # fused Adam update — the optimizer-step hot path
 # ---------------------------------------------------------------------------
@@ -867,7 +950,14 @@ fused_softmax_xent.defvjp(_sxent_fwd, _sxent_bwd)
 # differentiated: optimizer updates sit outside jax.grad by construction.
 
 _ADAM_LANES = 128
-_ADAM_TILE_ROWS = 4096
+# A block of at most 1 MiB: the call's three operands and three results,
+# double-buffered, are then 12 MiB of the 16 MiB the compiler gives a kernel
+# by default, so no call states a limit of its own and none depends on where
+# XLA keeps its operands. (At 2 MiB, the 4096 lane rows this kernel began
+# with, a call of two or more blocks asks for 24 MiB: the char-RNN's 4 MiB
+# leaves compiled only because XLA held them in VMEM, and inside the 9-block
+# hybrid program a [2688, 256] leaf was refused; PERF.md, PR 30.)
+_ADAM_BLOCK_BYTES = 1 << 20
 
 
 @jit_entry
@@ -886,36 +976,75 @@ def _adam_kernel(b1, b2, eps, g_ref, m_ref, v_ref, sc_ref,
     v_out[:] = v.astype(v_out.dtype)
 
 
+# Up to here a leaf is flattened into lane rows, as this kernel always did:
+# XLA holds leaves of this size in VMEM, where the relayout that flattening
+# asks for costs next to nothing (the char-RNN's largest are 4 MiB; tiled as
+# they lie that cell read 0.6% lower, PERF.md, PR 30). Above it the relayout
+# is an HBM copy of each operand and result: 29 ms of a 524 ms step of the
+# hybrid model when its lane-tiled leaves were flattened (PERF.md, PR 30).
+_ADAM_FLATTEN_BYTES = 4 << 20
+
+
+def _adam_view(shape, itemsize: int):
+    """How a leaf of ``shape`` is handed to the kernel as ``[rows, cols]``:
+    ``(swap, rows, cols, tile)``. Flattened into lane rows up to
+    ``_ADAM_FLATTEN_BYTES`` and where nothing else can be done (one axis, a
+    last axis short of a lane tile, rows that are no whole sublane tiles). A
+    longer matrix is taken as it lies, its leading axes merged, so that no
+    element changes its tile and XLA moves nothing. (The TPU keeps a matrix
+    whose last axis is no whole number of lane tiles and whose last but one
+    is, [2688, 1856], with the two swapped: ``swap`` views it so.)"""
+    n = 1
+    for d in shape:
+        n *= d
+    lane_rows = _ADAM_BLOCK_BYTES // (_ADAM_LANES * itemsize)
+    flat = (False, None, _ADAM_LANES if n >= _ADAM_LANES else max(n, 1),
+            lane_rows)
+    if len(shape) < 2 or n * itemsize <= _ADAM_FLATTEN_BYTES:
+        return flat
+    swap = shape[-1] % _ADAM_LANES != 0 and shape[-2] % _ADAM_LANES == 0
+    cols, sublanes = (shape[-2], shape[-1]) if swap else (shape[-1], shape[-2])
+    if cols < _ADAM_LANES or (len(shape) > 2 and sublanes % 8):
+        return flat
+    lanes = -(-cols // _ADAM_LANES) * _ADAM_LANES
+    return swap, n // cols, cols, max(
+        8, _ADAM_BLOCK_BYTES // (lanes * itemsize) // 8 * 8)
+
+
 def fused_adam_update(g, m, v, lr, bc1, bc2,
                       b1: float, b2: float, eps: float):
     """One fused Adam step for one parameter leaf: returns
     ``(update, new_m, new_v)`` with ``update = -lr·m̂/(√v̂+eps)`` using
     exactly optax's ``scale_by_adam`` bias corrections (``bc1``/``bc2`` are
     the traced ``1 - βᵢ**t`` scalars, ``lr`` the schedule's value). Any leaf
-    shape: the view is flattened, lane-padded, and row-tiled; padded slots
-    compute a zero update and are sliced off."""
+    shape, viewed as :func:`_adam_view` says and row-tiled in blocks of at
+    most ``_ADAM_BLOCK_BYTES``; a flattened view is lane-padded, its padded
+    slots compute a zero update and are sliced off."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
     shape, dt = g.shape, g.dtype
     n = g.size
-    cols = _ADAM_LANES if n >= _ADAM_LANES else max(n, 1)
-    pad = (-n) % cols
-    rows = (n + pad) // cols
+    sdt = jnp.promote_types(dt, jnp.float32)
+    swap, rows, cols, tile = _adam_view(shape, sdt.itemsize)
+    if swap:
+        g, m, v = (jnp.swapaxes(a, -1, -2) for a in (g, m, v))
+    lies = g.shape
+    pad = 0 if rows is not None else (-n) % cols
+    rows = rows if rows is not None else (n + pad) // cols
 
     def flat(a):
-        a = a.reshape(-1).astype(dt)
+        a = a.astype(dt)
         if pad:
-            a = jnp.concatenate([a, jnp.zeros((pad,), dt)])
+            a = jnp.concatenate([a.reshape(-1), jnp.zeros((pad,), dt)])
         return a.reshape(rows, cols)
 
     # traced scalars ride one (3,) array in SMEM — Mosaic reads scalars
     # from scalar memory, not from a VMEM block: lr, 1-b1^t, 1-b2^t (kept at
     # >=f32 — f64 under the x64 test env so parity against optax holds)
-    sdt = jnp.promote_types(dt, jnp.float32)
     scalars = jnp.stack([jnp.asarray(lr), jnp.asarray(bc1),
                          jnp.asarray(bc2)]).astype(sdt)
-    tile = min(_ADAM_TILE_ROWS, rows)
+    tile = min(tile, rows)
     grid = (pl.cdiv(rows, tile),)
     mat = pl.BlockSpec((tile, cols), lambda i: (i, 0))
     sc = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -930,6 +1059,7 @@ def fused_adam_update(g, m, v, lr, bc1, bc2,
     )(flat(g), flat(m), flat(v), scalars)
 
     def unflat(a):
-        return a.reshape(-1)[:n].reshape(shape)
+        a = a.reshape(-1)[:n].reshape(lies)
+        return jnp.swapaxes(a, -1, -2) if swap else a
 
     return unflat(u2), unflat(m2), unflat(v2)

@@ -55,3 +55,53 @@ def step_stats(loss, grads=None):
         gnorm = jnp.zeros((), jnp.float32)
     nonfinite = 1.0 - finite.astype(jnp.float32)
     return jnp.stack([loss32, gnorm, nonfinite])
+
+
+# --------------------------------------------------------------------------
+# Layer counters: what a layer counts on the device inside the step.
+#
+# A layer that counts (the dropless expert layer: rows that landed on the
+# experts it holds) keeps an int32 vector under ``state["counters"]``, named
+# entry by entry by its class's ``COUNTERS``, and adds to it every step. The
+# staged program zeroes the vectors when a dispatch begins, so after it they
+# hold the dispatch's sums; ``fit_on_device`` fetches them beside the losses
+# (inside ``dl4j.fit.fetch``: the program has ended, nothing waits) and adds
+# them to ``dl4jtpu_layer_counter_total{layer,counter}`` of the default
+# registry. No step reads them back, and the per-step ``fit`` path leaves
+# them in ``net.state`` unpublished.
+LAYER_COUNTERS = "counters"
+LAYER_COUNTER_FAMILY = "dl4jtpu_layer_counter_total"
+
+
+def zero_layer_counters(layer_state):
+    """``layer_state`` (one layer's state dict) with its counters at zero."""
+    if not isinstance(layer_state, dict) or LAYER_COUNTERS not in layer_state:
+        return layer_state
+    import jax.numpy as jnp
+
+    return {**layer_state, LAYER_COUNTERS:
+            jnp.zeros_like(layer_state[LAYER_COUNTERS])}
+
+
+def publish_layer_counters(named_layers) -> None:
+    """Fetch the counters of ``(name, layer, layer_state)`` triples and add
+    them to the default registry; a layer without ``COUNTERS`` is passed
+    over."""
+    import numpy as np
+
+    family = None
+    for name, layer, layer_state in named_layers:
+        names = getattr(layer, "COUNTERS", None)
+        if not names or not isinstance(layer_state, dict) \
+                or LAYER_COUNTERS not in layer_state:
+            continue
+        if family is None:
+            from . import get_registry
+
+            family = get_registry().counter(
+                LAYER_COUNTER_FAMILY,
+                "what layers count on the device, summed per dispatch",
+                labelnames=("layer", "counter"))
+        values = np.asarray(layer_state[LAYER_COUNTERS])
+        for counter, value in zip(names, values):
+            family.labels(layer=name, counter=counter).inc(float(value))

@@ -80,6 +80,40 @@ def test_leg_e_on_the_virtual_mesh():
     assert len(res["dp4"]) == len(res["dp2xfsdp2"]) == 3
 
 
+def _tiny_hybrid_sizes():
+    import json
+
+    with open(os.path.join(REPO, "tests", "benchmark_harness", "presets",
+                           "configs", "nemotron3_nano_30b_a3b.json")) as f:
+        return json.load(f)["sizes"]
+
+
+@pytest.mark.parametrize("mode", ["auto", "fused"])
+def test_leg_f_hybrid_blocks_tiny(monkeypatch, mode):
+    # float32 on the CPU, so the tolerances are rounding's; "fused" takes
+    # the flash and loss kernels in interpret mode (the scan kernels' layout
+    # needs the published widths: tests/test_nemotron_h.py calls them)
+    monkeypatch.setenv("DL4JTPU_KERNELS", mode)
+    tol = dict.fromkeys(("M", "A", "E", "head"), 1e-4)
+    res = chip_smoke.leg_f_hybrid_blocks(
+        _tiny_hybrid_sizes(), seq_len=24, batch=2, dtype="float32",
+        tolerances=tol)
+    assert set(res["worst"]) == {
+        "M", "A", "E", "head", "M with the scan state in bfloat16 (control)"}
+    assert res["worst"]["M with the scan state in bfloat16 (control)"] > 1e-3
+    if mode == "fused":
+        assert res["selection"]["attention"] == "flash"
+        assert res["selection"]["softmax_xent"] == "fused"
+
+
+def test_leg_f_fails_when_the_lower_precision_control_passes():
+    tol = dict.fromkeys(("M", "A", "E", "head"), 0.5)
+    with pytest.raises(chip_smoke.LegFailure, match="control"):
+        chip_smoke.leg_f_hybrid_blocks(
+            _tiny_hybrid_sizes(), seq_len=16, batch=1, dtype="float32",
+            tolerances=tol, kinds="M")
+
+
 def test_main_refuses_to_pass_without_a_chip():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],

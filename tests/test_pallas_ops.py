@@ -445,3 +445,61 @@ def test_rnn_time_step_streaming_under_seq_kernel(monkeypatch):
     for a, b in zip(outs["0"][1], outs["seq"][1]):
         # the carried h/c crossed the kernel boundary identically
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("shape", [
+    (512, 2048),      # 4 MiB: flattened into lane rows, four blocks
+    (1100, 1000),     # longer: tiled as it lies
+    (1100, 1024),     # the same with a last axis of whole lane tiles
+    (8, 512, 320),    # longer, last but one whole lane tiles: axes swapped
+    (3, 700, 500),    # rows no whole sublane tiles: flattened after all
+    (100, 33),        # short: flattened and lane-padded
+])
+def test_fused_adam_update_is_adams_step_in_every_view_of_a_leaf(shape):
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    key = jax.random.PRNGKey(len(shape) + shape[-1])
+    g, m, v = (jax.random.normal(k, shape, jnp.float32)
+               for k in jax.random.split(key, 3))
+    v = jnp.square(v)
+    lr, b1, b2, eps, t = 1e-3, 0.9, 0.999, 1e-8, 3
+    bc1, bc2 = 1 - b1 ** t, 1 - b2 ** t
+    u, m2, v2 = pk.fused_adam_update(g, m, v, lr, bc1, bc2, b1, b2, eps)
+    want_m = b1 * m + (1 - b1) * g
+    want_v = b2 * v + (1 - b2) * g * g
+    want_u = -lr * (want_m / bc1) / (jnp.sqrt(want_v / bc2) + eps)
+    assert u.shape == m2.shape == v2.shape == shape
+    np.testing.assert_allclose(np.asarray(m2), np.asarray(want_m), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(v2), np.asarray(want_v), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(u), np.asarray(want_u), rtol=1e-4,
+                               atol=1e-8)
+
+
+def test_adam_views_keep_six_double_buffered_blocks_inside_the_default():
+    """One rule for every leaf: blocks of at most 1 MiB, so no call states
+    a VMEM limit; a leaf of up to 4 MiB is flattened, a longer matrix is
+    tiled as it lies."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    def view(shape):
+        swap, rows, cols, tile = pk._adam_view(shape, 4)
+        lanes = -(-cols // pk._ADAM_LANES) * pk._ADAM_LANES
+        assert 12 * tile * lanes * 4 <= 12 * pk._ADAM_BLOCK_BYTES < 16 << 20
+        return swap, rows, cols
+
+    # charrnn_2x512's leaves (4 MiB at most), and what cannot be tiled as it
+    # lies: lane rows, as ever
+    for shape in [(512, 2048), (96, 2048), (512, 96), (2048,), (512,),
+                  (2688, 256), (4, 6144), (3, 700, 500), (2000000,)]:
+        assert view(shape) == (False, None, 128), shape
+    assert view((96,)) == (False, None, 96)
+    # a longer matrix: as it lies, swapped where the TPU keeps it so
+    assert view((16384, 2688)) == (False, 16384, 2688)
+    assert view((2688, 16384)) == (False, 2688, 16384)
+    assert view((8, 1856, 2688)) == (False, 8 * 1856, 2688)
+    assert view((8, 2688, 1856)) == (True, 8 * 1856, 2688)
+    assert view((2688, 10304)) == (True, 10304, 2688)
+    assert view((1100, 1000)) == (False, 1100, 1000)
+    assert view((8, 512, 320)) == (True, 8 * 320, 512)

@@ -72,3 +72,64 @@ def test_seq_kernels_compile_for_the_v5e_within_their_own_vmem_limit(
     assert text.count("tpu_custom_call") == 2
     assert prefix + "fwd" in text and prefix + "bwd" in text
     assert lean.count("tpu_custom_call") == 1 and "lstm_seq_lean" in lean
+
+
+@pytest.mark.parametrize("B,T,H,P,G,N,chunk,dtype", [
+    (1, 8192, 64, 64, 8, 128, 128, "bfloat16"),  # nemotron3_nano_train_1chip
+    (1, 8192, 64, 64, 8, 128, 128, "float32"),   # chip_smoke's f32
+    (2, 1000, 64, 64, 8, 128, 128, "bfloat16"),  # T padded to whole chunks
+    (1, 512, 16, 64, 8, 128, 128, "bfloat16"),   # two heads a group: one pack
+])
+def test_ssd_scan_kernels_compile_for_the_v5e_at_the_published_shapes(
+        one_chip, monkeypatch, B, T, H, P, G, N, chunk, dtype):
+    from deeplearning4j_tpu.ops import ssd_scan as ss
+
+    monkeypatch.setattr(ss, "_interpret", lambda: False)
+    dt = jnp.dtype(dtype)
+    assert ss.ssd_layout_ok(chunk, P, N, H // G)
+    assert ss.ssd_fits(chunk, P, N, H // G, dt.itemsize)
+    s = lambda shape, d=dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, d, sharding=one_chip)
+    args = (s((B, T, H, P)), s((B, T, H), jnp.float32), s((H,), jnp.float32),
+            s((B, T, G, N)), s((B, T, G, N)))
+
+    def loss(*a):
+        y = ss.ssd_scan_fused(*a, chunk)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    with jax.enable_x64(False):
+        text = grad.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+
+
+@pytest.mark.parametrize("M,K,N,E,dtype", [
+    (14336, 2688, 1856, 8, "bfloat16"),   # nemotron3_nano_train_1chip: up
+    (14336, 1856, 2688, 8, "bfloat16"),   # ... and down
+    (51200, 2688, 1856, 8, "bfloat16"),   # the buffer for all that can land
+    (14336, 2688, 1856, 8, "float32"),    # chip_smoke's f32
+    (512, 64, 32, 2, "bfloat16"),         # widths short of a lane tile
+])
+def test_grouped_matmul_kernels_compile_for_the_v5e_at_the_published_shapes(
+        one_chip, monkeypatch, M, K, N, E, dtype):
+    from deeplearning4j_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    dt = jnp.dtype(dtype)
+    assert gm.gmm_layout_ok(M, K, N) and gm.gmm_fits(K, N, dt.itemsize)
+    s = lambda shape, d=dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, d, sharding=one_chip)
+
+    def loss(lhs, rhs, sizes):
+        group, _, _, padded = gm.aligned_layout(sizes, gm.ROW_TILE, M)
+        out = gm.grouped_matmul_fused(lhs, rhs, group, padded, jnp.float32)
+        return jnp.sum(out ** 2)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    with jax.enable_x64(False):
+        text = grad.lower(s((M, K)), s((E, K, N)),
+                          s((E,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("fwd", "dlhs", "drhs"):
+        assert "grouped_matmul_" + name in text
