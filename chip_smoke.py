@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import json
 import os
 import sys
@@ -722,34 +723,66 @@ def _kd_adam(shape, dtype):
             "m": _err(m2, m_ref), "v": _err(v2, v_ref)}
 
 
-def _kd_flash(B, H, T, D, dtype):
+def _flash_reference_by_head(q, k, v, causal, kmask):
+    """The XLA attention in float32, one query head at a time (all 32 heads
+    of the cell's shape at once would materialise 8.6 GB of float32 scores):
+    the output and the gradients of ``sum(out ** 2)``, a shared key/value
+    head's summed over its query heads."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.parallel.ring_attention import attention
+
+    H, Hkv = q.shape[1], k.shape[1]
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+
+    def head(i):
+        take = lambda a, j: jax.lax.dynamic_slice_in_dim(a, j, 1, axis=1)  # noqa: E731
+        out, vjp = jax.vjp(
+            lambda *a: attention(*a, causal=causal, key_mask=kmask),
+            take(q, i), take(k, i // (H // Hkv)), take(v, i // (H // Hkv)))
+        return out[:, 0], tuple(g[:, 0] for g in vjp(2 * out))
+
+    out, (dq, dk, dv) = jax.lax.map(head, jnp.arange(H))   # [H, B, T, D]
+    shared = lambda g: jnp.moveaxis(  # noqa: E731
+        g.reshape(Hkv, H // Hkv, *g.shape[1:]).sum(axis=1), 0, 1)
+    return jnp.moveaxis(out, 0, 1), (jnp.moveaxis(dq, 0, 1), shared(dk),
+                                     shared(dv))
+
+
+def _kd_flash(B, H, T, D, dtype, kv_heads=None, causal_only=False):
+    """The flash kernels, forward and gradients, against the XLA reference:
+    with a random key mask, causal and not; ``causal_only`` is the hybrid
+    cell's call (causal, no key mask, ``kv_heads`` shared heads)."""
     import jax
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.ops.flash_attention import flash_attention
-    from deeplearning4j_tpu.parallel.ring_attention import attention
 
     rng = np.random.default_rng(0)
-    q, k, v = (jnp.asarray(rng.normal(size=(B, H, T, D)), dtype)
-               for _ in range(3))
-    kmask = jnp.asarray(rng.random((B, T)) > 0.2)
+    q = jnp.asarray(rng.normal(size=(B, H, T, D)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(B, kv_heads or H, T, D)), dtype)
+            for _ in range(2))
+    kmask = None if causal_only else jnp.asarray(rng.random((B, T)) > 0.2)
     out = {}
     # f32 matmul precision: with the MXU's default bf16 multiply flash-vs-XLA
     # causal grads differ ~2% from arithmetic alone, masking logic bugs
     with jax.default_matmul_precision("float32"):
-        for causal in (False, True):
+        for causal in (True,) if causal_only else (False, True):
             fl = lambda q, k, v: flash_attention(  # noqa: E731
                 q, k, v, causal=causal, key_mask=kmask)
-            rf = lambda q, k, v: attention(  # noqa: E731
-                q.astype(jnp.float32), k.astype(jnp.float32),
-                v.astype(jnp.float32), causal=causal, key_mask=kmask)
-            out[f"fwd_causal={causal}"] = _err(jax.jit(fl)(q, k, v),
-                                               rf(q, k, v))
-            gl = lambda fn: jax.grad(lambda *a: jnp.sum(  # noqa: E731
-                fn(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+            gl = jax.grad(lambda *a: jnp.sum(  # noqa: E731
+                fl(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+            ref, ref_grads = jax.jit(functools.partial(
+                _flash_reference_by_head, causal=causal, kmask=kmask))(q, k, v)
+            out[f"fwd_causal={causal}"] = _err(jax.jit(fl)(q, k, v), ref)
             out[f"grad_causal={causal}"] = max(map(
-                _err_norm, jax.jit(gl(fl))(q, k, v), gl(rf)(q, k, v)))
+                _err_norm, jax.jit(gl)(q, k, v), ref_grads))
     return out
+
+
+def _kd_flash_cell(B, H, Hkv, T, D, dtype):
+    return _kd_flash(B, H, T, D, dtype, kv_heads=Hkv, causal_only=True)
 
 
 def _kd_lrn(shape, dtype):
@@ -776,7 +809,8 @@ def _kd_lrn(shape, dtype):
 def leg_d_kernels(lstm=(256, 64, 512), lstm_small=(32, 16, 128),
                   sxent=((16384, 96), (128, 1000), (256, 10)),
                   adam=((512, 2048), (2048,), (96,), (7, 9)),
-                  flash=(2, 4, 256, 64), lrn=(4, 14, 14, 64)) -> dict:
+                  flash=(2, 4, 256, 64), flash_cell=(1, 32, 2, 8192, 128),
+                  lrn=(4, 14, 14, 64)) -> dict:
     """Every Pallas kernel compiled (interpret mode only off-TPU), f32 and
     bf16, against its XLA reference. Runs every check before failing, so one
     chip call shows every kernel Mosaic rejects."""
@@ -795,6 +829,9 @@ def leg_d_kernels(lstm=(256, 64, 512), lstm_small=(32, 16, 128),
                  for s in sxent]
         plan += [(f"adam{s}/{name}", _kd_adam, (s, dt)) for s in adam]
         plan.append((f"flash{flash}/{name}", _kd_flash, (*flash, dt)))
+        if dt == jnp.bfloat16:   # nemotron3_nano_train_1chip's own call
+            plan.append((f"flash_cell{flash_cell}/{name}", _kd_flash_cell,
+                         (*flash_cell, dt)))
         plan.append((f"lrn{lrn}/{name}", _kd_lrn, (lrn, dt)))
     failed, worst = [], {}
     for label, fn, args in plan:

@@ -14,14 +14,33 @@ denominator l), so HBM traffic is O(T·D) instead of O(T^2):
 
 The backward follows the standard flash recipe: save only (out, lse); rebuild
 p = e^(s - lse) per tile and accumulate dq over k-tiles (one kernel) and
-dk/dv over q-tiles (a second kernel).
+dk/dv over q-tiles (a second kernel, which works on the transposed tile
+``k q^T`` so that no product needs a transpose and ``lse``/``delta`` broadcast
+as the rows they are stored as).
+
+The tile loops. Each kernel loops over the tiles of the other axis inside one
+grid program. Under ``causal`` the loop visits only the tiles at or under the
+diagonal (:func:`causal_key_tiles`, :func:`causal_query_tiles`): a tile wholly
+above it adds exactly 0. The visited tiles come in two runs of one loop body:
+tiles wholly under the diagonal take no causal mask at all, tiles the
+diagonal crosses take the ``rows >= cols`` select. Without ``causal`` every
+tile is walked. :func:`tiles_walked_share` counts what the loops visit.
+
+Precision. The products take ``q``, ``k``, ``v`` and ``do`` in the dtype they
+arrive in (bfloat16 tiles go to the MXU as bfloat16) and accumulate in
+float32; ``p`` and ``ds`` are cast to that dtype for the products they enter.
+Scores, ``m``, ``l``, ``lse``, ``delta``, the exponentials, the rescaling and
+every accumulator are float32. float32 inputs multiply in float32.
 
 VMEM note: scores/probabilities are tiled, but each grid program stages the
 full per-head K/V [T, D] strip in VMEM (the k-loop runs inside the kernel,
-not the grid), so per-program VMEM is O(T·D). A budget guard in
-:func:`flash_attention` falls back to the XLA path beyond ~8 MB of K+V per
-head — beyond that length, ring attention (sequence parallelism) is the
-intended tool anyway. Grid-tiled K/V streaming is the upgrade path.
+not the grid; the dk/dv kernel stages Q and dO so), so per-program VMEM is
+O(T·D), double-buffered. A budget guard in :func:`flash_attention` falls back
+to the XLA path beyond ~8 MB of K+V per head — beyond that length, ring
+attention (sequence parallelism) is the intended tool anyway.
+:func:`default_blocks` sizes the tiles so that the float32 ``[block_q,
+block_k]`` temporaries fit beside those strips in the compiler's default
+scoped VMEM: no call states a limit.
 
 Used by SelfAttentionLayer via ``attention_impl="flash"``; interpret mode
 (CPU) runs identical code for tests. Causal masking and key padding masks are
@@ -32,7 +51,8 @@ applied inside the tiles. Inputs [B, H, T, D], same contract as
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,126 +62,249 @@ from .pallas_kernels import _interpret
 
 _NEG_INF = -1e30
 _KV_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+_LANES = 128
+# the compiler's default scoped VMEM on the chips this runs on; the tiles are
+# sized to stay inside it (see default_blocks)
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def causal_key_tiles(qi0, block_q: int, block_k: int):
+    """``(full, end)`` for the query tile whose first row is ``qi0``: key
+    tiles ``[0, full)`` lie wholly at or under the causal diagonal (``rows >=
+    cols`` everywhere: no mask), ``[full, end)`` are crossed by it, and tiles
+    from ``end`` on lie wholly above it and are never visited. ``qi0`` is a
+    Python int or a traced int32."""
+    return (qi0 + 1) // block_k, (qi0 + block_q + block_k - 1) // block_k
+
+
+def causal_query_tiles(kj0, block_q: int, block_k: int):
+    """``(start, full)`` for the key tile whose first column is ``kj0``:
+    query tiles ``[0, start)`` lie wholly above the causal diagonal and are
+    never visited, ``[start, full)`` are crossed by it, and tiles from
+    ``full`` on lie wholly at or under it (no mask)."""
+    return kj0 // block_q, (kj0 + block_k + block_q - 2) // block_q
+
+
+def tiles_walked_share(t: int, block_q: int, block_k: int,
+                       causal: bool) -> float:
+    """Tiles the three kernels' loops visit over the tiles of the full
+    square, for ``t`` positions padded to the blocks' common multiple as the
+    call pads them: 1.0 without ``causal``, ``(n + 1) / 2n`` with it for
+    ``n`` equal tiles a side."""
+    if not causal:
+        return 1.0
+    lcm = math.lcm(block_q, block_k)
+    t = -(-t // lcm) * lcm
+    nq, nk = t // block_q, t // block_k
+    by_query = sum(causal_key_tiles(i * block_q, block_q, block_k)[1]
+                   for i in range(nq))          # flash_fwd and flash_bwd_dq
+    by_key = sum(nq - causal_query_tiles(j * block_k, block_q, block_k)[0]
+                 for j in range(nk))            # flash_bwd_dkv
+    return (2 * by_query + by_key) / (3.0 * nq * nk)
+
+
+def default_blocks(t: int, d: int, itemsize: int) -> Tuple[int, int]:
+    """``(block_q, block_k)`` from the shapes. A loop iteration should be MXU
+    work and not loop overhead, so the tile is the largest power-of-two
+    multiple of the lane width that (a) pads ``t`` no further than 128-wide
+    tiles would, and (b) leaves the float32 ``[block_q, block_k]`` temporaries
+    of an iteration (scores, probabilities and their gradients: five of
+    them, and two casts for the MXU) room beside the double-buffered
+    ``[t, d]`` strips in the scoped VMEM. A sequence shorter than a lane tile
+    is one tile."""
+    if t <= _LANES:
+        return max(t, 1), max(t, 1)
+    padded = -(-t // _LANES) * _LANES
+    strips = 2 * 2 * padded * d * itemsize
+    block = _LANES
+    while (padded % (2 * block) == 0
+           and strips + (2 * block) ** 2 * (5 * 4 + 2 * itemsize)
+           <= _SCOPED_VMEM_BYTES):
+        block *= 2
+    return block, block
+
+
+def _dot(a, b, contract=(1, 0)):
+    """``a @ b`` on the operands as they are, accumulated in float32 (never
+    below the operands' own dtype). A product of 16-bit operands is exact in
+    float32 whatever ``jax.default_matmul_precision`` says, and Mosaic
+    refuses a float32 contract precision on them: they state the default."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        precision=None if a.dtype.itemsize >= 4 else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.promote_types(a.dtype, jnp.float32))
+
+
+def _dot_nt(a, b):
+    """``a @ b.T`` contracting the last axes directly: no transpose."""
+    return _dot(a, b, contract=(1, 1))
+
+
+def _tile_start(i, block: int):
+    """First row of tile ``i``; a loop's index carries the alignment the
+    dynamic slice needs, a lone tile's is the literal 0."""
+    return 0 if isinstance(i, int) else pl.multiple_of(i * block, block)
+
+
+def _rows_minus_cols(rows: int, cols: int):
+    """``row index - column index`` of a ``[rows, cols]`` tile: the causal
+    select keeps where this is at least ``first column - first row``."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _scores(a, b, scale: float, keep, under):
+    """The score tile ``a b^T * scale`` in float32, with ``_NEG_INF`` where
+    the key mask ``keep`` or the causal select ``under`` (boolean arrays
+    that broadcast against the tile; ``under`` is ``None`` off the
+    diagonal) says no."""
+    s = jnp.where(keep, _dot_nt(a, b) * scale, _NEG_INF)
+    if under is not None:
+        s = jnp.where(under, s, _NEG_INF)
+    return s
+
+
+def _two_runs(body, init, n_tiles: int, runs, diagonal_first: bool):
+    """The tile loop over an axis of ``n_tiles``. ``runs`` is ``None``
+    without ``causal`` (one run over every tile, no causal mask) or the
+    traced ``(first, split, last)``: two runs of the same body split at
+    ``split``, the run on the diagonal's side with the mask. A lone tile is
+    no loop (the diagonal crosses it), so its slices are static whatever its
+    size."""
+    if n_tiles == 1:
+        return body(runs is not None)(0, init)
+    if runs is None:
+        return jax.lax.fori_loop(0, n_tiles, body(False), init)
+    first, split, last = runs
+    carry = jax.lax.fori_loop(first, split, body(diagonal_first), init)
+    return jax.lax.fori_loop(split, last, body(not diagonal_first), carry)
 
 
 def _fwd_kernel(block_k: int, causal: bool, scale: float,
                 q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref):
-    """One q-tile vs all k-tiles. Refs: q [1,Bq,D]; k/v [1,T,D]; mask
-    [1,1,T]; out o [1,Bq,D], lse [1,1,Bq]. (Mask/lse ride a unit middle axis:
-    TPU lowering requires each block's last two dims to divide (8, 128) or
-    equal the array dims — a [1, T] block on a [BH, T] array violates the
-    sublane rule, a [1, 1, T] block on [BH, 1, T] does not.)"""
-    q = q_ref[0].astype(jnp.float32)  # [Bq, D]
+    """One q-tile vs the k-tiles at or under its diagonal. Refs: q [1,Bq,D];
+    k/v [1,T,D]; mask [1,1,T]; out o [1,Bq,D], lse [1,1,Bq]. (Mask/lse ride a
+    unit middle axis: TPU lowering requires each block's last two dims to
+    divide (8, 128) or equal the array dims — a [1, T] block on a [BH, T]
+    array violates the sublane rule, a [1, 1, T] block on [BH, 1, T] does
+    not.)"""
+    q = q_ref[0]  # [Bq, D]
     bq, d = q.shape
-    t = k_ref.shape[1]
+    acc_t = jnp.promote_types(q.dtype, jnp.float32)
     qi0 = pl.program_id(1) * bq
 
-    def body(j, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        s = (q @ k.T) * scale  # [Bq, Bk]
-        kmask = mask_ref[0, 0, pl.dslice(j * block_k, block_k)]  # [Bk]
-        s = jnp.where(kmask[None, :] > 0, s, _NEG_INF)
-        if causal:
-            rows = qi0 + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        # Rows with NO valid key yet have m_new == _NEG_INF; exp(s - m_new)
-        # would then be exp(0) = 1 at every masked position (the reference
-        # guards this with m_safe + explicit zeroing — ring_attention.py).
-        # Subtracting 0 instead keeps exp(-1e30) == 0 for those rows.
-        m_safe = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        alpha = jnp.exp(jnp.where(m <= _NEG_INF / 2, m_safe, m) - m_safe)
-        p = jnp.exp(s - m_safe[:, None])
-        acc = acc * alpha[:, None] + p @ v
-        l = l * alpha + p.sum(axis=-1)
-        return acc, m_new, l
+    def body(on_diagonal):
+        def step(j, carry):
+            acc, m, l = carry
+            k0 = _tile_start(j, block_k)
+            k = k_ref[0, pl.dslice(k0, block_k), :]
+            v = v_ref[0, pl.dslice(k0, block_k), :]
+            s = _scores(  # [Bq, Bk]
+                q, k, scale, mask_ref[0, :, pl.dslice(k0, block_k)] > 0,
+                _rows_minus_cols(bq, block_k) >= k0 - qi0 if on_diagonal
+                else None)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            # Rows with NO valid key yet have m_new == _NEG_INF; exp(s -
+            # m_new) would then be exp(0) = 1 at every masked position (the
+            # reference guards this with m_safe + explicit zeroing —
+            # ring_attention.py). Subtracting 0 instead keeps exp(-1e30) == 0
+            # for those rows.
+            m_safe = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
+            alpha = jnp.exp(jnp.where(m <= _NEG_INF / 2, m_safe, m) - m_safe)
+            p = jnp.exp(s - m_safe)
+            acc = acc * alpha + _dot(p.astype(v.dtype), v)
+            l = l * alpha + p.sum(axis=-1, keepdims=True)
+            return acc, m_new, l
+        return step
 
-    nk = t // block_k
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, nk, body, (acc0, m0, l0))
+    acc0 = jnp.zeros((bq, d), acc_t)
+    m0 = jnp.full((bq, 1), _NEG_INF, acc_t)
+    l0 = jnp.zeros((bq, 1), acc_t)
+    runs = (0, *causal_key_tiles(qi0, bq, block_k)) if causal else None
+    acc, m, l = _two_runs(body, (acc0, m0, l0), k_ref.shape[1] // block_k,
+                          runs, diagonal_first=False)
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     # Fully-masked rows (l == 0): out = 0, and lse = 0 (finite) so the
     # backward's exp(s - lse) = exp(-1e30) = 0 instead of exp(0) = 1.
     m_fin = jnp.where(m <= _NEG_INF / 2, 0.0, m)
     lse = jnp.where(l > 0, m_fin + jnp.log(l_safe), 0.0)
-    lse_ref[0, 0] = lse.astype(lse_ref.dtype)
+    lse_ref[0, 0] = lse[:, 0].astype(lse_ref.dtype)
 
 
 def _dq_kernel(block_k: int, causal: bool, scale: float,
                q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                dq_ref):
-    """dq for one q-tile: loop over k-tiles (flash backward, dq pass)."""
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)
-    delta = delta_ref[0, 0].astype(jnp.float32)  # rowsum(do * o)
+    """dq for one q-tile: loop over the k-tiles at or under its diagonal
+    (flash backward, dq pass)."""
+    q = q_ref[0]
+    do = do_ref[0]
     bq, d = q.shape
-    t = k_ref.shape[1]
+    acc_t = jnp.promote_types(q.dtype, jnp.float32)
+    lse = lse_ref[0, 0].astype(acc_t)[:, None]      # [Bq, 1]
+    delta = delta_ref[0, 0].astype(acc_t)[:, None]  # rowsum(do * o)
     qi0 = pl.program_id(1) * bq
 
-    def body(j, dq):
-        k = k_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        s = (q @ k.T) * scale
-        kmask = mask_ref[0, 0, pl.dslice(j * block_k, block_k)]
-        s = jnp.where(kmask[None, :] > 0, s, _NEG_INF)
-        if causal:
-            rows = qi0 + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])  # [Bq, Bk]
-        dp = do @ v.T  # [Bq, Bk]
-        ds = p * (dp - delta[:, None])
-        return dq + (ds @ k) * scale
+    def body(on_diagonal):
+        def step(j, dq):
+            k0 = _tile_start(j, block_k)
+            k = k_ref[0, pl.dslice(k0, block_k), :]
+            v = v_ref[0, pl.dslice(k0, block_k), :]
+            s = _scores(
+                q, k, scale, mask_ref[0, :, pl.dslice(k0, block_k)] > 0,
+                _rows_minus_cols(bq, block_k) >= k0 - qi0 if on_diagonal
+                else None)
+            p = jnp.exp(s - lse)  # [Bq, Bk]
+            dp = _dot_nt(do, v)  # [Bq, Bk]
+            ds = p * (dp - delta)
+            return dq + _dot(ds.astype(k.dtype), k)
+        return step
 
-    dq = jax.lax.fori_loop(0, t // block_k, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    runs = (0, *causal_key_tiles(qi0, bq, block_k)) if causal else None
+    dq = _two_runs(body, jnp.zeros((bq, d), acc_t),
+                   k_ref.shape[1] // block_k, runs, diagonal_first=False)
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(block_q: int, causal: bool, scale: float,
                 q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref):
-    """dk/dv for one k-tile: loop over q-tiles (flash backward, dk/dv pass).
+    """dk/dv for one k-tile: loop over the q-tiles at or under its diagonal
+    (flash backward, dk/dv pass), on the transposed tile ``k q^T`` [Bk, Bq].
     Refs: k/v tile [1,Bk,D]; q/do [1,T,D]; lse/delta [1,1,T]; mask tile
     [1,1,Bk] (unit middle axis — see _fwd_kernel)."""
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]
+    v = v_ref[0]
     bk, d = k.shape
-    tq = q_ref.shape[1]
+    acc_t = jnp.promote_types(k.dtype, jnp.float32)
     kj0 = pl.program_id(1) * bk
-    kmask = mask_ref[0, 0]  # [Bk]
+    keep = mask_ref[0, 0][:, None] > 0  # [Bk, 1]
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.dslice(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.dslice(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.dslice(i * block_q, block_q)].astype(jnp.float32)
-        delta = delta_ref[0, 0, pl.dslice(i * block_q, block_q)].astype(jnp.float32)
-        s = (q @ k.T) * scale  # [Bq, Bk]
-        s = jnp.where(kmask[None, :] > 0, s, _NEG_INF)
-        if causal:
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            cols = kj0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dv = dv + p.T @ do
-        dp = do @ v.T
-        ds = p * (dp - delta[:, None])
-        dk = dk + (ds.T @ q) * scale
-        return dk, dv
+    def body(on_diagonal):
+        def step(i, carry):
+            dk, dv = carry
+            q0 = _tile_start(i, block_q)
+            q = q_ref[0, pl.dslice(q0, block_q), :]
+            do = do_ref[0, pl.dslice(q0, block_q), :]
+            lse = lse_ref[0, :, pl.dslice(q0, block_q)].astype(acc_t)
+            delta = delta_ref[0, :, pl.dslice(q0, block_q)].astype(acc_t)
+            st = _scores(  # [Bk, Bq]: keys down, queries across
+                k, q, scale, keep,
+                _rows_minus_cols(bk, block_q) <= q0 - kj0 if on_diagonal
+                else None)
+            pt = jnp.exp(st - lse)
+            dv = dv + _dot(pt.astype(do.dtype), do)
+            dpt = _dot_nt(v, do)
+            dst = pt * (dpt - delta)
+            dk = dk + _dot(dst.astype(q.dtype), q)
+            return dk, dv
+        return step
 
-    zero = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, tq // block_q, body, (zero, zero))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    nq = q_ref.shape[1] // block_q
+    runs = (*causal_query_tiles(kj0, block_q, bk), nq) if causal else None
+    zero = jnp.zeros((bk, d), acc_t)
+    dk, dv = _two_runs(body, (zero, zero), nq, runs, diagonal_first=True)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -271,15 +414,17 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, key_mask=None,
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None):
     """Blockwise flash attention. q: [B, H, T, D]; k/v: [B, Hkv, T, D] with
     ``Hkv`` dividing ``H`` (grouped-query heads: query head ``i`` reads
     key/value head ``i // (H // Hkv)`` in place); key_mask: [B, T] (1 = real
     key). With ``Hkv == H`` the contract of ``ring_attention.attention``.
 
     T is padded internally to a block multiple (padded keys masked out,
-    padded query rows sliced off), so any sequence length works; block sizes
-    shrink automatically for short sequences.
+    padded query rows sliced off), so any sequence length works; the tiles
+    come from :func:`default_blocks` unless given, and shrink automatically
+    for short sequences.
     """
     b, h, t, d = q.shape
     hkv = k.shape[1]
@@ -296,8 +441,9 @@ def flash_attention(q, k, v, causal: bool = False,
             k, v = (jnp.repeat(a, h // hkv, axis=1) for a in (k, v))
         return _xla_attention(q, k, v, causal=causal, scale=scale,
                               key_mask=key_mask)
-    block_q = min(block_q, max(t, 1))
-    block_k = min(block_k, max(t, 1))
+    auto_q, auto_k = default_blocks(t, d, q.dtype.itemsize)
+    block_q = min(block_q or auto_q, max(t, 1))
+    block_k = min(block_k or auto_k, max(t, 1))
 
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * hkv, t, d)
@@ -311,8 +457,6 @@ def flash_attention(q, k, v, causal: bool = False,
     # one pad straight to the lcm: q must reach a block_k multiple for the
     # dkv q-loop and k a block_q multiple for the dq k-loop; zero mask
     # padding == masked out
-    import math
-
     lcm = math.lcm(block_q, block_k)
     qf, t_real = _pad_to(qf, 1, lcm)
     kf, _ = _pad_to(kf, 1, lcm)

@@ -634,11 +634,31 @@ def _attn_dims(ctx):
     return ctx["B"] * ctx["heads"], ctx["T"], ctx["D"], ctx["itemsize"]
 
 
+# the compute dtypes a net can state (nn/conf: float32, bfloat16; float64
+# under x64), by the itemsize the sites are asked with
+_OPERAND_NAME = {2: "bfloat16", 4: "float32", 8: "float64"}
+
+
+def _flash_detail(ctx) -> dict:
+    """The tiles the flash kernels take at these shapes, the dtype their
+    products multiply in (the operands' own), and the share of the full
+    square's tiles their loops visit: 1.0 without ``causal``, ``(n + 1) /
+    2n`` with it for ``n`` tiles a side."""
+    from .flash_attention import default_blocks, tiles_walked_share  # noqa: PLC0415
+
+    block_q, block_k = default_blocks(ctx["T"], ctx["D"], ctx["itemsize"])
+    return {"block_q": block_q, "block_k": block_k,
+            "mxu_operand": _OPERAND_NAME[ctx["itemsize"]],
+            "tiles_walked_share": tiles_walked_share(
+                ctx["T"], block_q, block_k, bool(ctx.get("causal")))}
+
+
 def _attn_flash_cost(ctx):
     bh, t, d, itemsize = _attn_dims(ctx)
     # online-softmax recompute in the two backward passes costs extra FLOPs
-    # but HBM traffic stays O(T*D) streams
-    flops = 14.0 * bh * t * t * d
+    # but HBM traffic stays O(T*D) streams; under causal the loops leave out
+    # the tiles above the diagonal
+    flops = 14.0 * bh * t * t * d * _flash_detail(ctx)["tiles_walked_share"]
     nbytes = itemsize * 12.0 * bh * t * d + 8.0 * bh * t
     return flops, nbytes, 3 * _LAUNCH_S
 
@@ -674,7 +694,7 @@ register_site(Site(
         # attention_impl="flash" keeps meaning flash wherever it fits VMEM
         "flash": Variant("flash", fused=True, cost=_attn_flash_cost,
                          available=_flash_fits_ctx,
-                         auto_gate=_flash_auto_gate),
+                         auto_gate=_flash_auto_gate, detail=_flash_detail),
         "xla": Variant("xla", fused=False, cost=_attn_xla_cost,
                        unfused_bytes=True),
     },

@@ -403,7 +403,15 @@ class CheckpointStore:
             with zipfile.ZipFile(path, "r") as zf:
                 names = set(zf.namelist())
                 if _MANIFEST_NAME not in names:
-                    zf.testzip()
+                    # testzip() names the first entry that fails its CRC or
+                    # header check, it does not raise; and a directory torn
+                    # short can list whole entries and still lack the model
+                    bad = zf.testzip()
+                    if bad is not None or "meta.json" not in names:
+                        raise CheckpointCorruptError(
+                            f"v{version}: no manifest and not a whole "
+                            f"legacy container (bad entry {bad!r}, "
+                            f"entries {sorted(names)})")
                     return "legacy"
                 manifest = json.loads(zf.read(_MANIFEST_NAME))
                 entries = dict(manifest.get("entries") or {})

@@ -276,6 +276,32 @@ class TestIntegrityQuarantine:
                 zf.writestr(name, data)
         assert store.verify(2) == "legacy"
 
+    @pytest.mark.parametrize("first_seed", [0, 100, 200, 300])
+    def test_flipped_bytes_never_pass_as_a_legacy_container(self, tmp_path,
+                                                            first_seed):
+        """64 flipped bytes that hit the zip's directory can hide the
+        manifest's name or cut the entry list short: the container then has
+        no manifest, and only a whole one (every entry sound, the model's
+        ``meta.json`` among them) may pass as pre-manifest. One seed in
+        eight used to, and ``restore`` then failed on the entry it lacked."""
+        from deeplearning4j_tpu.runtime.checkpoint import (
+            CheckpointCorruptError,
+        )
+        from deeplearning4j_tpu.testing.chaos import corrupt_file
+
+        net, store, (i1, i2) = self._seed(tmp_path)
+        with open(i2.path, "rb") as fh:
+            whole = fh.read()
+        for seed in range(first_seed, first_seed + 100):
+            with open(i2.path, "wb") as fh:
+                fh.write(whole)
+            corrupt_file(i2.path, seed, n_bytes=64)
+            with pytest.raises(CheckpointCorruptError):
+                store.verify(2)
+        model, info = store.restore_with_info()   # the last one: falls back
+        assert info.version == 1
+        assert os.path.exists(i2.path + ".quarantine")
+
     def test_truncated_zip_quarantined_with_fallback(self, tmp_path):
         from deeplearning4j_tpu.testing.chaos import truncate_file
 

@@ -71,8 +71,9 @@ def test_leg_d_kernels_tiny():
         lstm=(8, 8, 128), lstm_small=(8, 8, 128),
         sxent=((64, 96), (16, 1000), (32, 10)),
         adam=((16, 128), (256,), (96,), (7, 9)),
-        flash=(1, 2, 32, 16), lrn=(2, 4, 4, 16))
-    assert res["checks"] == 20
+        flash=(1, 2, 32, 16), flash_cell=(1, 4, 2, 48, 16),
+        lrn=(2, 4, 4, 16))
+    assert res["checks"] == 21
 
 
 def test_leg_e_on_the_virtual_mesh():

@@ -212,6 +212,53 @@ class TestSelectionCore:
         ks.select("softmax_xent", {"N": 1 << 14, "C": 96, "itemsize": 4})
         assert all("time_block" not in r for r in ks.selection_log())
 
+    @pytest.mark.parametrize("ctx,share,operand", [
+        # nemotron3_nano_train_1chip's attention block: 16 tiles a side
+        (dict(B=1, heads=32, T=8192, D=128, kv_heads=2), 17 / 32, "bfloat16"),
+        (dict(B=1, heads=32, T=8192, D=128, causal=False), 1.0, "bfloat16"),
+        (dict(itemsize=4), 9 / 16, "float32"),   # T=4096, D=64: 8 a side
+        (dict(T=300), 2 / 3, "bfloat16"),        # 128-wide, padded to 384
+    ])
+    def test_attention_record_says_its_tiles_and_what_they_walk(
+            self, ctx, share, operand):
+        from deeplearning4j_tpu.ops.flash_attention import (
+            default_blocks, tiles_walked_share)
+
+        ks.set_force_available(True)
+        ctx = _attn_ctx(ctx.pop("T", 4096), **ctx)
+        assert ks.select("attention", ctx) == "flash"
+        rec = ks.selection_log()[-1]
+        assert rec["reason"] == "auto"
+        blocks = default_blocks(ctx["T"], ctx["D"], ctx["itemsize"])
+        assert (rec["block_q"], rec["block_k"]) == blocks
+        assert rec["mxu_operand"] == operand
+        # from the function the kernels take their loop bounds from
+        assert rec["tiles_walked_share"] == pytest.approx(share) \
+            == tiles_walked_share(ctx["T"], *blocks, ctx["causal"])
+        assert ks.stats()["recent"][-1]["tiles_walked_share"] \
+            == rec["tiles_walked_share"]
+
+    def test_tiles_are_the_flash_variant_s_alone(self):
+        ks.set_force_available(True)
+        assert ks.select("attention", _attn_ctx(64)) == "xla"
+        assert not {"block_q", "block_k", "mxu_operand",
+                    "tiles_walked_share"} & set(ks.selection_log()[-1])
+
+    def test_flash_cost_follows_the_tiles_the_kernel_walks(self):
+        from deeplearning4j_tpu.ops.kernel_select import (_attn_flash_cost,
+                                                          _attn_xla_cost)
+
+        causal, full = _attn_ctx(8192, D=128), _attn_ctx(8192, D=128,
+                                                         causal=False)
+        flops, nbytes, overhead = _attn_flash_cost(full)
+        assert flops == 14.0 * 32 * 8192 * 8192 * 128
+        c_flops, c_bytes, c_overhead = _attn_flash_cost(causal)
+        # 17 of 32 tiles a side-pair at 512-wide tiles; bytes as they were
+        assert c_flops == pytest.approx(flops * 17 / 32)
+        assert (c_bytes, c_overhead) == (nbytes, overhead)
+        # the XLA path computes the whole square and masks it
+        assert _attn_xla_cost(causal) == _attn_xla_cost(full)
+
 
 class TestCalibration:
     def test_update_and_factor(self):

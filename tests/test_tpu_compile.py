@@ -133,3 +133,42 @@ def test_grouped_matmul_kernels_compile_for_the_v5e_at_the_published_shapes(
     assert text.count("tpu_custom_call") == 3
     for name in ("fwd", "dlhs", "drhs"):
         assert "grouped_matmul_" + name in text
+
+
+@pytest.mark.parametrize("B,H,Hkv,T,D,dtype,causal,masked", [
+    (1, 32, 2, 8192, 128, "bfloat16", True, False),  # nemotron3_nano_train_1chip
+    (1, 32, 2, 8192, 128, "bfloat16", False, True),  # every tile, a key mask
+    (2, 4, 4, 256, 64, "float32", True, True),       # chip_smoke's f32
+    (2, 4, 2, 1000, 64, "bfloat16", True, False),    # T padded to 1024
+    (2, 4, 2, 100, 64, "bfloat16", True, False),     # one tile short of a lane tile
+])
+def test_flash_kernels_compile_for_the_v5e_at_their_default_tiles(
+        one_chip, monkeypatch, B, H, Hkv, T, D, dtype, causal, masked):
+    """The tiles ``default_blocks`` picks fit the compiler's default scoped
+    VMEM beside the strips (no call states a limit), the traced loop bounds
+    lower, and bfloat16 operands reach the MXU as they are."""
+    import importlib
+
+    fa = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    dt = jnp.dtype(dtype)
+    s = lambda shape, d=dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, d, sharding=one_chip)
+    args = [s((B, H, T, D)), s((B, Hkv, T, D)), s((B, Hkv, T, D))]
+    if masked:
+        args.append(s((B, T), jnp.float32))
+
+    def loss(q, k, v, *mask):
+        out = fa.flash_attention(q, k, v, causal=causal,
+                                 key_mask=mask[0] if mask else None)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    # a float32 contract precision on bfloat16 operands is nothing Mosaic
+    # takes: the kernels' products state their own
+    with jax.enable_x64(False), jax.default_matmul_precision("float32"):
+        text = grad.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in text
+    assert "vmem_limit_bytes" not in text
