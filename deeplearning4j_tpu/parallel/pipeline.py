@@ -361,7 +361,7 @@ class PipelinedTrainer:
     output layer stays outside the region (replicated / tp-sharded by the
     ordinary spec rules) and sees the gathered hidden states in original
     batch order — the loss, regularization, RNG split chain and optimizer
-    update all mirror ``MultiLayerNetwork._build_train_step``, which is
+    update all mirror ``nn/engine.py``'s ``_build_train_step``, which is
     what makes trajectory parity vs the unpiped net hold to float
     tolerance.
 

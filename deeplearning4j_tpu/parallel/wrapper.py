@@ -327,7 +327,7 @@ class ParallelWrapper:
                 f"replica: got axis-1 size {int(xs.shape[1])}, "
                 f"workers={self.workers}"
             )
-        from ..nn.multilayer import _check_staged_counts  # noqa: PLC0415
+        from ..nn.engine import _check_staged_counts  # noqa: PLC0415
 
         _check_staged_counts(num_groups, (("ys", ys),
                                           ("features_masks", features_masks),
@@ -409,20 +409,16 @@ class ParallelWrapper:
         ls = getattr(net.conf, "loss_scale", None)
 
         def one_step(params, opt_state, state, x, y, rng, labels_mask, features_mask):
-            from ..nn.updaters import (  # noqa: PLC0415
-                optimizer_update, scaled_loss, unscale_grads, unscale_loss)
+            from ..nn.engine import apply_step  # noqa: PLC0415
 
             def loss_of(p):
                 loss, new_state, _ = net._loss(
                     p, state, x, y, rng, True, labels_mask, features_mask
                 )
-                return scaled_loss(loss, ls), new_state
+                return loss, new_state
 
-            (loss, new_state), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
-            loss = unscale_loss(loss, ls)
-            grads = unscale_grads(grads, ls)
-            _, new_opt, new_params = optimizer_update(
-                tx, grads, opt_state, params)
+            loss, new_state, _, _, new_opt, new_params = apply_step(
+                loss_of, tx, ls, params, opt_state)
             return new_params, new_opt, new_state, loss
 
         # vmap over the replica axis: every replica steps independently in one

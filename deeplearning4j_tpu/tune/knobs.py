@@ -40,8 +40,8 @@ __all__ = [
     "scoped_env",
 ]
 
-# donation gate for the jitted train steps (multilayer/_build_train_step,
-# computation_graph, the staged multi-step): default ON on accelerators;
+# donation gate for the jitted train steps (nn/engine.py: the per-batch
+# step and the staged multi-step of both net classes): default ON on accelerators;
 # the autopilot trials OFF because donation trades HBM for a copy
 DONATE_ENV = "DL4JTPU_DONATE"
 
