@@ -945,9 +945,14 @@ fused_softmax_xent_ids.defvjp(_sxent_ids_fwd, _sxent_ids_bwd)
 # The optax chain materializes every intermediate of the moment/bias-correct/
 # scale pipeline as a tree-wide HBM round trip; per parameter leaf this
 # kernel reads (g, m, v) and writes (update, m, v) once — the bandwidth
-# floor of the math. Selected by the "optimizer" kernel_select site (the
-# update is elementwise, i.e. always below the roofline ridge). Not
-# differentiated: optimizer updates sit outside jax.grad by construction.
+# floor of the math. A leaf tiled as it lies gets each result where the
+# operand it replaces lay (``input_output_aliases``: m' on m, v' on v, the
+# update on the gradient): a loop that carries the moments gets them back in
+# the buffers they came in, where fresh results cost a copy of each into the
+# carry, 5.3 GB a step of the hybrid model (PERF.md, PR 33). Selected by the
+# "optimizer" kernel_select site (the update is elementwise, i.e. always
+# below the roofline ridge). Not differentiated: optimizer updates sit
+# outside jax.grad by construction.
 
 _ADAM_LANES = 128
 # A block of at most 1 MiB: the call's three operands and three results,
@@ -982,6 +987,9 @@ def _adam_kernel(b1, b2, eps, g_ref, m_ref, v_ref, sc_ref,
 # they lie that cell read 0.6% lower, PERF.md, PR 30). Above it the relayout
 # is an HBM copy of each operand and result: 29 ms of a 524 ms step of the
 # hybrid model when its lane-tiled leaves were flattened (PERF.md, PR 30).
+# A flattened leaf's operands are XLA's own temporaries and its results stay
+# untied, as they always were: tied, the char-RNN read 1.7% lower (``p + u``
+# left its fusion and ran as a pass of its own; PERF.md, PR 33).
 _ADAM_FLATTEN_BYTES = 4 << 20
 
 
@@ -1013,13 +1021,17 @@ def _adam_view(shape, itemsize: int):
 
 def fused_adam_update(g, m, v, lr, bc1, bc2,
                       b1: float, b2: float, eps: float):
-    """One fused Adam step for one parameter leaf: returns
+    """One fused Adam step for one parameter leaf, in place: returns
     ``(update, new_m, new_v)`` with ``update = -lr·m̂/(√v̂+eps)`` using
     exactly optax's ``scale_by_adam`` bias corrections (``bc1``/``bc2`` are
     the traced ``1 - βᵢ**t`` scalars, ``lr`` the schedule's value). Any leaf
     shape, viewed as :func:`_adam_view` says and row-tiled in blocks of at
     most ``_ADAM_BLOCK_BYTES``; a flattened view is lane-padded, its padded
-    slots compute a zero update and are sliced off."""
+    slots compute a zero update and are sliced off. A leaf tiled as it lies
+    has each result aliased onto the operand it replaces (``g``, ``m``,
+    ``v``): a caller that donates them, as a loop's carry does, gets them
+    back where they lay, and XLA copies an operand first where its caller
+    still reads it."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
@@ -1030,6 +1042,7 @@ def fused_adam_update(g, m, v, lr, bc1, bc2,
     if swap:
         g, m, v = (jnp.swapaxes(a, -1, -2) for a in (g, m, v))
     lies = g.shape
+    in_place = {} if rows is None else {0: 0, 1: 1, 2: 2}
     pad = 0 if rows is not None else (-n) % cols
     rows = rows if rows is not None else (n + pad) // cols
 
@@ -1054,6 +1067,7 @@ def fused_adam_update(g, m, v, lr, bc1, bc2,
         in_specs=[mat, mat, mat, sc],
         out_specs=(mat, mat, mat),
         out_shape=(jax.ShapeDtypeStruct((rows, cols), dt),) * 3,
+        input_output_aliases=in_place,
         interpret=_interpret(),
         name="adam_update",
     )(flat(g), flat(m), flat(v), scalars)

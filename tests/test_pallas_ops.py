@@ -503,3 +503,160 @@ def test_adam_views_keep_six_double_buffered_blocks_inside_the_default():
     assert view((2688, 10304)) == (True, 10304, 2688)
     assert view((1100, 1000)) == (False, 1100, 1000)
     assert view((8, 512, 320)) == (True, 8 * 320, 512)
+
+
+# the views ``_adam_view`` gives the benchmark's leaves, at sizes a CPU test
+# can afford: with ``_ADAM_FLATTEN_BYTES`` lowered to 1 KiB a small leaf takes
+# the view of the large leaf it stands for
+ADAM_LEAVES = [
+    ((2, 128, 72), (True, 144, 128)),    # [8, 2688, 1856]: axes swapped
+    ((128, 200), (True, 200, 128)),      # [2688, 10304]: swapped, two axes
+    ((2, 72, 128), (False, 144, 128)),   # [8, 1856, 2688]: as it lies
+    ((136, 256), (False, 136, 256)),     # [2688, 16384]: as it lies
+    ((2688,), (False, None, 128)),       # a bias or a norm's weight: lane rows
+    ((64, 96), (False, None, 128)),      # short rows, as the char-RNN's
+                                         # leaves are under 4 MiB: lane rows
+    ((96, 33), (False, None, 128)),      # no multiple of 128: lane-padded
+]
+
+
+def _adam_leaf(shape, monkeypatch):
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_ADAM_FLATTEN_BYTES", 1 << 10)
+    key = jax.random.PRNGKey(sum(shape))
+    return pk, {"w": jax.random.normal(key, shape, jnp.float32)}
+
+
+def _grads(params, i):
+    return jax.tree_util.tree_map(lambda a: jnp.sin(a * (i + 1.5)), params)
+
+
+@pytest.mark.parametrize("shape,view", ADAM_LEAVES)
+def test_in_place_adam_is_optax_adam_over_three_steps(shape, view,
+                                                      monkeypatch):
+    """The kernel whose results lie on its operands against the same kernel
+    with the aliases taken off (as it was before PR 33), bit for bit, and
+    against ``optax.adam``: update, parameter and both moments, three steps
+    of a jit that donates what it carries."""
+    import optax
+    from jax.experimental import pallas as pl
+
+    pk, params = _adam_leaf(shape, monkeypatch)
+    assert pk._adam_view(shape, 4)[:3] == view
+    lr, b1, b2, eps = 1e-2, 0.9, 0.999, 1e-8
+    # tied where the leaf is tiled as it lies; a flattened leaf's call is
+    # declared as it always was
+    w = params["w"]
+    (call,) = [e for e in jax.make_jaxpr(lambda g, m, v: pk.fused_adam_update(
+        g, m, v, lr, 0.1, 0.001, b1, b2, eps))(w, w, w).eqns
+        if e.primitive.name == "pallas_call"]
+    assert call.params["input_output_aliases"] == (
+        () if view[1] is None else ((0, 0), (1, 1), (2, 2)))
+
+    def steps():
+        def step(p, m, v, t, g):
+            u, m, v = pk.fused_adam_update(g, m, v, lr, 1 - b1 ** t,
+                                           1 - b2 ** t, b1, b2, eps)
+            return optax.apply_updates(p, u), m, v, u
+
+        step = jax.jit(step, donate_argnums=(0, 1, 2))
+        p = params["w"] + 0.0   # the jit donates its own copy
+        m, v = jnp.zeros_like(p), jnp.zeros_like(p)
+        for i in range(3):
+            g = _grads({"w": p}, i)["w"]
+            p, m, v, u = step(p, m, v, jnp.float32(i + 1), g)
+        return [np.asarray(a) for a in (p, m, v, u)]
+
+    got = steps()
+    real = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, input_output_aliases=None, **kw: real(*a, **kw))
+    for a, b in zip(got, steps()):
+        assert a.shape == shape and a.dtype == np.float32
+        np.testing.assert_array_equal(a, b)
+
+    ref = optax.adam(lr, b1=b1, b2=b2, eps=eps)
+    want_p, want_state = params, ref.init(params)
+    for i in range(3):
+        u, want_state = ref.update(_grads(want_p, i), want_state, want_p)
+        want_p = optax.apply_updates(want_p, u)
+    # float32 against optax's own order of operations: an ulp or two
+    for a, want, atol in ((got[0], want_p, 1e-6), (got[3], u, 1e-6),
+                          (got[1], want_state[0].mu, 1e-6),
+                          (got[2], want_state[0].nu, 1e-8)):
+        np.testing.assert_allclose(a, np.asarray(want["w"]), rtol=1e-5,
+                                   atol=atol)
+
+
+@pytest.mark.parametrize("shape,view", ADAM_LEAVES)
+def test_aliased_adam_leaves_the_callers_arrays_unharmed(shape, view,
+                                                         monkeypatch):
+    """A leaf tiled as it lies has every result aliased onto an operand; a
+    jit that does not donate them must hand back fresh arrays and leave the
+    caller's as they were, in every view."""
+    pk, params = _adam_leaf(shape, monkeypatch)
+    g, m = params["w"], params["w"] * 0.5
+    v = jnp.square(m)
+    kept = [np.array(a) for a in (g, m, v)]
+
+    @jax.jit
+    def step(g, m, v):
+        return pk.fused_adam_update(g, m, v, 1e-2, 0.1, 0.001, 0.9, 0.999,
+                                    1e-8)
+
+    results = jax.block_until_ready(step(g, m, v))
+    for was, now, result in zip(kept, (g, m, v), results):
+        np.testing.assert_array_equal(was, np.asarray(now))
+        assert not np.array_equal(np.asarray(result), was)
+
+
+@pytest.mark.parametrize("route", ["reference", "fused"])
+def test_adam_state_tree_and_checkpoint_are_optax_adams(route, tmp_path,
+                                                        monkeypatch):
+    """The in-place updater keeps optax's optimizer-state tree, and a net
+    trained through it saves and restores moments and parameters as any
+    other and goes on training from them."""
+    import optax
+
+    from deeplearning4j_tpu import (DenseLayer, InputType,
+                                    MultiLayerConfiguration,
+                                    MultiLayerNetwork, OutputLayer,
+                                    UpdaterConfig, restore_model, write_model)
+    from deeplearning4j_tpu.ops import kernel_select as ks
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_ADAM_FLATTEN_BYTES", 1 << 10)
+    ks.reset()
+    if route == "fused":
+        ks.set_force_available(True)
+        ks.set_site_override("optimizer", "fused")
+    conf = MultiLayerConfiguration(
+        layers=[DenseLayer(n_out=128, activation="tanh"),
+                OutputLayer(n_out=3, activation="softmax", loss="mcxent")],
+        input_type=InputType.feed_forward(136),
+        updater=UpdaterConfig(updater="adam", learning_rate=1e-2), seed=5)
+    net = MultiLayerNetwork(conf).init()
+    want = optax.chain(optax.identity(), optax.adam(
+        optax.constant_schedule(1e-2))).init(net.params)
+    tree = jax.tree_util.tree_structure
+    assert tree(net.opt_state) == tree(want)
+    rng = np.random.default_rng(0)
+    xs = jnp.asarray(rng.normal(size=(3, 8, 136)), jnp.float32)
+    ys = jax.nn.one_hot(jnp.asarray(rng.integers(0, 3, (3, 8))), 3)
+    losses = net.fit_on_device(xs, ys)
+    assert np.isfinite(losses).all()
+    sites = [r for r in ks.selection_log() if r["site"] == "optimizer"]
+    assert sites and sites[-1]["variant"] == route
+    assert tree(net.opt_state) == tree(want)
+    path = str(tmp_path / "net.zip")
+    write_model(net, path)
+    back = restore_model(path)
+    assert tree(back.opt_state) == tree(net.opt_state)
+    for a, b in zip(jax.tree_util.tree_leaves((net.params, net.opt_state)),
+                    jax.tree_util.tree_leaves((back.params, back.opt_state))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and it goes on training from there as the net it was saved from does
+    np.testing.assert_array_equal(back.fit_on_device(xs, ys),
+                                  net.fit_on_device(xs, ys))

@@ -6,6 +6,8 @@ kernels reckon for themselves). Nothing runs, so nothing here is a time.
 All such compiles live in this one file and describe the topology inside a
 fixture: only the worker that is given the file loads the TPU's library."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -172,3 +174,94 @@ def test_flash_kernels_compile_for_the_v5e_at_their_default_tiles(
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert name in text
     assert "vmem_limit_bytes" not in text
+
+
+_HLO_LINE = re.compile(r"^\s*(ROOT\s+)?%(\S+) = .*? ([a-z-]+)\(([^)]*)\)")
+_TIED = re.compile(r"\{(\d)\}: \((\d), \{\}\)")   # {result}: (operand, {})
+
+
+def _copies_between_adam_and_the_carry(text: str) -> int:
+    """Copies of a loop's carried buffer on the way into an ``adam_update``
+    call, or of such a call's result on the way into the body's root tuple
+    (XLA puts the copy a fresh result forces on either side of the call),
+    bitcasts and tuple elements looked through."""
+    ins, roots = {}, set()
+    for line in text.splitlines():
+        m = _HLO_LINE.match(re.sub(r"/\*index=\d+\*/", "", line))
+        if m:
+            ins[m.group(2)] = (m.group(3), re.findall(r"%([^\s,]+)",
+                                                      m.group(4)))
+            if m.group(1) and m.group(3) == "tuple":
+                roots.add(m.group(2))
+    through = ("bitcast", "get-tuple-element", "reshape")
+
+    def origin(name):
+        while name in ins and ins[name][0] in through:
+            name = ins[name][1][0]
+        return name
+
+    def users(name):
+        for user, (op, operands) in ins.items():
+            if name in operands:
+                yield from users(user) if op in through else [user]
+
+    adam = lambda name: name.startswith("adam_update")  # noqa: E731
+    return sum(
+        1 for name, (op, operands) in ins.items() if op == "copy" and (
+            (adam(origin(operands[0])) and roots & set(users(name)))
+            or (ins.get(origin(operands[0]), ("",))[0] == "parameter"
+                and any(map(adam, users(name))))))
+
+
+def test_adam_kernel_in_a_loop_writes_its_carry_where_it_lies(
+        one_chip, monkeypatch):
+    """The staged step's shape of use: the moments and the parameter carried
+    by a ``fori_loop`` whose arguments are donated. Every result of every
+    ``adam_update`` call is tied to an operand, and no copy stands between
+    the carry and a call. With the aliases taken off the same call, which is
+    the kernel as it was before PR 33, XLA copies each moment of each leaf on
+    its way from carry to carry: the reader must find those, or it proves
+    nothing."""
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    leaves = [(8, 2688, 1856), (2688, 16384)]   # swapped; as it lies
+
+    def loop(ms, vs, ps, n):
+        def body(i, carry):
+            out = []
+            for m, v, p in zip(*carry):
+                g = jnp.sin(p) * (i + 1)
+                u, m, v = pk.fused_adam_update(
+                    g, m, v, 1e-3, 0.1, 0.001, 0.9, 0.999, 1e-8)
+                out.append((m, v, p + u))
+            return tuple(list(leaf) for leaf in zip(*out))
+        return jax.lax.fori_loop(0, n, body, (ms, vs, ps))
+
+    tree = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+            for shape in leaves]
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def compiled_text():
+        # a function of its own each time: jit would hand back the first trace
+        with jax.enable_x64(False):
+            return jax.jit(lambda *a: loop(*a), donate_argnums=(0, 1, 2)
+                           ).lower(tree, tree, tree, n).compile().as_text()
+
+    text = compiled_text()
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == len(leaves)
+    for call in calls:
+        pairs = _TIED.findall(call.split("output_to_operand_aliasing=")[1]
+                              .split(")}")[0] + ")")
+        assert sorted(pairs) == [("0", "0"), ("1", "1"), ("2", "2")]
+    assert _copies_between_adam_and_the_carry(text) == 0
+
+    real = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, input_output_aliases=None, **kw: real(*a, **kw))
+    before = compiled_text()
+    assert "output_to_operand_aliasing" not in before
+    assert _copies_between_adam_and_the_carry(before) == 2 * len(leaves)
