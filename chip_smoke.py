@@ -28,6 +28,15 @@ full width of the models the repo is measured on, and checks what comes out:
   Mamba block once more with the scan's decays and state in bfloat16, which
   has to FAIL its tolerance, or the comparison would pass lower precision.
 
+- **Leg G — the latent attention, gated feed-forward, gated expert and
+  hyper-connection blocks at the published widths**
+  (``benchmarks/configs/xing4_29b_a4b``, one sequence of 8192 positions, 4
+  heads and 8 experts held, a 4-stream residual): each as the engine runs it
+  against the float32 plain reference, like leg F. Two controls that have to
+  FAIL: the attention block with its rotary angles rounded to bfloat16, and
+  the hyper-connection block with its maps' projection and Sinkhorn in
+  bfloat16.
+
 Legs are plain functions taking sizes: ``tests/test_chip_smoke.py`` calls them
 tiny on the CPU (interpret-mode kernels); ``__main__`` runs them at full width
 and REQUIRES the chip. Run every leg: ``python chip_smoke.py``; while
@@ -54,7 +63,7 @@ import urllib.request
 
 import numpy as np
 
-LEGS = ("A", "B", "C", "D", "E", "F")
+LEGS = ("A", "B", "C", "D", "E", "F", "G")
 
 
 class LegFailure(AssertionError):
@@ -939,12 +948,92 @@ def _rel_l2(a, b) -> float:
     return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
 
 
-def _hybrid_config():
+def _f32(tree):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), tree)
+
+
+class _BlockCheck:
+    """Legs F and G: a block as the engine runs it (``dtype`` compute over
+    float32 masters) against its plain reference. ``both(fn)`` gives the
+    output, the parameters' gradient and the input's for the cotangent ``w``;
+    ``errors`` their relative L2 distances; ``held`` prints a block's line
+    and holds its worst distance to the block's tolerance."""
+
+    def __init__(self, tolerances: dict, dtype: str, w):
+        self.tolerances, self.dtype, self.w = tolerances, dtype, w
+        self.results = {}
+
+    def both(self, fn):
+        import jax
+
+        def run(p, x):
+            out, pull = jax.vjp(fn, p, x)
+            return (out,) + pull(self.w)
+        return jax.jit(run)
+
+    def layer_block(self, layer, plain, sizes, key, it):
+        """``(params, program, reference)`` of one layer: the program casts
+        the float32 masters as the engine does and runs ``layer.apply``."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.nn.multilayer import _cast_layer_params
+
+        cdt = jnp.dtype(self.dtype)
+        params = _f32(layer.init_params(key, it))
+        state = layer.init_state(it) if hasattr(layer, "init_state") else {}
+
+        def program(p, x):
+            out, _ = layer.apply(_cast_layer_params(self.dtype, layer, p),
+                                 x.astype(cdt), state, train=True)
+            return out.astype(jnp.float32)
+
+        def reference(p, x):
+            with jax.default_matmul_precision("highest"):
+                return plain(p, x, sizes)
+
+        return params, self.both(program), self.both(reference)
+
+    @staticmethod
+    def errors(kind, got, want) -> dict:
+        import jax
+        import jax.numpy as jnp
+
+        (o, gp, gx), (o_r, gp_r, gx_r) = got, want
+        errs = {"out": _rel_l2(o, o_r), "d_in": _rel_l2(gx, gx_r)}
+        flat, _ = jax.tree_util.tree_flatten_with_path(gp_r)
+        got_flat = dict(jax.tree_util.tree_flatten_with_path(gp)[0])
+        for path, g in flat:
+            if float(jnp.max(jnp.abs(g))) > 0.0:   # e_bias only selects
+                name = "_".join(str(getattr(k, "key", k)) for k in path)
+                errs["d_" + name] = _rel_l2(got_flat[path], g)
+        return errs
+
+    def held(self, kind, errs, label=None) -> bool:
+        label = label or kind
+        worst = max(errs.values())
+        tol = self.tolerances[kind]
+        line = {k: float(f"{v:.3g}") for k, v in errs.items()}
+        ok = bool(np.isfinite(worst) and worst <= tol)
+        print(f"  {'ok  ' if ok else 'OVER'} {label}: worst {worst:.3g} "
+              f"(tol {tol:g}) {json.dumps(line)}", flush=True)
+        self.results[label] = float(f"{worst:.3g}")
+        return ok
+
+
+def _bench_config(name: str):
     from benchmarks.harness.discovery import load_json, load_module
 
     base = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "benchmarks", "configs", "nemotron3_nano_30b_a3b")
+                        "benchmarks", "configs", name)
     return load_module(base + ".py"), load_json(base + ".json")
+
+
+def _hybrid_config():
+    return _bench_config("nemotron3_nano_30b_a3b")
 
 
 @contextlib.contextmanager
@@ -983,7 +1072,6 @@ def leg_f_hybrid_blocks(sizes: dict | None = None, seq_len: int = 8192,
 
     from deeplearning4j_tpu.models.nemotron_h import nemotron_h_conf
     from deeplearning4j_tpu.nn.conf.inputs import InputType
-    from deeplearning4j_tpu.nn.multilayer import _cast_layer_params
     from deeplearning4j_tpu.ops import kernel_select as ks
 
     t_leg = time.perf_counter()
@@ -1007,52 +1095,13 @@ def leg_f_hybrid_blocks(sizes: dict | None = None, seq_len: int = 8192,
     x = x.astype(cdt).astype(jnp.float32)
     w = jax.random.normal(keys[1], (batch, seq_len, width), jnp.float32)
 
-    def f32(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jnp.asarray(a, jnp.float32), tree)
+    check_blocks = _BlockCheck(tolerances, dtype, w)
+    held, compare = check_blocks.held, check_blocks.errors
+    results, failed = check_blocks.results, []
 
     def block(kind):
-        layer = layers[kind]
-        params = f32(layer.init_params(keys[2 + "MAE".index(kind)], it))
-        state = layer.init_state(it) if hasattr(layer, "init_state") else {}
-
-        def program(p, x):
-            out, _ = layer.apply(_cast_layer_params(dtype, layer, p),
-                                 x.astype(cdt), state, train=True)
-            return out.astype(jnp.float32)
-
-        def reference(p, x):
-            with jax.default_matmul_precision("highest"):
-                return plain[kind](p, x, sizes)
-
-        def both(fn):
-            def run(p, x):
-                out, pull = jax.vjp(fn, p, x)
-                return (out,) + pull(w)
-            return jax.jit(run)
-
-        return params, both(program), both(reference)
-
-    def compare(kind, got, want) -> dict:
-        (o, gp, gx), (o_r, gp_r, gx_r) = got, want
-        errs = {"out": _rel_l2(o, o_r), "d_in": _rel_l2(gx, gx_r)}
-        for name, g in gp_r.items():
-            if float(jnp.max(jnp.abs(g))) > 0.0:   # e_bias only selects
-                errs["d_" + name] = _rel_l2(gp[name], g)
-        return errs
-
-    results, failed = {}, []
-
-    def held(kind, errs, label=None):
-        label = label or kind
-        worst = max(errs.values())
-        tol = tolerances[kind]
-        line = {k: float(f"{v:.3g}") for k, v in errs.items()}
-        ok = bool(np.isfinite(worst) and worst <= tol)
-        print(f"  {'ok  ' if ok else 'OVER'} {label}: worst {worst:.3g} "
-              f"(tol {tol:g}) {json.dumps(line)}", flush=True)
-        results[label] = float(f"{worst:.3g}")
-        return ok
+        return check_blocks.layer_block(
+            layers[kind], plain[kind], sizes, keys[2 + "MAE".index(kind)], it)
 
     for kind in (k for k in "MAE" if k in kinds):
         params, program, reference = block(kind)
@@ -1070,7 +1119,7 @@ def leg_f_hybrid_blocks(sizes: dict | None = None, seq_len: int = 8192,
 
     if "H" in kinds:
         head = layers["H"]
-        hp = f32(head.init_params(keys[8], it))
+        hp = _f32(head.init_params(keys[8], it))
         ids = jax.random.randint(keys[9], (batch, seq_len), 0,
                                  sizes["vocab_size"])
 
@@ -1097,11 +1146,175 @@ def leg_f_hybrid_blocks(sizes: dict | None = None, seq_len: int = 8192,
             "leg_seconds": round(time.perf_counter() - t_leg, 1)}
 
 
+# ---- leg G: the latent attention / gated / hyper-connection blocks ---------
+# Worst distances read on the v5e at the published widths (my chip run, PR
+# 34, first call), bfloat16 against the float32 plain reference:
+#   A 0.00783 (d_q_norm; output 0.00593)     D 0.0047 (d_in; output 0.00423)
+#   E 0.00504 (d_in; output 0.00414)         H 0.00244 (d_in; output 0.00166)
+#   H with the maps' projection and Sinkhorn in bfloat16 (the control):
+#   0.00953 (d_maps_a; d_maps_P 0.00403, d_in 0.00385): H's tolerance lies
+#   between the two, twice the sound reading and half the control's.
+LEG_G_TOLERANCES = {"A": 0.02, "D": 0.015, "E": 0.015, "H": 0.005}
+
+
+@contextlib.contextmanager
+def _replaced(module, name: str, make):
+    """``module.<name>`` (a function through which a block takes its float32
+    part) replaced by ``make(the function)`` for a control's lower
+    precision, never the program's."""
+    was = getattr(module, name)
+    setattr(module, name, make(was))
+    try:
+        yield
+    finally:
+        setattr(module, name, was)
+
+
+class _HyperConnectedIdentity:
+    """One hyper-connected sublayer's own arithmetic as a block: ``X`` ->
+    maps -> the normed read ``h`` -> ``X' = H_res X + H_post^T h`` (the
+    sublayer ``F`` is the identity, so the read, its norm and the write all
+    carry gradient). Parameters ``{"maps": ..., "pre": ...}``."""
+
+    def __init__(self, conf, dtype):
+        self.maps = conf.vertices["b0H_maps"].layer
+        self.pre, self.post = (conf.vertices[f"b0H_{k}"] for k in ("pre",
+                                                                   "post"))
+        self.dtype = dtype
+
+    def init_params(self, key, it):
+        return {"maps": self.maps.init_params(key, it),
+                "pre": self.pre.init_params(key, it, it)}
+
+    def apply(self, params, x):
+        from deeplearning4j_tpu.nn.multilayer import (_cast_layer_params,
+                                                      _cast_params)
+
+        maps, _ = self.maps.apply(
+            _cast_layer_params(self.dtype, self.maps, params["maps"]), x, {})
+        h, _ = self.pre.apply(_cast_params(self.dtype, params["pre"]),
+                              [x, maps], {})
+        return self.post.apply({}, [x, maps, h], {})[0]
+
+
+def leg_g_latent_blocks(sizes: dict | None = None, seq_len: int = 8192,
+                        batch: int = 1, dtype: str = "bfloat16",
+                        tolerances: dict | None = None, kinds="ADEH",
+                        control: str | None = "bfloat16",
+                        seed: int = 0) -> dict:
+    """One latent attention (``A``), one gated dense feed-forward (``D``),
+    one gated expert layer (``E``) and one hyper-connected sublayer's own
+    arithmetic (``H``) as ``ComputationGraph`` runs them, against the plain
+    reference, as leg F does. ``control`` (``bfloat16``): ``A`` again with
+    the rotary angles rounded to bfloat16 and ``H`` again with the maps'
+    projection and the Sinkhorn in it; both must not pass. The angles are
+    rounded with ``jax.lax.reduce_precision``: a cast to bfloat16 and back
+    between elementwise operations proves nothing on the chip, where the
+    compiler keeps the excess precision inside a fusion (the block read
+    0.00781 against 0.00783 so; my chip run, PR 34)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models.xing4 import xing4_conf
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.layers import attention as att
+    from deeplearning4j_tpu.nn.layers import hyper_connections as hc
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    t_leg = time.perf_counter()
+    cfg, published = _bench_config("xing4_29b_a4b")
+    sizes = dict(published, **(sizes or {}))
+    tolerances = dict(LEG_G_TOLERANCES, **(tolerances or {}))
+    conf = xing4_conf(dtype=dtype, **dict(cfg.builder_kwargs(sizes),
+                                          n_dense=1, n_expert=1))
+    layers = {"A": conf.vertices["b0A_mixer"].layer,
+              "D": conf.vertices["b1D_mixer"].layer,
+              "E": conf.vertices["b3E_mixer"].layer}
+    plain = {"A": cfg.reference_attention, "D": cfg.reference_dense,
+             "E": cfg.reference_experts}
+    width, n = sizes["hidden_size"], sizes["hc_mult"]
+    it = InputType.recurrent(width, seq_len)
+    cdt = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 16)
+    # a block's input is a normed hidden state: unit scale, rounded to the
+    # compute dtype once, so both sides route and attend over the same values
+    x = jax.random.normal(keys[0], (batch, seq_len, width), jnp.float32)
+    x = x.astype(cdt).astype(jnp.float32)
+    w = jax.random.normal(keys[1], (batch, seq_len, width), jnp.float32)
+    check_blocks = _BlockCheck(tolerances, dtype, w)
+    held, compare = check_blocks.held, check_blocks.errors
+    failed = []
+
+    for kind in (k for k in "ADE" if k in kinds):
+        params, program, reference = check_blocks.layer_block(
+            layers[kind], plain[kind], sizes, keys[2 + "ADE".index(kind)], it)
+        want = jax.block_until_ready(reference(params, x))
+        got = jax.block_until_ready(program(params, x))
+        if not held(kind, compare(kind, got, want)):
+            failed.append(kind)
+        if kind == "A" and control == "bfloat16":
+            rounded = lambda exact: lambda t, f: jax.lax.reduce_precision(  # noqa: E731
+                exact(t, f), exponent_bits=8, mantissa_bits=7)
+            with _replaced(att, "_rotary_angles", rounded):
+                lower = jax.block_until_ready(check_blocks.layer_block(
+                    layers["A"], plain["A"], sizes, keys[2], it)[1](params, x))
+            if held("A", compare("A", lower, want),
+                    "A with the rotary angles in bfloat16 (control)"):
+                failed.append("control: lower-precision rotary angles passed")
+        del params, program, reference, want, got
+
+    if "H" in kinds:
+        wide = InputType.recurrent(n * width, seq_len)
+        block = _HyperConnectedIdentity(conf, dtype)
+        hp = _f32(block.init_params(keys[8], wide))
+        # streams that differ, and maps that depend on the token: gates of
+        # order one instead of the start's 0.01
+        hp["maps"]["a"] = jnp.asarray([0.5, -0.5, 0.25], jnp.float32)
+        X = jax.random.normal(keys[9], (batch, seq_len, n * width),
+                              jnp.float32).astype(cdt).astype(jnp.float32)
+        wide_checks = _BlockCheck(tolerances, dtype, jax.random.normal(
+            keys[10], X.shape, jnp.float32))
+        wide_checks.results = check_blocks.results
+
+        def program(p, X):
+            return block.apply(p, X.astype(cdt)).astype(jnp.float32)
+
+        def reference(p, X):
+            with jax.default_matmul_precision("highest"):
+                streams = X.reshape(X.shape[:-1] + (n, width))
+                pre, post, res = cfg.reference_maps(p["maps"], streams, sizes)
+                h = cfg._rmsnorm(jnp.einsum("bts,btsd->btd", pre, streams),
+                                 p["pre"]["gamma"], sizes["rms_norm_eps"])
+                out = (jnp.einsum("btij,btjd->btid", res, streams)
+                       + post[..., None] * h[..., None, :])
+                return out.reshape(X.shape)
+
+        want = jax.block_until_ready(wide_checks.both(reference)(hp, X))
+        got = jax.block_until_ready(wide_checks.both(program)(hp, X))
+        if not wide_checks.held("H", compare("H", got, want)):
+            failed.append("H")
+        if control:
+            with _replaced(hc, "_sinkhorn_dtype",
+                           lambda _: lambda dt: np.dtype(control)):
+                lower = jax.block_until_ready(
+                    wide_checks.both(program)(hp, X))
+            if wide_checks.held("H", compare("H", lower, want),
+                                f"H with the maps in {control} (control)"):
+                failed.append("control: lower-precision maps passed")
+
+    sites = {r["site"]: r["variant"] for r in ks.selection_log()
+             if r.get("mode") != "reference"}
+    print(f"  selection: {json.dumps(sites)}")
+    check(not failed, f"leg G: over tolerance or control passed: {failed}")
+    return {"worst": check_blocks.results, "selection": sites,
+            "leg_seconds": round(time.perf_counter() - t_leg, 1)}
+
+
 # --------------------------------------------------------------------- main
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma list out of A,B,C,D,E,F (default: all)")
+                    help="comma list out of A,B,C,D,E,F,G (default: all)")
     legs = [l.strip().upper() for l in ap.parse_args(argv).legs.split(",")
             if l.strip()]
     unknown = [l for l in legs if l not in LEGS]
@@ -1153,6 +1366,7 @@ def main(argv=None) -> int:
     else:
         run("E", leg_e_four_chips)
     run("F", leg_f_hybrid_blocks)
+    run("G", leg_g_latent_blocks)
 
     print(f"compile totals: {json.dumps(monitors().snapshot())}")
     print(f"compile manager: {json.dumps(_admission_state())}")

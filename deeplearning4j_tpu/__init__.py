@@ -58,7 +58,11 @@ from .nn.layers.recurrent import (
     LastTimeStepLayer,
 )
 from .nn.layers.normalization import BatchNormalization, LocalResponseNormalization
-from .nn.layers.attention import LayerNormLayer, SelfAttentionLayer
+from .nn.layers.attention import (LatentAttentionLayer, LayerNormLayer,
+                                  SelfAttentionLayer)
+from .nn.layers.dense import GatedFeedForwardLayer
+from .nn.layers.hyper_connections import (HyperConnectionMapsLayer,
+                                          HyperConnectionVertex)
 from .nn.layers.moe import DroplessExpertsLayer, MixtureOfExpertsLayer
 from .nn.layers.state_space import Mamba2Layer, RMSNormLayer
 from .nn.layers.center_loss import CenterLossOutputLayer

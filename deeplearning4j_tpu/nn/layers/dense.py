@@ -60,6 +60,54 @@ class DenseLayer(BaseLayer):
 
 @register_layer
 @dataclass
+class GatedFeedForwardLayer(BaseLayer):
+    """A gated feed-forward block over the trailing axis, no bias:
+    ``(act(x @ W_gate) * (x @ W_up)) @ W_down`` with ``hidden`` columns in
+    between and ``n_out`` (the input's width when 0) out. ``activation``
+    is the gate's (``silu``: SwiGLU)."""
+
+    n_out: int = 0
+    hidden: int = 0
+    activation: str = "silu"
+    init_std: float = 0.02
+    rescale_layers: int = 0       # > 0: W_down at init_std / sqrt(it)
+
+    PARAM_ROLES = {"W_gate": "ffn_up", "W_up": "ffn_up", "W_down": "ffn_down"}
+
+    @property
+    def is_recurrent(self) -> bool:
+        return False  # shape-agnostic over leading dims
+
+    def get_output_type(self, input_type: InputType) -> InputType:
+        n = self.n_out or input_type.size
+        if input_type.kind == "rnn":
+            return InputType.recurrent(n, input_type.timesteps)
+        return InputType.feed_forward(n)
+
+    def init_params(self, key, input_type) -> Params:
+        n_in = input_type.size
+        if not self.hidden:
+            raise ValueError("GatedFeedForwardLayer needs its hidden width")
+        dt = jnp.result_type(float)
+        kg, ku, kd = jax.random.split(key, 3)
+        down = self.init_std / (self.rescale_layers or 1) ** 0.5
+        return {
+            "W_gate": self.init_std * jax.random.normal(
+                kg, (n_in, self.hidden), dt),
+            "W_up": self.init_std * jax.random.normal(
+                ku, (n_in, self.hidden), dt),
+            "W_down": down * jax.random.normal(
+                kd, (self.hidden, self.n_out or n_in), dt),
+        }
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        x = maybe_dropout(x, self.dropout, train, rng)
+        hid = self._activate(x @ params["W_gate"]) * (x @ params["W_up"])
+        return hid @ params["W_down"], state
+
+
+@register_layer
+@dataclass
 class OutputLayer(DenseLayer):
     """Dense + loss head. Reference: conf/layers/OutputLayer.java.
 

@@ -186,7 +186,10 @@ class DroplessExpertsLayer(BaseLayer):
         w = s[chosen] / sum(s[chosen]) * routed_scaling       (norm_topk_prob)
         out = sum_k w_k * expert_k(token) + shared_expert(token)
 
-    with ``expert(x) = act(x @ W_up) @ W_down`` (not gated, no bias).
+    with ``expert(x) = act(x @ W_up) @ W_down`` (no bias), or with ``gated``
+    ``expert(x) = (act(x @ W_gate) * (x @ W_up)) @ W_down``: ``W_gate`` and
+    ``W_up`` are two stacks and two grouped products, and the shared expert
+    is gated likewise (``Ws_gate``).
     ``experts_held_first`` / ``experts_held_count`` (0: all) name the experts
     whose weights live here: the router scores all ``n_experts``, and only the
     held experts' part of the result is computed; what the others would add is
@@ -214,10 +217,12 @@ class DroplessExpertsLayer(BaseLayer):
     routed_scaling: float = 1.0
     norm_topk_prob: bool = True
     expert_activation: str = "relu2"
+    gated: bool = False           # act(x W_gate) * (x W_up) before W_down
     init_std: float = 0.02
     rescale_layers: int = 0       # > 0: down projections at init_std / sqrt(it)
 
-    PARAM_ROLES = {"Ws_up": "ffn_up", "Ws_down": "ffn_down"}
+    PARAM_ROLES = {"Ws_up": "ffn_up", "Ws_gate": "ffn_up",
+                   "Ws_down": "ffn_down"}
     FLOAT32_PARAMS = ("Wr", "e_bias")   # the router scores in float32
     COUNTERS = ("rows_held", "rows_fullest", "tokens", "rows_dropped")
 
@@ -261,6 +266,12 @@ class DroplessExpertsLayer(BaseLayer):
                 ksu, (n_in, self.shared_hidden), dt)
             p["Ws_down"] = down * normal(
                 ksd, (self.shared_hidden, self.n_out), dt)
+        if self.gated:   # keys of their own: the other draws stay as they were
+            kg, ksg = jax.random.split(jax.random.fold_in(key, 1))
+            p["W_gate"] = self.init_std * normal(kg, (count, n_in, h), dt)
+            if self.shared_hidden:
+                p["Ws_gate"] = self.init_std * normal(
+                    ksg, (n_in, self.shared_hidden), dt)
         return p
 
     def init_state(self, input_type) -> State:
@@ -329,8 +340,11 @@ class DroplessExpertsLayer(BaseLayer):
                 w_rows = jnp.where(valid, w.reshape(-1)[pick], 0)
             with jax.named_scope("experts"):
                 acc = jnp.promote_types(tokens.dtype, jnp.float32)
-                hid = act(grouped_matmul(xs, params["W_up"], group, padded,
-                                         variant, acc))
+                hid = grouped_matmul(xs, params["W_up"], group, padded,
+                                     variant, acc)
+                hid = act(hid) if not self.gated else hid * act(
+                    grouped_matmul(xs, params["W_gate"], group, padded,
+                                   variant, acc))
                 out = grouped_matmul(hid.astype(tokens.dtype),
                                      params["W_down"], group, padded,
                                      variant, acc)
@@ -357,7 +371,10 @@ class DroplessExpertsLayer(BaseLayer):
     def shared(self, params, tokens):
         """The shared expert, which every share computes alike."""
         with jax.named_scope("shared_expert"):
-            return self._act()(tokens @ params["Ws_up"]) @ params["Ws_down"]
+            hid = tokens @ params["Ws_up"]
+            hid = self._act()(hid) if not self.gated \
+                else self._act()(tokens @ params["Ws_gate"]) * hid
+            return hid @ params["Ws_down"]
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         lead = x.shape[:-1]

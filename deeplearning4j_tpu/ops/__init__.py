@@ -201,9 +201,13 @@ def select_lstm_variant(T: int, B: int, H: int, itemsize: int,
 
 def select_attention_variant(B: int, heads: int, T: int, D: int,
                              itemsize: int, impl: str = "auto",
-                             causal: bool = False, kv_heads: int = 0) -> str:
+                             causal: bool = False, kv_heads: int = 0,
+                             d_v: int = 0, d_rope: int = 0) -> str:
     """'flash' | 'xla' for a local attention call; an explicit
-    ``attention_impl`` ("flash"/"xla") is the per-site escape hatch."""
+    ``attention_impl`` ("flash"/"xla") is the per-site escape hatch. ``D`` is
+    the width of the score product; ``d_v`` (0: ``D``) the value product's
+    and ``d_rope`` the part of ``D`` that is a rotary key every head shares
+    (latent attention), recorded only where they say something."""
     forced = impl if impl in ("flash", "xla") else None
     if _FORCED is False:
         forced = "xla"
@@ -211,6 +215,9 @@ def select_attention_variant(B: int, heads: int, T: int, D: int,
            "itemsize": int(itemsize), "causal": bool(causal)}
     if kv_heads and kv_heads != heads:   # grouped-query heads only
         ctx["kv_heads"] = int(kv_heads)
+    if d_rope or (d_v and d_v != D):     # products of two sizes only
+        ctx.update(d_qk=int(D), d_v=int(d_v or D), d_rope=int(d_rope),
+                   rope_shared_key=bool(d_rope))
     return kernel_select.select("attention", ctx, forced=forced)
 
 

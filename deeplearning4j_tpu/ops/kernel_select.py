@@ -639,6 +639,17 @@ def _attn_dims(ctx):
 _OPERAND_NAME = {2: "bfloat16", 4: "float32", 8: "float64"}
 
 
+def _strip_width(ctx) -> int:
+    """What the flash kernels size their tiles and their VMEM budget by:
+    ``D`` for a plain call, the lane-padded strips of a call whose score and
+    value products differ (``d_qk``, ``d_v``, ``d_rope`` in the ctx)."""
+    from .flash_attention import strip_width  # noqa: PLC0415
+
+    rope = ctx.get("d_rope", 0)
+    return strip_width(ctx.get("d_qk", ctx["D"]) - rope,
+                       ctx.get("d_v", ctx["D"]), rope)
+
+
 def _flash_detail(ctx) -> dict:
     """The tiles the flash kernels take at these shapes, the dtype their
     products multiply in (the operands' own), and the share of the full
@@ -646,7 +657,8 @@ def _flash_detail(ctx) -> dict:
     2n`` with it for ``n`` tiles a side."""
     from .flash_attention import default_blocks, tiles_walked_share  # noqa: PLC0415
 
-    block_q, block_k = default_blocks(ctx["T"], ctx["D"], ctx["itemsize"])
+    block_q, block_k = default_blocks(ctx["T"], _strip_width(ctx),
+                                      ctx["itemsize"])
     return {"block_q": block_q, "block_k": block_k,
             "mxu_operand": _OPERAND_NAME[ctx["itemsize"]],
             "tiles_walked_share": tiles_walked_share(
@@ -677,7 +689,7 @@ def _flash_fits_ctx(ctx) -> bool:
     site must say ``xla`` — never report a kernel that did not run."""
     from .flash_attention import _KV_VMEM_BUDGET_BYTES  # noqa: PLC0415
 
-    return (2 * ctx["T"] * ctx["D"] * ctx["itemsize"]
+    return (2 * ctx["T"] * _strip_width(ctx) * ctx["itemsize"]
             <= _KV_VMEM_BUDGET_BYTES)
 
 

@@ -69,7 +69,7 @@ def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
     B, H, Tq, D = q.shape
     m = jnp.full((B, H, Tq), _NEG_INF, q.dtype)
     l = jnp.zeros((B, H, Tq), q.dtype)
-    o = jnp.zeros((B, H, Tq, D), q.dtype)
+    o = jnp.zeros((B, H, Tq, v.shape[-1]), q.dtype)
     m, l, o = _block_accumulate(q, k, v, m, l, o, scale, causal, 0, 0, key_mask)
     return o / jnp.maximum(l, 1e-30)[..., None]
 

@@ -115,6 +115,45 @@ def test_leg_f_fails_when_the_lower_precision_control_passes():
             tolerances=tol, kinds="M")
 
 
+def _tiny_latent_sizes():
+    import json
+
+    with open(os.path.join(REPO, "tests", "benchmark_harness", "presets",
+                           "configs", "xing4_29b_a4b.json")) as f:
+        return json.load(f)["sizes"]
+
+
+G_CONTROLS = ("A with the rotary angles in bfloat16 (control)",
+              "H with the maps in bfloat16 (control)")
+
+
+@pytest.mark.parametrize("mode", ["auto", "fused"])
+def test_leg_g_latent_blocks_tiny(monkeypatch, mode):
+    # float32 on the CPU, so the tolerances are rounding's; "fused" takes
+    # the flash kernels (two sizes of product, a shared rotary key) and the
+    # grouped products in interpret mode
+    monkeypatch.setenv("DL4JTPU_KERNELS", mode)
+    # 24 positions and weights of 0.02: rounding the angles moves the
+    # attention block by 3e-5 only, so its tolerance here is 1e-5
+    tol = dict(dict.fromkeys(("D", "E", "H"), 2e-4), A=1e-5)
+    res = chip_smoke.leg_g_latent_blocks(
+        _tiny_latent_sizes(), seq_len=24, batch=2, dtype="float32",
+        tolerances=tol)
+    assert set(res["worst"]) == {"A", "D", "E", "H", *G_CONTROLS}
+    assert res["worst"][G_CONTROLS[0]] > 10 * res["worst"]["A"]
+    assert res["worst"][G_CONTROLS[1]] > 1e-3
+    if mode == "fused":
+        assert res["selection"]["attention"] == "flash"
+
+
+def test_leg_g_fails_when_a_lower_precision_control_passes():
+    tol = dict.fromkeys(("A", "D", "E", "H"), 0.9)
+    with pytest.raises(chip_smoke.LegFailure, match="control"):
+        chip_smoke.leg_g_latent_blocks(
+            _tiny_latent_sizes(), seq_len=16, batch=1, dtype="float32",
+            tolerances=tol, kinds="H")
+
+
 def test_main_refuses_to_pass_without_a_chip():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],

@@ -137,18 +137,26 @@ def test_grouped_matmul_kernels_compile_for_the_v5e_at_the_published_shapes(
         assert "grouped_matmul_" + name in text
 
 
-@pytest.mark.parametrize("B,H,Hkv,T,D,dtype,causal,masked", [
-    (1, 32, 2, 8192, 128, "bfloat16", True, False),  # nemotron3_nano_train_1chip
-    (1, 32, 2, 8192, 128, "bfloat16", False, True),  # every tile, a key mask
-    (2, 4, 4, 256, 64, "float32", True, True),       # chip_smoke's f32
-    (2, 4, 2, 1000, 64, "bfloat16", True, False),    # T padded to 1024
-    (2, 4, 2, 100, 64, "bfloat16", True, False),     # one tile short of a lane tile
+@pytest.mark.parametrize("B,H,Hkv,T,D,dtype,causal,masked,latent", [
+    (1, 32, 2, 8192, 128, "bfloat16", True, False, None),  # nemotron3_nano_train_1chip
+    (1, 32, 2, 8192, 128, "bfloat16", False, True, None),  # every tile, a key mask
+    (2, 4, 4, 256, 64, "float32", True, True, None),       # chip_smoke's f32
+    (2, 4, 2, 1000, 64, "bfloat16", True, False, None),    # T padded to 1024
+    (2, 4, 2, 100, 64, "bfloat16", True, False, None),     # one tile short of a lane tile
+    # xing4_train_1chip: 4 heads held, scores over 128 + a rotary 64 whose
+    # key every head shares, values of 128
+    (1, 4, 4, 8192, 128, "bfloat16", True, False, (64, 128)),
+    (1, 4, 4, 8192, 128, "bfloat16", False, True, (64, 128)),
+    (2, 4, 4, 300, 128, "float32", True, True, (64, 128)),  # chip_smoke's f32
+    (2, 4, 2, 1000, 192, "bfloat16", True, False, (0, 128)),  # d_qk != d_v alone
 ])
 def test_flash_kernels_compile_for_the_v5e_at_their_default_tiles(
-        one_chip, monkeypatch, B, H, Hkv, T, D, dtype, causal, masked):
+        one_chip, monkeypatch, B, H, Hkv, T, D, dtype, causal, masked, latent):
     """The tiles ``default_blocks`` picks fit the compiler's default scoped
     VMEM beside the strips (no call states a limit), the traced loop bounds
-    lower, and bfloat16 operands reach the MXU as they are."""
+    lower, and bfloat16 operands reach the MXU as they are. ``latent``:
+    ``(rotary width, value width)`` of a call whose score and value products
+    differ in size."""
     import importlib
 
     fa = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
@@ -156,16 +164,22 @@ def test_flash_kernels_compile_for_the_v5e_at_their_default_tiles(
     dt = jnp.dtype(dtype)
     s = lambda shape, d=dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, d, sharding=one_chip)
-    args = [s((B, H, T, D)), s((B, Hkv, T, D)), s((B, Hkv, T, D))]
+    d_rope, d_v = latent or (0, D)
+    args = [s((B, H, T, D)), s((B, Hkv, T, D)), s((B, Hkv, T, d_v))]
+    if d_rope:
+        args += [s((B, H, T, d_rope)), s((B, 1, T, d_rope))]
     if masked:
         args.append(s((B, T), jnp.float32))
 
-    def loss(q, k, v, *mask):
-        out = fa.flash_attention(q, k, v, causal=causal,
-                                 key_mask=mask[0] if mask else None)
+    def loss(q, k, v, *rest):
+        rest = list(rest)
+        mask = rest.pop() if masked else None
+        out = fa.flash_attention(q, k, v, causal=causal, key_mask=mask,
+                                 q_rope=rest[0] if rest else None,
+                                 k_rope=rest[1] if rest else None)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    grad = jax.jit(jax.grad(loss, argnums=tuple(range(5 if d_rope else 3))))
     # a float32 contract precision on bfloat16 operands is nothing Mosaic
     # takes: the kernels' products state their own
     with jax.enable_x64(False), jax.default_matmul_precision("float32"):
