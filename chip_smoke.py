@@ -815,11 +815,71 @@ def _kd_lrn(shape, dtype):
             "grad": _err_norm(jax.jit(gl(fused))(x), gl(ref)(x))}
 
 
+def _kd_hyper(N, n, D, dtype):
+    """The six kernels of the hyper-connected residual (the maps' projection,
+    the normed read, the write; forward and every gradient) through their
+    layer objects, against the same objects' jax.numpy (the site's
+    ``reference`` variant) in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.layers import hyper_connections as hc
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    f32 = jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(N + n + D), 5)
+    x = jax.random.normal(keys[0], (1, N, n * D), f32).astype(dtype)
+    y = jax.random.normal(keys[1], (1, N, D), f32).astype(dtype)
+    maps = jax.random.uniform(keys[2], (1, N, n * (n + 2)), f32)
+    gamma = 1.0 + 0.1 * jax.random.normal(keys[3], (D,), f32)
+    layer = hc.HyperConnectionMapsLayer(n_streams=n)
+    start = layer.init_params(keys[4], InputType.recurrent(n * D, N))
+    # gates of order one: the maps depend on the token
+    a, b = jnp.asarray([0.5, -0.5, 0.25], f32), start["b"].astype(f32)
+    read = hc.HyperConnectionVertex(op="read", n_streams=n, norm_eps=1e-6)
+    write = hc.HyperConnectionVertex(op="write", n_streams=n)
+    pieces = {
+        "maps": (lambda x, P: layer.apply({"P": P, "a": a, "b": b}, x, {})[0],
+                 (x, start["P"].astype(f32))),
+        "read": (lambda x, m, g: read.apply({"gamma": g}, [x, m], {})[0],
+                 (x, maps, gamma)),
+        "write": (lambda x, m, y: write.apply({}, [x, m, y], {})[0],
+                  (x, maps, y)),
+    }
+    out = {}
+    for name, (fn, args) in pieces.items():
+        argnums = tuple(range(len(args)))
+
+        def both(variant, args, fn=fn, argnums=argnums):
+            ks.set_site_override("hyper_connection", variant)
+            try:
+                # a jit of its own a variant: the site is asked while tracing
+                value = jax.jit(lambda *a: fn(*a))(*args)
+                grads = jax.jit(jax.grad(lambda *a: jnp.sum(
+                    fn(*a).astype(f32) ** 2), argnums=argnums))(*args)
+                return jax.block_until_ready((value, grads))
+            finally:
+                ks.set_site_override("hyper_connection", None)
+
+        got, got_g = _named(f"hyper_{name}", lambda: both("fused", args))
+        want, want_g = both("reference", jax.tree_util.tree_map(
+            lambda t: t.astype(f32), args))
+        out[f"{name}_fwd"] = _err(got, want)
+        out[f"{name}_grad"] = max(map(_err_norm, got_g, want_g))
+    ran = {r["ctx"]["op"] for r in ks.selection_log()
+           if r["site"] == "hyper_connection" and r["variant"] == "fused"
+           and r["ctx"]["N"] == N and r["ctx"]["itemsize"]
+           == jnp.dtype(dtype).itemsize}
+    check(ran == set(pieces), f"hyper-connection kernels ran for {ran} only")
+    return out
+
+
 def leg_d_kernels(lstm=(256, 64, 512), lstm_small=(32, 16, 128),
                   sxent=((16384, 96), (128, 1000), (256, 10)),
                   adam=((512, 2048), (2048,), (96,), (7, 9)),
                   flash=(2, 4, 256, 64), flash_cell=(1, 32, 2, 8192, 128),
-                  lrn=(4, 14, 14, 64)) -> dict:
+                  lrn=(4, 14, 14, 64), hyper=(8192, 4, 3584)) -> dict:
     """Every Pallas kernel compiled (interpret mode only off-TPU), f32 and
     bf16, against its XLA reference. Runs every check before failing, so one
     chip call shows every kernel Mosaic rejects."""
@@ -842,6 +902,8 @@ def leg_d_kernels(lstm=(256, 64, 512), lstm_small=(32, 16, 128),
             plan.append((f"flash_cell{flash_cell}/{name}", _kd_flash_cell,
                          (*flash_cell, dt)))
         plan.append((f"lrn{lrn}/{name}", _kd_lrn, (lrn, dt)))
+        # xing4_train_1chip's streams: [8192, 4 x 3584]
+        plan.append((f"hyper{hyper}/{name}", _kd_hyper, (*hyper, dt)))
     failed, worst = [], {}
     for label, fn, args in plan:
         tol = _tol(args[-1], long_sequence=fn is _kd_lstm and args[0] >= 128)
@@ -1305,6 +1367,9 @@ def leg_g_latent_blocks(sizes: dict | None = None, seq_len: int = 8192,
     sites = {r["site"]: r["variant"] for r in ks.selection_log()
              if r.get("mode") != "reference"}
     print(f"  selection: {json.dumps(sites)}")
+    if "H" in kinds and jax.default_backend() == "tpu":
+        check(sites.get("hyper_connection") == "fused",
+              "leg G: the hyper-connection block ran without its kernels")
     check(not failed, f"leg G: over tolerance or control passed: {failed}")
     return {"worst": check_blocks.results, "selection": sites,
             "leg_seconds": round(time.perf_counter() - t_leg, 1)}

@@ -121,10 +121,16 @@ class ComputationGraph(TrainingEngine):
         new_rnn = dict(rnn_state) if rnn_state is not None else None
         for name, r in zip(self._topo, rngs):
             vertex = conf.vertices[name]
-            ins = [acts[src] for src in conf.vertex_inputs[name]]
+            sources = conf.vertex_inputs[name]
+            ins = [acts[src] for src in sources]
+            stepping = new_rnn is not None and bool(new_rnn.get(name))
+            # a vertex that hands its first input on: the vertices after it
+            # read that input from here (BaseVertex.hands_input_on)
+            hands_on = train and not stepping and vertex.hands_input_on
+            apply = vertex.apply_handing_on if hands_on else vertex.apply
             # every operation of a vertex carries its configured name
             with jax.named_scope(name):
-                if new_rnn is not None and new_rnn.get(name):
+                if stepping:
                     acts[name], new_rnn[name] = vertex.apply_seq(
                         params[name], ins, new_rnn[name], train=train, rng=r,
                         masks=vmasks
@@ -133,18 +139,20 @@ class ComputationGraph(TrainingEngine):
                     # per-vertex jax.checkpoint: keep only vertex-boundary
                     # activations for backward (see
                     # MultiLayerConfiguration.remat)
-                    def _ck(p_, ins_, st_, r_, m_, _v=vertex):
-                        return _v.apply(p_, ins_, st_, train=True, rng=r_,
-                                        masks=m_)
+                    def _ck(p_, ins_, st_, r_, m_, _apply=apply):
+                        return _apply(p_, ins_, st_, train=True, rng=r_,
+                                      masks=m_)
 
                     acts[name], new_state[name] = jax.checkpoint(_ck)(
                         params[name], ins, state[name], r, vmasks
                     )
                 else:
-                    acts[name], new_state[name] = vertex.apply(
+                    acts[name], new_state[name] = apply(
                         params[name], ins, state[name], train=train, rng=r,
                         masks=vmasks
                     )
+            if hands_on:
+                acts[name], acts[sources[0]] = acts[name]
         return acts, new_state, new_rnn
 
     def _forward(self, params, inputs, state, train, rng, masks=None, rnn_state=None):

@@ -99,6 +99,16 @@ class BaseVertex:
     ) -> Tuple[jnp.ndarray, State]:
         raise NotImplementedError
 
+    @property
+    def hands_input_on(self) -> bool:
+        """Whether a training graph should call :meth:`apply_handing_on`:
+        ``((output, first input), state)``, the first input handed on for
+        the vertices that read it after this one. What they send back for it
+        then reaches this vertex's backward pass beside its output's
+        cotangent, and a vertex whose backward pass writes the whole of that
+        input's cotangent adds onto it where it lies."""
+        return False
+
 
 @register_vertex
 @dataclass
@@ -162,6 +172,17 @@ class LayerVertex(BaseVertex):
             x = self.preprocessor.apply(x)
         mask = None if masks is None else masks.get("features")
         return self.layer.apply(params, x, state, train=train, rng=rng, mask=mask)
+
+    @property
+    def hands_input_on(self) -> bool:
+        return self.preprocessor is None \
+            and hasattr(self.layer, "apply_handing_on")
+
+    def apply_handing_on(self, params, inputs, state, *, train=False,
+                         rng=None, masks=None):
+        mask = None if masks is None else masks.get("features")
+        return self.layer.apply_handing_on(params, inputs[0], state,
+                                           train=train, rng=rng, mask=mask)
 
     # ---- streaming/TBPTT support (reference: ComputationGraph.rnnTimeStep
     # :1801 routes through each vertex's rnnTimeStep; only layer vertices

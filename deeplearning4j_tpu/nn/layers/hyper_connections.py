@@ -26,7 +26,10 @@ pre-norm, with its learned scale, is taken where the streams are read, so
 that the un-normed sum is nowhere kept for the backward pass), ``write``
 (``X``, maps, ``y`` -> ``X'``), and ``expand`` / ``collapse`` (the embedding
 copied into the ``n`` streams; the streams summed before the final norm).
-Each is written to read ``X`` once.
+Each is written to read ``X`` once. The projection, ``read`` and ``write``
+run as the jax.numpy below or as the Mosaic kernels of
+``ops/hyper_connections.py``, as the ``hyper_connection`` selection site says
+for their shapes.
 """
 
 from __future__ import annotations
@@ -37,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops import hyper_connections as hc_kernels
+from ...ops import select_hyper_connection_variant
 from ..conf.inputs import InputType
 from ..graph.vertices import BaseVertex, register_vertex
 from .base import BaseLayer, Params, register_layer
@@ -58,6 +63,14 @@ def sinkhorn(logits, iters: int, eps: float):
         m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)   # columns
         m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)   # rows
     return m
+
+
+def _fused(op: str, x, n: int) -> bool:
+    """Whether the ``hyper_connection`` site takes the kernels for piece
+    ``op`` of the streams ``x`` [.., n * D]."""
+    return select_hyper_connection_variant(
+        op, x.size // x.shape[-1], n, x.shape[-1] // n,
+        x.dtype.itemsize) == "fused"
 
 
 def split_maps(maps, n: int):
@@ -126,19 +139,37 @@ class HyperConnectionMapsLayer(BaseLayer):
         }
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        return self._maps(params, x, False)[0], state
+
+    def apply_handing_on(self, params, x, state, *, train=False, rng=None,
+                         mask=None):
+        """``((maps, x), state)``: ``BaseVertex.hands_input_on``."""
+        return self._maps(params, x, True), state
+
+    def _maps(self, params, x, hand_on: bool):
+        """``(maps, x)``; with ``hand_on`` the ``x`` whose cotangent the
+        projection's backward kernel adds onto."""
         n = self.n_streams
         f = _sinkhorn_dtype(x.dtype)
         lead = x.shape[:-1]
         tokens = x.reshape(-1, x.shape[-1])
         with jax.named_scope("project"):
-            rms = jax.lax.rsqrt(jnp.mean(jnp.square(tokens.astype(f)),
-                                         axis=-1, keepdims=True)
-                                + self.norm_eps)
-            raw = jnp.dot(tokens.astype(f), params["P"].astype(f),
-                          precision=jax.lax.Precision.HIGHEST) * rms
+            # [maps, N] and [1, N]: tokens along the lanes from here on
+            if _fused("maps", x, n):
+                project = hc_kernels.hc_project_handing_on if hand_on \
+                    else hc_kernels.hc_project
+                xp, ms, *on = project(tokens, params["P"].astype(f), n)
+                xp, ms = xp.astype(f), ms.astype(f)
+                x = on[0].reshape(x.shape) if on else x
+            else:
+                ms = jnp.mean(jnp.square(tokens.astype(f)), axis=-1,
+                              keepdims=True).T
+                xp = jnp.dot(tokens.astype(f), params["P"].astype(f),
+                             precision=jax.lax.Precision.HIGHEST).T
             gate = params["a"].astype(f)[
                 np.repeat(np.arange(3), [n, n, n * n])]
-            raw = (raw * gate + params["b"].astype(f)).T      # [maps, N]
+            raw = xp * jax.lax.rsqrt(ms + self.norm_eps) * gate[:, None] \
+                + params["b"].astype(f)[:, None]
         with jax.named_scope("sinkhorn"):
             res = sinkhorn(
                 jnp.clip(raw[2 * n:], self.clamp_min, self.clamp_max)
@@ -146,7 +177,7 @@ class HyperConnectionMapsLayer(BaseLayer):
         maps = jnp.concatenate([jax.nn.sigmoid(raw[:n]),
                                 2.0 * jax.nn.sigmoid(raw[n:2 * n]),
                                 res.reshape(n * n, -1)], axis=0).T
-        return maps.reshape(lead + (self.n_maps,)), state
+        return maps.reshape(lead + (self.n_maps,)), x
 
 
 @register_vertex
@@ -201,10 +232,42 @@ class HyperConnectionVertex(BaseVertex):
             return t
         return self._resized(t, t.size // n)
 
+    def _apply_kernels(self, params, inputs, hand_on: bool = False):
+        """``read`` or ``write`` through ``ops/hyper_connections.py``; with
+        ``hand_on`` the read's ``(output, x)``."""
+        n, x = self.n_streams, inputs[0]
+        tokens = lambda a: a.reshape(-1, a.shape[-1])  # noqa: E731
+        shaped = lambda a: a.reshape(x.shape[:-1] + a.shape[-1:])  # noqa: E731
+        if self.op == "write":
+            return shaped(hc_kernels.hc_write(tokens(x), tokens(inputs[1]),
+                                              tokens(inputs[2]), n))
+        read = hc_kernels.hc_read_handing_on if hand_on else hc_kernels.hc_read
+        out = read(tokens(x), tokens(inputs[1]),
+                   params["gamma"] if self.has_params else None, n,
+                   float(self.norm_eps) if self.has_params else 0.0)
+        return (shaped(out[0]), shaped(out[1])) if hand_on else shaped(out)
+
+    @property
+    def hands_input_on(self) -> bool:
+        return self.op == "read"
+
+    def apply_handing_on(self, params, inputs, state, *, train=False,
+                         rng=None, masks=None):
+        """The read's ``((output, X), state)``: ``X`` handed on, so that the
+        write's cotangent of it comes back through the read's backward
+        kernel, which adds onto it."""
+        if _fused("read", inputs[0], self.n_streams):
+            return self._apply_kernels(params, inputs, hand_on=True), state
+        out, state = self.apply(params, inputs, state, train=train, rng=rng,
+                                masks=masks)
+        return (out, inputs[0]), state
+
     def apply(self, params, inputs, state, *, train=False, rng=None, masks=None):
         n, x = self.n_streams, inputs[0]
         if self.op == "expand":
             return jnp.tile(x, (1,) * (x.ndim - 1) + (n,)), state
+        if self.op in ("read", "write") and _fused(self.op, x, n):
+            return self._apply_kernels(params, inputs), state
         f = jnp.promote_types(x.dtype, jnp.float32)
         d = x.shape[-1] // n
         # a stream is a slice of the features (whole lane tiles at a width

@@ -262,6 +262,17 @@ def select_grouped_matmul_variant(M: int, K: int, N: int, E: int,
     return kernel_select.select("grouped_matmul", ctx, forced=forced)
 
 
+def select_hyper_connection_variant(op: str, N: int, n: int, D: int,
+                                    itemsize: int) -> str:
+    """'fused' | 'reference' for one piece (``op``: 'maps', 'read', 'write')
+    of a hyper-connected residual of ``n`` streams ``D`` wide over ``N``
+    tokens."""
+    forced = "reference" if _FORCED is False else None
+    ctx = {"N": int(N), "n": int(n), "D": int(D), "op": str(op),
+           "itemsize": int(itemsize)}
+    return kernel_select.select("hyper_connection", ctx, forced=forced)
+
+
 def select_optimizer_variant(n_elems: int, itemsize: int, updater: str,
                              n_leaves: int = 1) -> str:
     forced = "reference" if _FORCED is False else None
@@ -284,6 +295,7 @@ __all__ = [
     "lstm_helper_enabled",
     "select_attention_variant",
     "select_grouped_matmul_variant",
+    "select_hyper_connection_variant",
     "select_lrn_variant",
     "select_lstm_variant",
     "select_optimizer_variant",

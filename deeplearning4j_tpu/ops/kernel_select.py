@@ -874,6 +874,51 @@ register_site(Site(
 ))
 
 
+# reads and writes of the streams X [N, n * D] (the unit) a piece makes,
+# forward + backward, when each kernel passes over X once: the read's result
+# and the sublayer's output are a quarter of a unit at four streams
+_HC_PASSES = {"maps": 1.0 + 2.0, "read": 1.25 + 2.25, "write": 2.25 + 3.5}
+
+
+def _hc_cost(ctx, trips: float):
+    elems = float(ctx["N"]) * ctx["n"] * ctx["D"]
+    # a multiply-add a term: 24 columns, n streams, n * n + n map entries
+    terms = {"maps": ctx["n"] * (2 + ctx["n"]), "read": 1,
+             "write": ctx["n"] + 1}[ctx["op"]]
+    return (6.0 * terms * elems,
+            trips * _HC_PASSES[ctx["op"]] * ctx["itemsize"] * elems)
+
+
+def _hc_fused_cost(ctx):
+    return (*_hc_cost(ctx, 1.0), 2 * _LAUNCH_S)
+
+
+def _hc_reference_cost(ctx):
+    # measured on the compiled program, not modelled: XLA's fusions move
+    # 7.0 GB a sublayer where one pass a piece moves 3.6 (PERF.md, PR 35)
+    return (*_hc_cost(ctx, 2.0), 0.0)
+
+
+def _hc_row_tile(ctx):
+    from .hyper_connections import hc_row_tile  # noqa: PLC0415
+
+    return hc_row_tile(ctx["op"], ctx["n"], ctx["D"], ctx["itemsize"])
+
+
+register_site(Site(
+    name="hyper_connection",
+    reference="reference",
+    preferred_fused="fused",
+    variants={
+        "fused": Variant("fused", fused=True, cost=_hc_fused_cost,
+                         available=lambda ctx: _hc_row_tile(ctx) is not None,
+                         detail=lambda ctx: {"row_tile": _hc_row_tile(ctx)}),
+        "reference": Variant("reference", fused=False,
+                             cost=_hc_reference_cost),
+    },
+))
+
+
 def _opt_fused_cost(ctx):
     n, itemsize = ctx["n_elems"], ctx["itemsize"]
     # read g/m/v, write u/m/v in one pass per leaf

@@ -72,8 +72,8 @@ def test_leg_d_kernels_tiny():
         sxent=((64, 96), (16, 1000), (32, 10)),
         adam=((16, 128), (256,), (96,), (7, 9)),
         flash=(1, 2, 32, 16), flash_cell=(1, 4, 2, 48, 16),
-        lrn=(2, 4, 4, 16))
-    assert res["checks"] == 21
+        lrn=(2, 4, 4, 16), hyper=(40, 2, 128))
+    assert res["checks"] == 23
 
 
 def test_leg_e_on_the_virtual_mesh():

@@ -194,6 +194,61 @@ _HLO_LINE = re.compile(r"^\s*(ROOT\s+)?%(\S+) = .*? ([a-z-]+)\(([^)]*)\)")
 _TIED = re.compile(r"\{(\d)\}: \((\d), \{\}\)")   # {result}: (operand, {})
 
 
+@pytest.mark.parametrize("N,n,D,dtype", [
+    (8192, 4, 3584, "bfloat16"),   # xing4_train_1chip's streams
+    (8192, 4, 3584, "float32"),    # chip_smoke's f32
+    (1000, 2, 256, "bfloat16"),    # two streams, rows padded to whole tiles
+])
+def test_hyper_connection_kernels_compile_for_the_v5e_as_a_sublayer_runs_them(
+        one_chip, monkeypatch, N, n, D, dtype):
+    """The six kernels within the VMEM limit each reckons for itself, as one
+    remat'd sublayer chains them: the maps and the read hand the streams on,
+    so their backward kernels add onto the cotangent seen so far where it
+    lies and no add over the streams is left; the re-run forward of the read
+    and of the write is dead code, the projection's runs again."""
+    from deeplearning4j_tpu.ops import hyper_connections as hk
+
+    monkeypatch.setattr(hk, "_interpret", lambda: False)
+    dt = jnp.dtype(dtype)
+    assert hk.hc_layout_ok(n, D, dt.itemsize)
+    s = lambda shape, d=dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, d, sharding=one_chip)
+    f32, m = jnp.float32, n * (n + 2)
+    args = (s((N, n * D)), s((n * D, m), f32), s((N, m), f32), s((D,), f32),
+            s((N, D)))
+
+    def normed_projection(x, P):   # its backward needs the kernel's results
+        xp, ms, x = hk.hc_project_handing_on(x, P, n)
+        return (xp * jax.lax.rsqrt(ms + 1e-6)).T, x
+
+    def loss(x, P, maps, gamma, y):
+        raw, x = jax.checkpoint(normed_projection)(x, P)
+        h, x = jax.checkpoint(lambda x, maps, gamma: hk.hc_read_handing_on(
+            x, maps, gamma, n, 1e-6))(x, maps + raw, gamma)
+        out = jax.checkpoint(lambda x, maps, y: hk.hc_write(x, maps, y, n))(
+            x, maps * raw, y + h)
+        return jnp.sum(out.astype(f32) ** 2)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    with jax.enable_x64(False):
+        text = grad.lower(*args).compile().as_text()
+    calls = {name: len(re.findall(rf"%{name}[.\d]* = ", text))
+             for name in ("hc_maps_fwd", "hc_maps_bwd", "hc_read_fwd",
+                          "hc_read_bwd", "hc_write_fwd", "hc_write_bwd")}
+    assert calls == {"hc_maps_fwd": 2, "hc_maps_bwd": 1, "hc_read_fwd": 1,
+                     "hc_read_bwd": 1, "hc_write_fwd": 1, "hc_write_bwd": 1}
+    for name, operand in (("hc_maps_bwd", 5), ("hc_read_bwd", 4)):
+        line = next(ln for ln in text.splitlines()
+                    if re.match(rf"\s*(ROOT\s+)?%{name}[.\d]* = ", ln))
+        assert (0, operand) in {(int(r), int(o))
+                                for r, o in _TIED.findall(line)}
+    streams = f"{dt.name.replace('bfloat', 'bf').replace('float', 'f')}" \
+        f"[{N},{n * D}]"
+    assert not [ln for ln in text.splitlines()
+                if re.match(rf"\s*(ROOT\s+)?%\S+ = {re.escape(streams)}\S* "
+                            r"(add|fusion)\(", ln) and "add" in ln.split("=")[0]]
+
+
 def _copies_between_adam_and_the_carry(text: str) -> int:
     """Copies of a loop's carried buffer on the way into an ``adam_update``
     call, or of such a call's result on the way into the body's root tuple
