@@ -37,6 +37,19 @@ full width of the models the repo is measured on, and checks what comes out:
   the hyper-connection block with its maps' projection and Sinkhorn in
   bfloat16.
 
+- **Leg H — the Kimi Delta Attention block, the position-free latent
+  attention block and the expert block at the published widths**
+  (``benchmarks/configs/kimi_linear_48b_a3b``, one sequence of 8192
+  positions): each as the engine runs it, in bfloat16 over float32 masters,
+  and the delta-rule block in float32 too, against the float32 plain
+  reference (the delta rule one position at a time), like leg F; the expert
+  block's input is rounded once for both sides, so both route alike. And the
+  recurrence by itself (``ops/kda.py kda_recurrence`` on the operands the
+  block hands it: bfloat16 ``q``, ``k``, ``v``, float32 decays and steps)
+  against the delta rule one position at a time, as the cell holds it before
+  it times a step. A control that has to FAIL: the recurrence with its
+  running sums, solved system and carried state rounded to bfloat16.
+
 Legs are plain functions taking sizes: ``tests/test_chip_smoke.py`` calls them
 tiny on the CPU (interpret-mode kernels); ``__main__`` runs them at full width
 and REQUIRES the chip. Run every leg: ``python chip_smoke.py``; while
@@ -63,7 +76,7 @@ import urllib.request
 
 import numpy as np
 
-LEGS = ("A", "B", "C", "D", "E", "F", "G")
+LEGS = ("A", "B", "C", "D", "E", "F", "G", "H")
 
 
 class LegFailure(AssertionError):
@@ -1375,11 +1388,162 @@ def leg_g_latent_blocks(sizes: dict | None = None, seq_len: int = 8192,
             "leg_seconds": round(time.perf_counter() - t_leg, 1)}
 
 
+# Relative L2 distances of leg H's blocks from the plain reference (my chip
+# runs, PR 36; the readings, seed by seed, are in PERF.md's findings of that
+# PR). ``R``, the recurrence by itself, takes the limits the cell holds it to
+# (the configuration's ``RECURRENCE_RTOL*``, one a result's dtype), each
+# between its sound readings and the control's, which is held there: a
+# block's other products, at one bfloat16 pass each, read as much as the
+# control adds (``K``), the recurrence alone does not.
+LEG_H_TOLERANCES = {"K": 0.026, "A": 0.02, "E": 0.015}
+LEG_H_CONTROL = "R with the recurrence's state in bfloat16 (control)"
+
+
+def _really_rounded(exact):
+    """``exact``'s result rounded to bfloat16's 8 bits of mantissa (``jax.
+    lax.reduce_precision``: the compiler cannot skip it inside a fusion)."""
+    import jax
+
+    return lambda a: jax.lax.reduce_precision(exact(a), exponent_bits=8,
+                                              mantissa_bits=7)
+
+
+def _each_held(errs: dict, label: str, limit, results: dict) -> bool:
+    """``errs`` ``{name: (distance, its limit)}`` printed as a block's line
+    and held, each distance to its own limit (to ``limit`` where a caller
+    gives one for all)."""
+    if limit is not None:
+        errs = {k: (d, limit) for k, (d, _) in errs.items()}
+    ok = all(d <= l for d, l in errs.values())     # a NaN holds nothing
+    line = {k: [float(f"{d:.3g}"), l] for k, (d, l) in errs.items()}
+    print(f"  {'ok  ' if ok else 'OVER'} {label}: [distance, limit] "
+          f"{json.dumps(line)}", flush=True)
+    results[label] = float(f"{max(d for d, _ in errs.values()):.3g}")
+    return ok
+
+
+def _recurrence_operands(cfg, sizes, params, x, dtype: str):
+    """What the plain reference's delta-rule block hands its recurrence for
+    ``params`` and ``x``: ``q``, ``k`` and ``v`` rounded to ``dtype``, as a
+    layer computing in it hands them over, ``g`` and ``beta`` float32."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def operands(p, a):
+        with jax.default_matmul_precision("highest"):
+            q, k, v, g, beta = cfg.delta_rule_operands(p, a, sizes)
+        cast = lambda t: t.astype(jnp.dtype(dtype))  # noqa: E731
+        return cast(q), cast(k), cast(v), g, beta
+
+    return operands(params, x)
+
+
+def leg_h_kimi_blocks(sizes: dict | None = None, seq_len: int = 8192,
+                      batch: int = 1, dtypes=("float32", "bfloat16"),
+                      tolerances: dict | None = None, kinds="KAER",
+                      control: bool = True, seed: int = 0,
+                      a_dtypes=("bfloat16",)) -> dict:
+    """One Kimi Delta Attention block (``K``), one latent attention block
+    without positions (``A``) and one expert block (``E``: a sigmoid router
+    256 wide, 8 a token, the 8 experts held and the shared one) as
+    ``ComputationGraph`` runs them, ``K`` in each of ``dtypes`` over float32
+    masters, against the plain reference: outputs, every parameter's
+    gradient and the input's, as leg F. ``A`` and ``E`` run in ``a_dtypes``,
+    bfloat16 alone at 8192 positions: in float32 the flash kernels' strips do
+    not fit their budget (``PERF.md`` section 7 (b)) and the XLA path's
+    ``[32, 8192, 8192]`` scores are 8 GB. With its input rounded once for both
+    sides ``E`` routes alike on both, which the whole cell's comparison cannot
+    arrange, so the router's and the routed experts' gradients are held here.
+    ``R``: the recurrence by itself, as kernel selection resolves it at these
+    shapes, on the operands the ``K`` block hands it in the last of
+    ``dtypes``, against the delta rule one position at a time: ``o`` and the
+    gradient of every operand (the configuration's ``recurrence_distances``,
+    what the cell runs on its own first delta-rule sublayer before it times a
+    step; ``tolerances["R"]``, if given, stands in for its limits).
+    ``control``: ``R`` once more with the recurrence's running sums, solved
+    system and carried state really rounded to bfloat16 (``ops/kda.py
+    _round_state``), which must not pass."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models.kimi_linear import kimi_linear_conf
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.ops import kda
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    t_leg = time.perf_counter()
+    cfg, published = _bench_config("kimi_linear_48b_a3b")
+    sizes = dict(published, **(sizes or {}))
+    tolerances = dict(LEG_H_TOLERANCES, **(tolerances or {}))
+    width = sizes["hidden_size"]
+    it = InputType.recurrent(width, seq_len)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    plain = {"K": cfg.reference_delta_attention,
+             "A": cfg.reference_latent_attention,
+             "E": cfg.reference_experts}
+    # a block's input is a normed hidden state: unit scale, rounded to
+    # bfloat16 once, so every side works on the same values
+    x = jax.random.normal(keys[0], (batch, seq_len, width), jnp.float32)
+    x = x.astype(jnp.bfloat16).astype(jnp.float32)
+    w = jax.random.normal(keys[1], (batch, seq_len, width), jnp.float32)
+    results, failed, want = {}, [], {}
+    for dtype in dtypes:
+        conf = kimi_linear_conf(dtype=dtype, **dict(
+            cfg.builder_kwargs(sizes), mixers="KA", n_dense=0))
+        layers = {"K": conf.vertices["b0K_mixer"].layer,
+                  "A": conf.vertices["b2A_mixer"].layer,
+                  "E": conf.vertices["b1E_mixer"].layer}
+        blocks = _BlockCheck(tolerances, dtype, w)
+        blocks.results = results
+        for kind in (k for k in "KAE" if k in kinds):
+            if kind in "AE" and dtype not in a_dtypes:
+                continue
+            params, program, reference = blocks.layer_block(
+                layers[kind], plain[kind], sizes, keys[2 + "KAE".index(kind)],
+                it)
+            if kind not in want:    # the same seeded weights in every dtype
+                want[kind] = jax.block_until_ready(reference(params, x))
+            t0 = time.perf_counter()
+            got = jax.block_until_ready(program(params, x))
+            label = f"{kind} in {dtype}"
+            if not blocks.held(kind, blocks.errors(kind, got, want[kind]),
+                               label):
+                failed.append(label)
+            print(f"  {label}: forward + backward with its compile in "
+                  f"{time.perf_counter() - t0:.1f}s", flush=True)
+            del params, program, reference, got
+    if "R" in kinds:
+        t0 = time.perf_counter()
+        label = f"R in {dtypes[-1]}"
+        operands = _recurrence_operands(
+            cfg, sizes, _f32(layers["K"].init_params(keys[2], it)), x,
+            dtypes[-1])
+        errs, alone = cfg.recurrence_distances(operands, sizes)
+        if not _each_held(errs, label, tolerances.get("R"), results):
+            failed.append(label)
+        if control:
+            with _replaced(kda, "_round_state", _really_rounded):
+                errs, _ = cfg.recurrence_distances(operands, sizes, alone)
+            if _each_held(errs, LEG_H_CONTROL, tolerances.get("R"), results):
+                failed.append("control: a lower-precision state passed")
+        print(f"  R: the recurrence alone, its reference and its control in "
+              f"{time.perf_counter() - t0:.1f}s", flush=True)
+    sites = {r["site"]: r["variant"] for r in ks.selection_log()
+             if r.get("mode") != "reference"}
+    print(f"  selection: {json.dumps(sites)}")
+    check("kda_recurrence" in sites or not set("KR") & set(kinds),
+          "leg H: the delta rule left no kda_recurrence selection")
+    check(not failed, f"leg H: over tolerance or control passed: {failed}")
+    return {"worst": results, "selection": sites,
+            "leg_seconds": round(time.perf_counter() - t_leg, 1)}
+
+
 # --------------------------------------------------------------------- main
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma list out of A,B,C,D,E,F,G (default: all)")
+                    help="comma list out of A,B,C,D,E,F,G,H (default: all)")
     legs = [l.strip().upper() for l in ap.parse_args(argv).legs.split(",")
             if l.strip()]
     unknown = [l for l in legs if l not in LEGS]
@@ -1432,6 +1596,7 @@ def main(argv=None) -> int:
         run("E", leg_e_four_chips)
     run("F", leg_f_hybrid_blocks)
     run("G", leg_g_latent_blocks)
+    run("H", leg_h_kimi_blocks)
 
     print(f"compile totals: {json.dumps(monitors().snapshot())}")
     print(f"compile manager: {json.dumps(_admission_state())}")

@@ -63,6 +63,7 @@ from .nn.layers.attention import (LatentAttentionLayer, LayerNormLayer,
 from .nn.layers.dense import GatedFeedForwardLayer
 from .nn.layers.hyper_connections import (HyperConnectionMapsLayer,
                                           HyperConnectionVertex)
+from .nn.layers.linear_attention import KimiDeltaAttentionLayer
 from .nn.layers.moe import DroplessExpertsLayer, MixtureOfExpertsLayer
 from .nn.layers.state_space import Mamba2Layer, RMSNormLayer
 from .nn.layers.center_loss import CenterLossOutputLayer

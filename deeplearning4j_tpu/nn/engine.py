@@ -52,6 +52,23 @@ def apply_step(loss_of, tx, loss_scale, params, opt_state):
     return loss, aux, grads, updates, new_opt, new_params
 
 
+def adam_kernel_gives_way_beside(layers) -> Optional[str]:
+    """What among ``layers`` the in-place Adam kernel gives way beside, or
+    None: ``"kda_recurrence"`` for a net with a Kimi Delta Attention layer
+    (its recurrence runs in jax.numpy, the one form it has) and a dropless
+    expert layer, whose staged program with that kernel does not return from
+    its first step on the v5e (``ops.kernel_select``'s ``optimizer`` site;
+    ``PERF.md`` section 7 (c)). Read from the net's own layers when it builds
+    its updater, so it holds however and how often a step is traced."""
+    from .layers.linear_attention import KimiDeltaAttentionLayer  # noqa: PLC0415
+    from .layers.moe import DroplessExpertsLayer  # noqa: PLC0415
+
+    kinds = {type(layer) for layer in layers}
+    if {KimiDeltaAttentionLayer, DroplessExpertsLayer} <= kinds:
+        return "kda_recurrence"
+    return None
+
+
 def _staged_dim0(arr) -> int:
     """Leading (staged-batch) dim of an array or ShapeDtypeStruct."""
     shape = getattr(arr, "shape", None)
@@ -161,6 +178,12 @@ class TrainingEngine:
         self._rnn_step_fn = None
         self._grad_stats_step = None
         self._telemetry_step = None
+
+    def _build_tx(self) -> optax.GradientTransformation:
+        """The updater of ``conf``, told what among this net's layers its
+        kernel gives way beside (:func:`adam_kernel_gives_way_beside`)."""
+        return self.conf.updater.build(beside=adam_kernel_gives_way_beside(
+            layer for _, layer, _ in self._layer_states()))
 
     def _kernel_scoped(self, fn):
         """``fn`` traced with kernel selection told whether GSPMD will

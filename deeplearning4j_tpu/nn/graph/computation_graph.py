@@ -59,7 +59,7 @@ class ComputationGraph(TrainingEngine):
                 name: self.conf.vertices[name].init_state(*vit[name])
                 for name in self._topo
             }
-            self._tx = self.conf.updater.build()
+            self._tx = self._build_tx()
             self.opt_state = self._tx.init(self.params)
             self.iteration = 0
             self._invalidate_compiled()

@@ -263,6 +263,12 @@ class LatentAttentionLayer(BaseLayer):
     :func:`yarn_inv_freq`'s, the cosines and sines scaled by
     ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``.
 
+    ``q_rank=None``: the queries take no low rank and no norm, ``[q_nope_i,
+    q_rope_i] = x W_q``. ``rotary=False``: no position enters (a stack whose
+    other mixers carry the order): the ``rope_dim`` lanes stay what they are,
+    one key part shared by every head, and ``s = (nope_dim + rope_dim) **
+    -0.5``.
+
     ``heads_held_first`` / ``heads_held_count`` (0: all ``n_heads``) name the
     heads whose ``W_qb``, ``W_kvb`` and ``W_o`` slices live here (a
     tensor-parallel rank's share); ``W_qa``, ``W_kva`` and the two norms are
@@ -279,12 +285,13 @@ class LatentAttentionLayer(BaseLayer):
     n_heads: int = 32
     heads_held_first: int = 0
     heads_held_count: int = 0     # 0: all n_heads
-    q_rank: int = 768
+    q_rank: Optional[int] = 768   # None: one W_q, no query latent
     kv_rank: int = 512
     nope_dim: int = 128
     rope_dim: int = 64
     v_dim: int = 128
     eps: float = 1e-6
+    rotary: bool = True           # False: the rope_dim lanes are not rotated
     rope_theta: float = 10000.0
     rope_factor: float = 1.0      # > 1: YaRN over rope_original_positions
     rope_original_positions: int = 4096
@@ -297,8 +304,8 @@ class LatentAttentionLayer(BaseLayer):
     init_std: float = 0.02
     rescale_layers: int = 0        # > 0: W_o at init_std / sqrt(it)
 
-    PARAM_ROLES = {"W_qb": "attention_qkv", "W_kvb": "attention_qkv",
-                   "W_o": "attention_out"}
+    PARAM_ROLES = {"W_qb": "attention_qkv", "W_q": "attention_qkv",
+                   "W_kvb": "attention_qkv", "W_o": "attention_out"}
 
     @property
     def is_recurrent(self) -> bool:
@@ -312,7 +319,7 @@ class LatentAttentionLayer(BaseLayer):
     @property
     def softmax_scale(self) -> float:
         m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim) \
-            if self.rope_mscale_all_dim else 1.0
+            if self.rope_mscale_all_dim and self.rotary else 1.0
         return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
 
     @property
@@ -337,10 +344,17 @@ class LatentAttentionLayer(BaseLayer):
         normal = jax.random.normal
         out_std = self.init_std / math.sqrt(self.rescale_layers or 1)
         qk = self.nope_dim + self.rope_dim
+        if self.q_rank is None:
+            queries = {"W_q": self.init_std * normal(ks[0], (n_in, count * qk),
+                                                     dt)}
+        else:
+            queries = {
+                "W_qa": self.init_std * normal(ks[0], (n_in, self.q_rank), dt),
+                "q_norm": jnp.ones((self.q_rank,), dt),
+                "W_qb": self.init_std * normal(
+                    ks[1], (self.q_rank, count * qk), dt)}
         return {
-            "W_qa": self.init_std * normal(ks[0], (n_in, self.q_rank), dt),
-            "q_norm": jnp.ones((self.q_rank,), dt),
-            "W_qb": self.init_std * normal(ks[1], (self.q_rank, count * qk), dt),
+            **queries,
             "W_kva": self.init_std * normal(
                 ks[2], (n_in, self.kv_rank + self.rope_dim), dt),
             "kv_norm": jnp.ones((self.kv_rank,), dt),
@@ -362,21 +376,29 @@ class LatentAttentionLayer(BaseLayer):
         dn, dr, dv = self.nope_dim, self.rope_dim, self.v_dim
         x = maybe_dropout(x, self.dropout, train, rng)
         with jax.named_scope("q_proj"):
-            c_q = rms_norm(x @ params["W_qa"], params["q_norm"], self.eps)
-            q = (c_q @ params["W_qb"]).reshape(B, T, H, dn + dr)
+            if self.q_rank is None:
+                q = x @ params["W_q"]
+            else:
+                c_q = rms_norm(x @ params["W_qa"], params["q_norm"], self.eps)
+                q = c_q @ params["W_qb"]
+            q = q.reshape(B, T, H, dn + dr)
         with jax.named_scope("kv_proj"):
             kva = x @ params["W_kva"]
             c_kv = rms_norm(kva[..., :self.kv_rank], params["kv_norm"],
                             self.eps)
             kv = (c_kv @ params["W_kvb"]).reshape(B, T, H, dn + dv)
-        with jax.named_scope("rotary"):
-            inv_freq = yarn_inv_freq(
-                dr, self.rope_theta, self.rope_factor,
-                self.rope_original_positions, self.rope_beta_fast,
-                self.rope_beta_slow)
-            q_rope = apply_rotary(q[..., dn:], inv_freq, self.rotary_magnitude)
-            k_rope = apply_rotary(kva[..., None, self.kv_rank:], inv_freq,
-                                  self.rotary_magnitude)      # [B, T, 1, dr]
+        if self.rotary:
+            with jax.named_scope("rotary"):
+                inv_freq = yarn_inv_freq(
+                    dr, self.rope_theta, self.rope_factor,
+                    self.rope_original_positions, self.rope_beta_fast,
+                    self.rope_beta_slow)
+                q_rope = apply_rotary(q[..., dn:], inv_freq,
+                                      self.rotary_magnitude)
+                k_rope = apply_rotary(kva[..., None, self.kv_rank:], inv_freq,
+                                      self.rotary_magnitude)  # [B, T, 1, dr]
+        else:
+            q_rope, k_rope = q[..., dn:], kva[..., None, self.kv_rank:]
         heads_first = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
         q_nope, q_rope = heads_first(q[..., :dn]), heads_first(q_rope)
         k_nope, v = heads_first(kv[..., :dn]), heads_first(kv[..., dn:])

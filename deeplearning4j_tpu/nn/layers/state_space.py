@@ -72,14 +72,16 @@ class RMSNormLayer(BaseLayer):
         return self._activate(y), state
 
 
-def causal_depthwise_conv(x, w, b):
+def causal_depthwise_conv(x, w, b=None):
     """``y[t] = b + sum_j w[j] * x[t - (K-1) + j]`` over ``x`` [B, T, C] with
-    zeros before the sequence's start: ``K`` shifted multiply-adds."""
+    zeros before the sequence's start: ``K`` shifted multiply-adds (no ``b``:
+    no bias)."""
     K, T = w.shape[0], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     y = b
     for j in range(K):
-        y = y + xp[:, j:j + T, :] * w[j]
+        tap = xp[:, j:j + T, :] * w[j]
+        y = tap if y is None else y + tap
     return y
 
 

@@ -133,7 +133,7 @@ class MultiLayerNetwork(TrainingEngine):
                 layer.init_state(it)
                 for layer, it in zip(self.conf.layers, input_types)
             )
-            self._tx = self.conf.updater.build()
+            self._tx = self._build_tx()
             self.opt_state = self._tx.init(self.params)
             self.iteration = 0
             self._invalidate_compiled()

@@ -150,8 +150,9 @@ def gradient_normalization(kind: str, threshold: float = 1.0) -> optax.GradientT
 # Fused optimizer update (kernel-selection site "optimizer")
 # ---------------------------------------------------------------------------
 
-def _maybe_fused_adam(sched, b1: float, b2: float,
-                      eps: float) -> optax.GradientTransformation:
+def _maybe_fused_adam(sched, b1: float, b2: float, eps: float,
+                      beside: Optional[str] = None
+                      ) -> optax.GradientTransformation:
     """optax.adam with a cost-model-guided fused fast path.
 
     ``init`` is exactly ``optax.adam``'s, so the optimizer-state pytree
@@ -161,7 +162,8 @@ def _maybe_fused_adam(sched, b1: float, b2: float,
     whole moment/bias-correct/scale chain runs as one Pallas pass per
     parameter leaf (ops.fused_adam_update — bit-matching optax's
     ``scale_by_adam`` + schedule-scale math). Any state layout this wrapper
-    does not recognize falls back to optax, never breaks.
+    does not recognize falls back to optax, never breaks. ``beside`` is
+    handed to the site with every call (:meth:`UpdaterConfig.build`).
     """
     ref = optax.adam(learning_rate=sched, b1=b1, b2=b2, eps=eps)
 
@@ -178,7 +180,7 @@ def _maybe_fused_adam(sched, b1: float, b2: float,
         n_elems = sum(int(l.size) for l in leaves)
         itemsize = max(l.dtype.itemsize for l in leaves)
         choice = select_optimizer_variant(n_elems, itemsize, "adam",
-                                          n_leaves=len(leaves))
+                                          n_leaves=len(leaves), beside=beside)
         adam_i = next((i for i, s in enumerate(state)
                        if isinstance(s, optax.ScaleByAdamState)), None)
         sched_i = next((i for i, s in enumerate(state)
@@ -255,7 +257,12 @@ class UpdaterConfig:
         return UpdaterConfig(**d)
 
     # -- build ---------------------------------------------------------------
-    def build(self) -> optax.GradientTransformation:
+    def build(self, beside: Optional[str] = None
+              ) -> optax.GradientTransformation:
+        """``beside``: what in the net that builds this updater the in-place
+        Adam kernel gives way beside (``nn.engine
+        .adam_kernel_gives_way_beside``), or None; no other updater reads
+        it."""
         sched = build_schedule(
             self.learning_rate,
             self.lr_policy,
@@ -275,7 +282,7 @@ class UpdaterConfig:
             core = optax.sgd(learning_rate=sched, momentum=self.momentum)
         elif name == "adam":
             core = _maybe_fused_adam(sched, self.beta1, self.beta2,
-                                     self.epsilon)
+                                     self.epsilon, beside)
         elif name == "adamw":
             core = optax.adamw(learning_rate=sched, b1=self.beta1, b2=self.beta2,
                                eps=self.epsilon)

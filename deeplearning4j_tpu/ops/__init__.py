@@ -273,11 +273,25 @@ def select_hyper_connection_variant(op: str, N: int, n: int, D: int,
     return kernel_select.select("hyper_connection", ctx, forced=forced)
 
 
+def select_kda_variant(B: int, T: int, H: int, K: int, V: int, chunk: int,
+                       itemsize: int) -> str:
+    """The variant of the chunked gated delta rule (Kimi Delta Attention's
+    recurrence): 'reference', the one there is, recorded with its shapes."""
+    ctx = {"B": int(B), "T": int(T), "H": int(H), "K": int(K), "V": int(V),
+           "chunk": int(chunk), "itemsize": int(itemsize)}
+    return kernel_select.select("kda_recurrence", ctx)
+
+
 def select_optimizer_variant(n_elems: int, itemsize: int, updater: str,
-                             n_leaves: int = 1) -> str:
+                             n_leaves: int = 1,
+                             beside: Optional[str] = None) -> str:
+    """``beside``: what in the caller's program the fused variant gives way
+    beside (``kernel_select``'s ``optimizer`` site says why), or None."""
     forced = "reference" if _FORCED is False else None
     ctx = {"n_elems": int(n_elems), "itemsize": int(itemsize),
            "updater": str(updater), "n_leaves": int(n_leaves)}
+    if beside:
+        ctx["beside_reference"] = str(beside)
     return kernel_select.select("optimizer", ctx, forced=forced)
 
 
@@ -296,6 +310,7 @@ __all__ = [
     "select_attention_variant",
     "select_grouped_matmul_variant",
     "select_hyper_connection_variant",
+    "select_kda_variant",
     "select_lrn_variant",
     "select_lstm_variant",
     "select_optimizer_variant",

@@ -919,6 +919,29 @@ register_site(Site(
 ))
 
 
+def _kda_reference_cost(ctx):
+    """The chunk equations' operations (two score matrices and the triangular
+    system over half a chunk on average, three products with the ``K x V``
+    state), three times for forward + backward; every operand in float32,
+    read and written about twice a pass by XLA's fusions."""
+    B, T, H, K, V, C = (ctx[k] for k in ("B", "T", "H", "K", "V", "chunk"))
+    a_token = C / 2.0 * (2 * K + 2 * V) + 3.0 * K * V
+    streams = B * T * H * (3.0 * K + 2.0 * V + 1.0)
+    return 3.0 * 2.0 * B * T * H * a_token, 4.0 * 6.0 * streams, 0.0
+
+
+register_site(Site(
+    name="kda_recurrence",
+    reference="reference",
+    preferred_fused="fused",   # none yet: Mosaic kernels kda_fwd / kda_bwd*
+    variants={
+        "reference": Variant("reference", fused=False,
+                             cost=_kda_reference_cost, unfused_bytes=True,
+                             detail=lambda ctx: {"chunk": ctx["chunk"]}),
+    },
+))
+
+
 def _opt_fused_cost(ctx):
     n, itemsize = ctx["n_elems"], ctx["itemsize"]
     # read g/m/v, write u/m/v in one pass per leaf
@@ -932,13 +955,23 @@ def _opt_reference_cost(ctx):
     return 12.0 * n, itemsize * 14.0 * n, 0.0
 
 
+# On the v5e a staged program that holds the in-place Adam kernel beside the
+# gated delta rule in its jax.numpy form and an expert layer does not return
+# from its first step: the host waits on the losses for good, on any seed,
+# though the kernel alone is bit-exact there and the program compiles
+# (PERF.md section 7 (c)). With optax's update the same program runs. Until
+# the cause is found the kernel is infeasible where the caller says what it
+# stands beside (``beside_reference``: a net says so of its own layers when
+# it builds its updater, ``nn.engine.adam_kernel_gives_way_beside``), and the
+# record shows it (``fallback``, ``infeasible``).
 register_site(Site(
     name="optimizer",
     reference="reference",
     preferred_fused="fused",
     variants={
         "fused": Variant("fused", fused=True, cost=_opt_fused_cost,
-                         available=lambda ctx: ctx.get("updater") == "adam"),
+                         available=lambda ctx: ctx.get("updater") == "adam"
+                         and not ctx.get("beside_reference")),
         "reference": Variant("reference", fused=False,
                              cost=_opt_reference_cost, unfused_bytes=True),
     },

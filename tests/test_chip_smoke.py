@@ -154,6 +154,40 @@ def test_leg_g_fails_when_a_lower_precision_control_passes():
             tolerances=tol, kinds="H")
 
 
+def _tiny_kimi_sizes():
+    import json
+
+    with open(os.path.join(REPO, "tests", "benchmark_harness", "presets",
+                           "configs", "kimi_linear_48b_a3b.json")) as f:
+        return json.load(f)["sizes"]
+
+
+@pytest.mark.parametrize("mode", ["auto", "fused"])
+def test_leg_h_kimi_blocks_tiny(monkeypatch, mode):
+    # float32 on the CPU, so the tolerances are rounding's; "fused" takes the
+    # flash kernels (no query rank, an un-rotated shared key part) in
+    # interpret mode; the recurrence has its jax.numpy under every mode
+    monkeypatch.setenv("DL4JTPU_KERNELS", mode)
+    res = chip_smoke.leg_h_kimi_blocks(
+        _tiny_kimi_sizes(), seq_len=22, batch=2, dtypes=("float32",),
+        a_dtypes=("float32",),
+        tolerances={"K": 2e-4, "A": 2e-4, "E": 2e-4, "R": 2e-4})
+    control = chip_smoke.LEG_H_CONTROL
+    assert set(res["worst"]) == {"K in float32", "A in float32",
+                                 "E in float32", "R in float32", control}
+    assert res["worst"][control] > 100 * res["worst"]["R in float32"]
+    assert res["selection"]["kda_recurrence"] == "reference"
+    if mode == "fused":
+        assert res["selection"]["attention"] == "flash"
+
+
+def test_leg_h_fails_when_the_lower_precision_control_passes():
+    with pytest.raises(chip_smoke.LegFailure, match="control"):
+        chip_smoke.leg_h_kimi_blocks(
+            _tiny_kimi_sizes(), seq_len=16, batch=1, dtypes=("float32",),
+            tolerances={"K": 0.9, "A": 0.9, "R": 0.9}, kinds="KR")
+
+
 def test_main_refuses_to_pass_without_a_chip():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
