@@ -55,11 +55,14 @@ def apply_step(loss_of, tx, loss_scale, params, opt_state):
 def adam_kernel_gives_way_beside(layers) -> Optional[str]:
     """What among ``layers`` the in-place Adam kernel gives way beside, or
     None: ``"kda_recurrence"`` for a net with a Kimi Delta Attention layer
-    (its recurrence runs in jax.numpy, the one form it has) and a dropless
-    expert layer, whose staged program with that kernel does not return from
-    its first step on the v5e (``ops.kernel_select``'s ``optimizer`` site;
-    ``PERF.md`` section 7 (c)). Read from the net's own layers when it builds
-    its updater, so it holds however and how often a step is traced."""
+    and a dropless expert layer, whose staged program with that kernel did
+    not return from its first step on the v5e while the recurrence ran in
+    jax.numpy, the one form it had then (``ops.kernel_select``'s
+    ``optimizer`` site; ``PERF.md`` section 7 (c)). With the recurrence's own
+    kernels (``ops/kda.py``, PR 37) the gate stands as it stood: letting the
+    Adam kernel back in is a change, and a pair of runs, of its own. Read
+    from the net's own layers when it builds its updater, so it holds however
+    and how often a step is traced."""
     from .layers.linear_attention import KimiDeltaAttentionLayer  # noqa: PLC0415
     from .layers.moe import DroplessExpertsLayer  # noqa: PLC0415
 

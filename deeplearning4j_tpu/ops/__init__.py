@@ -275,11 +275,12 @@ def select_hyper_connection_variant(op: str, N: int, n: int, D: int,
 
 def select_kda_variant(B: int, T: int, H: int, K: int, V: int, chunk: int,
                        itemsize: int) -> str:
-    """The variant of the chunked gated delta rule (Kimi Delta Attention's
-    recurrence): 'reference', the one there is, recorded with its shapes."""
+    """'fused' | 'reference' for one chunked gated delta rule (Kimi Delta
+    Attention's recurrence)."""
+    forced = "reference" if _FORCED is False else None
     ctx = {"B": int(B), "T": int(T), "H": int(H), "K": int(K), "V": int(V),
            "chunk": int(chunk), "itemsize": int(itemsize)}
-    return kernel_select.select("kda_recurrence", ctx)
+    return kernel_select.select("kda_recurrence", ctx, forced=forced)
 
 
 def select_optimizer_variant(n_elems: int, itemsize: int, updater: str,

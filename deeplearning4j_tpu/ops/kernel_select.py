@@ -919,22 +919,57 @@ register_site(Site(
 ))
 
 
-def _kda_reference_cost(ctx):
+def _kda_flops(ctx) -> float:
     """The chunk equations' operations (two score matrices and the triangular
     system over half a chunk on average, three products with the ``K x V``
-    state), three times for forward + backward; every operand in float32,
-    read and written about twice a pass by XLA's fusions."""
+    state), three times for forward + backward."""
     B, T, H, K, V, C = (ctx[k] for k in ("B", "T", "H", "K", "V", "chunk"))
     a_token = C / 2.0 * (2 * K + 2 * V) + 3.0 * K * V
+    return 3.0 * 2.0 * B * T * H * a_token
+
+
+def _kda_reference_cost(ctx):
+    """With no loop over the chunks the jax.numpy form also builds a chunk's
+    map (``M`` [K, K], ``Z`` [K, V]) and composes the maps in the two sweeps
+    of an associative scan (``[K, K] x [K, K + V]`` a chunk and sweep); every
+    operand in float32, read and written about twice a pass by XLA's
+    fusions."""
+    B, T, H, K, V, C = (ctx[k] for k in ("B", "T", "H", "K", "V", "chunk"))
+    scan = K * (K + V) * (1.0 + 2.0 * K / C)
     streams = B * T * H * (3.0 * K + 2.0 * V + 1.0)
-    return 3.0 * 2.0 * B * T * H * a_token, 4.0 * 6.0 * streams, 0.0
+    return (_kda_flops(ctx) + 3.0 * 2.0 * B * T * H * scan,
+            4.0 * 6.0 * streams, 0.0)
+
+
+def _kda_fused_cost(ctx):
+    # the same operations without the maps; forward, and twice more with the
+    # cotangents: v and o at the item size, q, k and the sums of the
+    # log-decays in float32, a chunk's two [C, C] matrices; the states that
+    # enter the chunks (float32) written once and read once
+    B, T, H, K, V, C = (ctx[k] for k in ("B", "T", "H", "K", "V", "chunk"))
+    streams = 3.0 * (ctx["itemsize"] * 2.0 * V + 4.0 * 3.0 * K + 4.0 * 2.0 * C)
+    states = 4.0 * 2.0 * K * V / C
+    return (_kda_flops(ctx), B * T * H * (streams + states), 2 * _LAUNCH_S)
+
+
+def _kda_fits_ctx(ctx) -> bool:
+    from .kda import kda_fits, kda_layout_ok  # noqa: PLC0415
+
+    # float64 (the gradient checks) keeps the jax.numpy: the kernels' state
+    # and products are float32
+    return ctx["itemsize"] <= 4 and kda_layout_ok(
+        ctx["chunk"], ctx["K"], ctx["V"]) and kda_fits(
+        ctx["chunk"], ctx["K"], ctx["V"], ctx["itemsize"])
 
 
 register_site(Site(
     name="kda_recurrence",
     reference="reference",
-    preferred_fused="fused",   # none yet: Mosaic kernels kda_fwd / kda_bwd*
+    preferred_fused="fused",
     variants={
+        "fused": Variant("fused", fused=True, cost=_kda_fused_cost,
+                         available=_kda_fits_ctx,
+                         detail=lambda ctx: {"chunk": ctx["chunk"]}),
         "reference": Variant("reference", fused=False,
                              cost=_kda_reference_cost, unfused_bytes=True,
                              detail=lambda ctx: {"chunk": ctx["chunk"]}),

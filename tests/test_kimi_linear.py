@@ -185,10 +185,134 @@ def test_the_site_records_the_chunk_and_the_shapes_once():
     assert rec["variant"] == "reference" and rec["chunk"] == 8
     assert rec["ctx"] == {"B": 2, "T": 20, "H": 2, "K": 8, "V": 8,
                           "chunk": 8, "itemsize": 4}
-    # pinned to its jax.numpy under every mode: there is no other variant yet
+    # its jax.numpy under every mode: the kernels do not tile K = 8
     for mode in ("fused", "reference"):
         with ks.forced_mode(mode):
             assert ks.select("kda_recurrence", rec["ctx"]) == "reference"
+
+
+# ----------------------------------------------- the kernels (interpret mode)
+def fused_inputs(case, dtype):
+    """``(q, k, v, g, beta)`` at the widths the kernels tile (K = V = 128)
+    for one case of the recurrence; ``q``, ``k``, ``v`` in ``dtype``, ``g``
+    and ``beta`` float32, as a layer hands them over."""
+    T, B, H, decay, shift = {
+        "several chunks": (192, 1, 2, 0.1, 0.0),
+        "one chunk": (64, 1, 1, 0.1, 0.0),
+        "a padded tail": (100, 1, 1, 0.1, 0.0),
+        "strong decays": (128, 1, 1, 3.0, 4.0),
+        "no decay": (128, 1, 1, 0.0, 0.0),
+        "aligned keys": (84, 1, 1, 0.001, 5.0),
+        "a masked position": (128, 1, 1, 0.1, 0.0),
+        "two sequences": (128, 2, 1, 0.1, 0.0),
+    }[case]
+    q, k, v, g, beta = delta_inputs(T, decay, shift, B=B, H=H, K=128, V=128,
+                                    dtype=jnp.float32)
+    if case == "aligned keys":      # 20 updates along nearly one direction
+        base = k[:, :1]
+        unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+        near = unit(base + 0.05 * k)
+        k = k.at[:, 64:84].set(near[:, 64:84])
+        q = q.at[:, 64:84].set(unit(base + 0.05 * q)[:, 64:84])
+    if case == "a masked position":  # as the layer masks: g = 0, beta = 0
+        keep = jnp.ones((T,), jnp.float32).at[jnp.array([5, 63, 64, 100])].set(0.0)
+        g, beta = g * keep[None, :, None, None], beta * keep[None, :, None]
+    cast = lambda a: a.astype(jnp.dtype(dtype))  # noqa: E731
+    return cast(q), cast(k), cast(v), g, beta
+
+
+FUSED_CASES = ["several chunks", "one chunk", "a padded tail",
+               "strong decays", "no decay", "aligned keys",
+               "a masked position", "two sequences"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", FUSED_CASES)
+def test_the_kernels_match_the_recurrence(case, dtype):
+    """``kda_fwd`` / ``kda_bwd`` in interpret mode against the recurrence one
+    position at a time: ``o`` and the cotangent of every operand."""
+    args = fused_inputs(case, dtype)
+    if case == "strong decays":     # a chunk's summed log-decay past -88
+        assert float(jnp.max(jnp.sum(args[3][:, :64], 1))) < -88.0
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape, jnp.float32)
+    w = w.astype(args[2].dtype).astype(jnp.float32)   # once, for both sides
+
+    def both(fn, a):
+        out, pull = jax.vjp(lambda *b: fn(*b, scale=0.5), *a)
+        return (out,) + pull(w.astype(out.dtype))
+
+    want = both(kda.kda_reference, tuple(a.astype(jnp.float32) for a in args))
+    got = both(lambda *a, scale: kda.kda_fused(*a, chunk=64, scale=scale),
+               args)
+    assert got[0].dtype == args[2].dtype and got[4].dtype == jnp.float32
+    for name, a, b in zip(("o", "d_q", "d_k", "d_v", "d_g", "d_beta"),
+                          got, want):
+        # float32 results to rounding; one that comes back in bfloat16 to
+        # what rounding it costs (2^-9 of the largest value)
+        tol = 6e-3 if a.dtype == jnp.bfloat16 else 1e-4
+        assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32)))), name
+        close(a.astype(jnp.float32), b, tol)
+    if case == "a masked position":     # the state passes it unchanged
+        q, k, v, g, beta = args
+        gone = kda.kda_fused(q, k, v.at[:, 5].set(100.0), g, beta, chunk=64)
+        kept = kda.kda_fused(q, k, v, g, beta, chunk=64)
+        close(gone[:, 6:].astype(jnp.float32), kept[:, 6:].astype(jnp.float32),
+              1e-6)
+
+
+def test_the_site_takes_the_kernels_where_they_tile():
+    """``fused`` at the published head (K = V = 128, chunks of 64) when fused
+    variants compete, the jax.numpy at the tests' small shapes, off a lane
+    tile and under ``mode == "reference"``; the record carries what
+    ``kda_recurrence_roofline.shapes_of`` reads."""
+    published = {"B": 1, "T": 8192, "H": 32, "K": 128, "V": 128, "chunk": 64,
+                 "itemsize": 2}
+    assert ks.select("kda_recurrence", published) == "reference"   # the CPU
+    ks.set_force_available(True)
+    try:
+        assert ks.select("kda_recurrence", published) == "fused"
+        for off in ({"K": 8, "V": 8}, {"K": 64}, {"V": 192}, {"chunk": 4},
+                    {"chunk": 48}, {"chunk": 96}, {"itemsize": 8}):
+            assert ks.select("kda_recurrence",
+                             dict(published, **off)) == "reference", off
+        with ks.forced_mode("reference"):
+            assert ks.select("kda_recurrence", published) == "reference"
+        with ks.partitioned_program():
+            assert ks.select("kda_recurrence", published) == "reference"
+    finally:
+        ks.set_force_available(False)
+    ks.reset()
+    # one chunk is too little for the cost model: asked for, the kernels run
+    args = fused_inputs("one chunk", "bfloat16")
+    with ks.forced_mode("fused"):
+        out = jax.jit(lambda *a: kda.kda_recurrence(*a, chunk=64))(*args)
+    close(out.astype(jnp.float32),
+          kda.kda_chunked(*args, chunk=64).astype(jnp.float32), 1e-2)
+    (rec,) = [r for r in ks.selection_log() if r["site"] == "kda_recurrence"]
+    assert rec["variant"] == "fused" and rec["chunk"] == 64
+    assert rec["ctx"] == {"B": 1, "T": 64, "H": 1, "K": 128, "V": 128,
+                          "chunk": 64, "itemsize": 2}
+
+
+def test_the_fused_program_has_no_scan_over_chunk_maps():
+    """No ``[K, K] x [K, K]`` product (the chunk maps ``M`` composed by
+    ``lax.associative_scan``) is in the ``fused`` program's text, forward or
+    backward; the jax.numpy form, the control, has them. ``V`` is 256 so that
+    a product with the state is no such shape."""
+    q, k, _, g, beta = delta_inputs(256, 0.1, B=1, H=1, K=128, V=128,
+                                    dtype=jnp.float32)
+    v = jnp.ones((1, 256, 1, 256), jnp.float32)
+    square = re.compile(r"dot_general.*\(tensor<(?:\d+x)*128x128xf32>, "
+                        r"tensor<(?:\d+x)*128x128xf32>\)")
+
+    def text(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(fn(*a, chunk=64)), argnums=(0, 1, 2, 3, 4))
+        ).lower(q, k, v, g, beta).as_text()
+
+    assert square.search(text(kda.kda_chunked))
+    fused = text(kda.kda_fused)
+    assert not square.search(fused) and "dot_general" in fused
 
 
 def test_the_recurrence_runs_under_its_named_scope():

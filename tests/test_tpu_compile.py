@@ -106,6 +106,39 @@ def test_ssd_scan_kernels_compile_for_the_v5e_at_the_published_shapes(
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
+@pytest.mark.parametrize("B,T,H,K,V,chunk,dtype", [
+    (1, 8192, 32, 128, 128, 64, "bfloat16"),   # kimi_linear_train_1chip
+    (1, 8192, 32, 128, 128, 64, "float32"),    # chip_smoke's f32
+    (2, 1000, 4, 128, 128, 64, "bfloat16"),    # T padded to whole chunks
+    (1, 512, 2, 128, 256, 32, "bfloat16"),     # values two lane tiles wide
+])
+def test_kda_kernels_compile_for_the_v5e_at_the_published_shapes(
+        one_chip, monkeypatch, B, T, H, K, V, chunk, dtype):
+    """``kda_fwd`` / ``kda_bwd`` with their float32 products at ``highest``
+    through Mosaic: a compile that succeeds held them inside the
+    ``vmem_limit_bytes`` their footprint states."""
+    from deeplearning4j_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    dt = jnp.dtype(dtype)
+    assert kda.kda_layout_ok(chunk, K, V)
+    assert kda.kda_fits(chunk, K, V, dt.itemsize)
+    s = lambda shape, d=dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, d, sharding=one_chip)
+    args = (s((B, T, H, K)), s((B, T, H, K)), s((B, T, H, V)),
+            s((B, T, H, K), jnp.float32), s((B, T, H), jnp.float32))
+
+    def loss(*a):
+        o = kda.kda_fused(*a, chunk=chunk, scale=K ** -0.5)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    with jax.enable_x64(False):
+        text = grad.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "kda_fwd" in text and "kda_bwd" in text
+
+
 @pytest.mark.parametrize("M,K,N,E,dtype", [
     (14336, 2688, 1856, 8, "bfloat16"),   # nemotron3_nano_train_1chip: up
     (14336, 1856, 2688, 8, "bfloat16"),   # ... and down
