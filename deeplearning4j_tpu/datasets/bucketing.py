@@ -41,6 +41,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ..runtime.compile_manager import next_pow2
+from ..telemetry.spans import identified, span
 
 __all__ = [
     "PaddedWindow",
@@ -197,6 +198,14 @@ class PaddedWindow:
     features_masks: Optional[List[Optional[np.ndarray]]]
     labels_masks: Optional[List[Optional[np.ndarray]]]
     n_real: int
+    ordinal: int = 0  # which window of its stager (of its epoch, in ``fit``)
+
+    def nbytes(self) -> int:
+        """Host bytes of everything staged, dummy slots and masks included:
+        what a ``device_put`` of the window moves."""
+        return sum(int(a.nbytes) for a in (
+            self.features + self.labels + (self.features_masks or [])
+            + (self.labels_masks or [])) if a is not None)
 
 
 class BucketedStager:
@@ -298,6 +307,19 @@ class BucketedStager:
 
     # -------------------------------------------------------------- window
     def _build_window(self, group: List[_Member], target_b: int,
+                      target_t: Optional[int]) -> PaddedWindow:
+        """Pad and stack ``group``; on the caller's thread, under the span
+        ``dl4j.fit.stack`` (the copy of every batch into the window)."""
+        ordinal = self._padding["windows"]
+        with identified(window=ordinal), span(
+                "dl4j.fit.stack", batches=len(group),
+                padded_rows=sum(target_b - m.batch for m in group)) as stack:
+            window = self._stack_window(group, target_b, target_t)
+            window.ordinal = ordinal
+            stack.args["bytes"] = window.nbytes()
+        return window
+
+    def _stack_window(self, group: List[_Member], target_b: int,
                       target_t: Optional[int]) -> PaddedWindow:
         any_pad = any(
             m.batch != target_b

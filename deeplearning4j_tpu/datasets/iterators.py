@@ -359,8 +359,9 @@ class AsyncDataSetIterator(DataSetIterator):
         self.base.reset()
 
     def __iter__(self):
-        # the producer-thread/sentinel/drain machinery lives once, in
-        # utils.collections.AsyncIterator (the generic reference sibling)
+        # the producer-thread/sentinel/drain machinery (and its counters)
+        # lives once, in utils.collections.AsyncIterator (the generic
+        # reference sibling)
         from ..utils.collections import AsyncIterator  # noqa: PLC0415
 
         yield from AsyncIterator(self.base, queue_size=self.queue_size)

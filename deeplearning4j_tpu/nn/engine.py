@@ -27,7 +27,8 @@ import numpy as np
 import optax
 
 from ..telemetry import device as _tdev
-from ..telemetry.spans import span
+from ..telemetry.registry import get_registry
+from ..telemetry.spans import identified, span
 from .updaters import (optimizer_update, scaled_loss, unscale_grads,
                        unscale_loss)
 
@@ -113,6 +114,40 @@ def _shell(a):
     return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
 
 
+def _host_bytes(tree) -> int:
+    """Bytes of the host (numpy) arrays in ``tree``: what a jitted call
+    handed them has to bring to the device before it can run."""
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree)
+               if isinstance(a, np.ndarray))
+
+
+class _BatchWaits:
+    """One epoch's iterator as ``fit`` consumes it: every wait for the next
+    item is a ``dl4j.fit.next_batch`` span on the consuming thread (for an
+    :class:`AsyncDataSetIterator` the wait on its queue; for a plain
+    iterator the production itself), the end of the stream included.
+    ``batch`` is the ordinal of the item waited for; ``window`` the staged
+    window it will fill, which the staged loop moves on (None on the
+    per-batch path)."""
+
+    def __init__(self, it):
+        self._it = iter(it)
+        self.batches = 0
+        self.window: Optional[int] = None
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        ids = {"batch": self.batches}
+        if self.window is not None:
+            ids["window"] = self.window
+        with identified(**ids), span("dl4j.fit.next_batch"):
+            item = next(self._it)
+        self.batches += 1
+        return item
+
+
 def _zero_counters(state):
     """``state`` (a tuple by layer or a dict by vertex) with every counting
     layer's counters at zero, entries in their own order; the state itself,
@@ -162,6 +197,7 @@ class TrainingEngine:
         self._telemetry_step = None
         self._cm_token = None  # compile-manager owner token (one per init())
         self.staged_steps_total = 0  # optimizer steps run via fit_on_device
+        self._host_bytes_counters: dict = {}  # by path, looked up once
 
     def _invalidate_compiled(self) -> None:
         """Retire every executable built for the previous generation (the
@@ -590,8 +626,14 @@ class TrainingEngine:
 
         ``stage_on_device=K`` (TPU fast path): buffer K batches, stack them
         in HBM, and run the whole window as ONE dispatch via
-        :meth:`fit_on_device`, double-buffered (window i+1's host→device
-        transfer overlaps window i's compute). With ``bucketing`` (default)
+        :meth:`fit_on_device`, double-buffered. What the double buffer
+        covers is the transfer: window i+1's ``device_put`` is enqueued
+        before window i is launched, so it runs beside window i's compute.
+        What it does not cover is the stack: window i+1 is copied together
+        from its K batches on this thread (``np.stack``, the span
+        ``dl4j.fit.stack``) BEFORE window i is launched, and the launch
+        returns only with window i's losses, so the device has nothing
+        queued while the host stacks. With ``bucketing`` (default)
         ragged batches stay on the staged path: trailing partial batches pad
         up with masked zero rows, variable sequence lengths pad to
         power-of-two time buckets, and a trailing partial window runs with a
@@ -602,6 +644,11 @@ class TrainingEngine:
         legacy contract: only full uniform groups stage (bit-identical RNG
         chain), everything ragged trains per-batch. Gradient-stats listeners
         and TBPTT disable staging since the on-device loop can't serve them.
+
+        Every epoch is one ``dl4j.fit.epoch`` span on the calling thread,
+        with the iterator waits (``dl4j.fit.next_batch``), the windows'
+        ``stack`` / ``put`` / ``dispatch`` or the batches' ``step`` /
+        ``listeners`` under it (docs/observability.md has the table).
         """
         from ..datasets.iterators import AsyncDataSetIterator, as_iterator
 
@@ -635,11 +682,18 @@ class TrainingEngine:
                 it.reset()  # reference resets the iterator each epoch (fit:917)
             if getattr(it, "prefetch_supported", False):
                 it = AsyncDataSetIterator(it)
-            if stage > 1:
-                self._fit_epoch_staged(it, stage, bucketing)
-            else:
-                for ds in it:
-                    self._fit_batch(ds)
+            with span("dl4j.fit.epoch", net=self._KIND, epoch=self.epoch,
+                      stage=stage) as epoch:
+                waits = _BatchWaits(it)
+                if stage > 1:
+                    waits.window = 0
+                    self._fit_epoch_staged(waits, stage, bucketing)
+                else:
+                    for batch, ds in enumerate(waits):
+                        with identified(batch=batch):
+                            self._fit_batch(ds)
+                epoch.args.update(batches=waits.batches,
+                                  windows=waits.window or 0)
             self.epoch += 1
             for lst in self.listeners:
                 if hasattr(lst, "on_epoch_end"):
@@ -648,13 +702,29 @@ class TrainingEngine:
             self.telemetry.flush()  # drain a partial K-window at fit end
         return self
 
+    def _count_host_bytes(self, path: str, nbytes: int) -> None:
+        """``dl4jtpu_fit_host_bytes_total{path}`` of the default registry
+        (``staged``: a window's ``device_put``; ``per_batch``: a jitted
+        step's host arguments), looked up once a net and path: a batch of a
+        small net is a few hundred microseconds of host work."""
+        counter = self._host_bytes_counters.get(path)
+        if counter is None:
+            counter = self._host_bytes_counters[path] = get_registry().counter(
+                "dl4jtpu_fit_host_bytes_total",
+                "host bytes fit() handed to the device (a window's "
+                "device_put, a step's host arguments)",
+                labelnames=("path",)).labels(path=path)
+        counter.inc(nbytes)
+
     def _fit_epoch_staged(self, it, stage: int, bucketing: bool = True) -> None:
         """Stage windows of ``stage`` batches per fit_on_device dispatch via
-        the bucketed planner (datasets/bucketing.py), double-buffered: while
-        window i executes on device, window i+1 is host-stacked and
-        ``jax.device_put`` (async) so its H2D transfer overlaps compute.
-        Unstageable batches train through the ordinary per-batch step, in
-        stream order."""
+        the bucketed planner (datasets/bucketing.py), double-buffered:
+        window i+1 is host-stacked (``dl4j.fit.stack``) and its
+        ``jax.device_put`` enqueued (``dl4j.fit.put``, async) before window i
+        is dispatched, so its H2D transfer overlaps window i's compute; the
+        stack itself does not (``fit``'s docstring). Unstageable batches
+        train through the ordinary per-batch step, in stream order. ``it``:
+        the epoch's :class:`_BatchWaits`."""
         from ..datasets.bucketing import BucketedStager
 
         stager = BucketedStager(stage, bucketing=bucketing,
@@ -667,22 +737,32 @@ class TrainingEngine:
 
         def to_device(win):
             # async: overlaps the pending dispatch
-            (win.features, win.labels, win.features_masks,
-             win.labels_masks) = jax.tree_util.tree_map(
-                jax.device_put, (win.features, win.labels,
-                                 win.features_masks, win.labels_masks))
+            nbytes = win.nbytes()
+            with identified(window=win.ordinal), span("dl4j.fit.put",
+                                                      bytes=nbytes):
+                (win.features, win.labels, win.features_masks,
+                 win.labels_masks) = jax.tree_util.tree_map(
+                    jax.device_put, (win.features, win.labels,
+                                     win.features_masks, win.labels_masks))
+            self._count_host_bytes("staged", nbytes)
             return win
 
         def dispatch(win):
             xs, ys, fm, lm = self._from_lists(
                 win.features, win.labels, win.features_masks,
                 win.labels_masks)
-            self.fit_on_device(xs, ys, steps=win.n_real, features_masks=fm,
-                               labels_masks=lm, real_batches=win.n_real)
+            # fit_on_device's spans are this window's without knowing of it
+            with identified(window=win.ordinal):
+                self.fit_on_device(xs, ys, steps=win.n_real,
+                                   features_masks=fm, labels_masks=lm,
+                                   real_batches=win.n_real)
 
         pending = None
+        trained = 0  # the plan keeps stream order: ordinals count what came out
         for kind, payload in stager.plan(it, normalize):
             if kind == "window":
+                it.window = payload.ordinal + 1  # what the next waits fill
+                trained += payload.n_real
                 staged = to_device(payload)
                 if pending is not None:
                     dispatch(pending)
@@ -691,7 +771,9 @@ class TrainingEngine:
                 if pending is not None:
                     dispatch(pending)
                     pending = None
-                self._fit_batch(payload)
+                with identified(batch=trained):
+                    self._fit_batch(payload)
+                trained += 1
         if pending is not None:
             dispatch(pending)
         self._check_padding_waste(stager)
@@ -735,31 +817,39 @@ class TrainingEngine:
         step_args = (x, y, step_key, lm, fm)
         tel = self.telemetry
         mvec = None
-        if self._wants_grad_stats():
-            if self._grad_stats_step is None:
-                self._grad_stats_step = self._step_callable("grad_stats")
-            (self.params, self.opt_state, self.state, loss,
-             self._last_grads, self._last_updates) = self._grad_stats_step(
-                self.params, self.opt_state, self.state, *step_args)
-            if tel is not None:
-                # grads already left the program for StatsListener; reduce
-                # them eagerly (async dispatch, still no host sync)
-                mvec = _tdev.step_stats(loss, self._last_grads)
-        elif tel is not None:
-            if self._telemetry_step is None:
-                self._telemetry_step = self._step_callable("telemetry")
-            (self.params, self.opt_state, self.state, loss, mvec) = \
-                self._telemetry_step(
+        nbytes = _host_bytes(step_args)
+        # the jitted step's call: the enqueue, and the implicit transfer of
+        # whatever it was handed as host arrays (never a sync)
+        with span("dl4j.fit.step", bytes=nbytes):
+            if self._wants_grad_stats():
+                if self._grad_stats_step is None:
+                    self._grad_stats_step = self._step_callable("grad_stats")
+                (self.params, self.opt_state, self.state, loss,
+                 self._last_grads, self._last_updates) = self._grad_stats_step(
                     self.params, self.opt_state, self.state, *step_args)
-        else:
-            self.params, self.opt_state, self.state, loss = self._train_step(
-                self.params, self.opt_state, self.state, *step_args)
+                if tel is not None:
+                    # grads already left the program for StatsListener; reduce
+                    # them eagerly (async dispatch, still no host sync)
+                    mvec = _tdev.step_stats(loss, self._last_grads)
+            elif tel is not None:
+                if self._telemetry_step is None:
+                    self._telemetry_step = self._step_callable("telemetry")
+                (self.params, self.opt_state, self.state, loss, mvec) = \
+                    self._telemetry_step(
+                        self.params, self.opt_state, self.state, *step_args)
+            else:
+                (self.params, self.opt_state, self.state,
+                 loss) = self._train_step(
+                    self.params, self.opt_state, self.state, *step_args)
+        self._count_host_bytes("per_batch", nbytes)
         self._last_loss = loss
         self.iteration += 1
         if tel is not None and mvec is not None:
             tel.on_step(self.iteration, mvec)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, loss)
+        if self.listeners:  # a span only where there is something to time
+            with span("dl4j.fit.listeners"):
+                for lst in self.listeners:
+                    lst.iteration_done(self, self.iteration, loss)
         # listeners have copied what they need; don't pin ~2x model size of
         # gradient+update buffers in HBM until the next instrumented step
         self._last_grads = None
@@ -848,19 +938,24 @@ class TrainingEngine:
             lm, fm = jax.tree_util.tree_map(
                 lambda m: np.asarray(m)[:, seg], (labels_mask, features_mask))
             self._rng, step_key = jax.random.split(self._rng)
-            (self.params, self.opt_state, self.state, rnn, loss) = self._tbptt_step(
-                self.params, self.opt_state, self.state, rnn,
-                self._time_slice(x, seg), self._time_slice(y, seg), step_key,
-                lm, fm,
-            )
+            step_args = (self._time_slice(x, seg), self._time_slice(y, seg),
+                         step_key, lm, fm)
+            nbytes = _host_bytes(step_args)
+            with span("dl4j.fit.step", bytes=nbytes, segment=t0 // L):
+                (self.params, self.opt_state, self.state, rnn,
+                 loss) = self._tbptt_step(
+                    self.params, self.opt_state, self.state, rnn, *step_args)
+            self._count_host_bytes("per_batch", nbytes)
             self._last_loss = loss
             self.iteration += 1
             if self.telemetry is not None:
                 # TBPTT's step returns no gradient view; record loss +
                 # finiteness (grad norm reads 0 on this path)
                 self.telemetry.on_step(self.iteration, _tdev.step_stats(loss))
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration, loss)
+            if self.listeners:
+                with span("dl4j.fit.listeners", segment=t0 // L):
+                    for lst in self.listeners:
+                        lst.iteration_done(self, self.iteration, loss)
 
     # ------------------------------------------------------------------ misc
     def clone(self):

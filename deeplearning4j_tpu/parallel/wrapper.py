@@ -154,14 +154,13 @@ class ParallelWrapper:
         self._sync_ready = False
         # Shared instrumentation path (profiler.StepTimer): the same
         # data/step/average phases feed the TrainingMaster's phase stats, the
-        # StatsListener records (UI system page), the bench breakdown AND the
-        # telemetry registry (dl4jtpu_phase_seconds at /metrics) —
+        # StatsListener records (UI system page), the bench breakdown AND,
+        # as the spans dl4j.parallel_wrapper.<phase>, the telemetry registry
+        # (dl4jtpu_span_seconds at /metrics) —
         # reference: ParameterAveragingTrainingWorkerStats per-phase events.
         from ..profiler import StepTimer  # noqa: PLC0415
-        from ..telemetry import get_registry  # noqa: PLC0415
 
-        self.timer = StepTimer(registry=get_registry(),
-                               component="parallel_wrapper")
+        self.timer = StepTimer(component="parallel_wrapper")
         net._phase_timer = self.timer
 
     # ------------------------------------------------------------- sync mode

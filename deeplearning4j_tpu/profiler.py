@@ -66,24 +66,17 @@ class StepTimer:
     ``with timer.phase("data"): ...`` or ``timer.tick("data")`` /
     ``timer.tock()`` for loop-structured code.
 
-    ``registry``: a ``telemetry.MetricsRegistry`` — every recorded phase
-    duration is also observed into ``dl4jtpu_phase_seconds{phase=...,
-    component=...}``, so per-phase timing is scrapeable at ``/metrics``
-    alongside the breakdown() dict the UI/bench already consume.
+    ``component``: ``phase()`` is also the span ``dl4j.<component>.<name>``,
+    so every phase's seconds and count are scrapeable at ``/metrics`` under
+    ``dl4jtpu_span_seconds{name="dl4j.<component>.<name>"}`` alongside the
+    breakdown() dict the UI/bench already consume.
     """
 
-    def __init__(self, registry=None, component: str = "") -> None:
+    def __init__(self, component: str = "") -> None:
         self.totals: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
         self._open: Optional[tuple] = None
         self._component = component
-        self._phase_hist = None
-        if registry is not None:
-            self._phase_hist = registry.histogram(
-                "dl4jtpu_phase_seconds",
-                "per-phase wall time (data/step/sync/average)",
-                labelnames=("component", "phase"),
-            )
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -111,10 +104,6 @@ class StepTimer:
     def add(self, name: str, seconds: float) -> None:
         self.totals[name] = self.totals.get(name, 0.0) + seconds
         self.counts[name] = self.counts.get(name, 0) + 1
-        if self._phase_hist is not None:
-            self._phase_hist.labels(
-                component=self._component, phase=name
-            ).observe(seconds)
 
     def breakdown(self) -> Dict[str, dict]:
         """{phase: {total_s, count, mean_ms}} — JSON-ready."""

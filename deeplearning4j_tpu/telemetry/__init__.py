@@ -70,7 +70,7 @@ from .registry import (
 )
 from .session import Telemetry
 from .slo import SLOMonitor, get_slo_monitor, set_slo_monitor
-from .spans import Span, SpanRecorder, get_recorder, span
+from .spans import Span, SpanRecorder, get_recorder, identified, span
 from .tracing import (
     TRACE_HEADER,
     TRACE_SAMPLE_ENV,
@@ -106,6 +106,7 @@ __all__ = [
     "Span",
     "SpanRecorder",
     "get_recorder",
+    "identified",
     "span",
     "AnomalyEvent",
     "Watchdog",

@@ -12,12 +12,19 @@ Every span is recorded three ways, each on one clock:
   (``ph: "X"``, microseconds; ``ts`` and ``dur`` both from
   ``time.perf_counter``) carrying ``parent`` — the name of the span that
   encloses it on the same thread — and ``dispatch`` — the identifier of the
-  root span it descends from (one number per ``fit_on_device`` call, shared
-  by all its children). Both come from a thread-local stack.
+  root span it descends from (one number per ``fit_on_device`` call, or per
+  epoch of ``fit``, shared by all its children). Both come from a
+  thread-local stack.
 - seconds and count by name in the default registry's
   ``dl4jtpu_span_seconds{name=...}`` histogram, which is where runs without
   a profiler (set-up is never inside a traced window) read them, and where
   ``/metrics`` scrapes them.
+
+One identifier a unit of work: every span opened inside ``with
+identified(window=3):`` on that thread carries ``window=3`` in its event's
+args and on its ``TraceAnnotation`` (so the xplane event has it as a stat),
+whoever opens it. ``fit`` names a staged window and a batch that way, and the
+spans of ``fit_on_device`` under it are the window's without knowing of it.
 
 Closing a span never syncs the device: it records wall-clock enqueue time.
 Under async dispatch a span around an un-synced jit call measures dispatch,
@@ -84,7 +91,8 @@ def get_recorder() -> SpanRecorder:
     return _GLOBAL_RECORDER
 
 
-_STACK = threading.local()     # .spans: the open spans of this thread
+_STACK = threading.local()     # .spans: the open spans of this thread;
+#                                .ids: the unit of work they belong to
 _ROOT_IDS = itertools.count(1)  # one per root span (next() is atomic)
 
 
@@ -96,16 +104,60 @@ def _open_spans() -> list:
         return _STACK.spans
 
 
+def _unit_ids() -> dict:
+    return getattr(_STACK, "ids", None) or {}
+
+
+class identified:  # noqa: N801 - used as a function: ``with identified(...)``
+    """Spans this thread opens inside carry ``ids`` (``window=``, ``batch=``:
+    the ordinal of the unit of work in its epoch); an inner ``identified``
+    adds to the outer one's and wins where both name a key."""
+
+    __slots__ = ("_ids", "_outer")
+
+    def __init__(self, **ids):
+        self._ids = ids
+
+    def __enter__(self) -> None:
+        self._outer = _unit_ids()
+        _STACK.ids = {**self._outer, **self._ids}
+
+    def __exit__(self, *exc) -> None:
+        _STACK.ids = self._outer
+
+
+_SECONDS: dict = {}      # span name -> its child of dl4jtpu_span_seconds
+_SECONDS_IN = None       # the registry those children belong to
+
+
+def _seconds_of(name: str):
+    """``dl4jtpu_span_seconds{name}`` of the default registry, looked up once
+    a name (a per-batch span may not pay two locked lookups every time)."""
+    global _SECONDS_IN
+    reg = get_registry()
+    if reg is not _SECONDS_IN:  # a test put another default registry in place
+        _SECONDS.clear()
+        _SECONDS_IN = reg
+    child = _SECONDS.get(name)
+    if child is None:
+        child = _SECONDS[name] = reg.histogram(
+            "dl4jtpu_span_seconds", "host span durations",
+            labelnames=("name",)).labels(name=name)
+    return child
+
+
 class Span:
     """One named region; context manager or explicit ``start()``/``stop()``.
 
     After ``start()``, ``parent`` is the enclosing span's name (None for a
     root) and ``dispatch`` the root's identifier. ``args`` may be added to
-    until ``stop()``; they land in the in-memory event."""
+    until ``stop()``; they land in the in-memory event, under the
+    identifiers of the unit of work the thread is in (:func:`identified`)."""
 
     def __init__(self, name: str, **args):
         self.name = str(name)
         self.args = dict(args)
+        self.ids: dict = {}
         self.parent: Optional[str] = None
         self.dispatch: Optional[int] = None
         self._annotation = None
@@ -121,10 +173,12 @@ class Span:
         else:
             self.parent, self.dispatch = None, next(_ROOT_IDS)
         stack.append(self)
+        self.ids = _unit_ids()
         try:
             import jax  # noqa: PLC0415 - keep telemetry importable without jax
 
-            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                            **self.ids)
             self._annotation.__enter__()
         except Exception:
             self._annotation = None  # no profiler backend: host-only span
@@ -152,13 +206,10 @@ class Span:
             "dur": dur * 1e6,
             "pid": os.getpid(),
             "tid": threading.get_ident(),
-            "args": dict(self.args, parent=self.parent,
-                         dispatch=self.dispatch),
+            "args": {**self.ids, **self.args, "parent": self.parent,
+                     "dispatch": self.dispatch},
         })
-        get_registry().histogram(
-            "dl4jtpu_span_seconds", "host span durations",
-            labelnames=("name",),
-        ).labels(name=self.name).observe(dur)
+        _seconds_of(self.name).observe(dur)
         return dur
 
     def __enter__(self) -> "Span":

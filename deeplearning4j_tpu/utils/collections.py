@@ -18,6 +18,7 @@ import pickle
 import queue
 import tempfile
 import threading
+import time
 from typing import Any, Dict, Hashable, Iterable, Iterator, List, Optional, Tuple
 
 
@@ -155,7 +156,22 @@ class MagicQueue:
 class AsyncIterator:
     """Background-thread prefetch over any iterator (reference:
     parallelism/AsyncIterator.java; the generic sibling of
-    AsyncDataSetIterator)."""
+    AsyncDataSetIterator).
+
+    Counted in the default registry, where the work happens:
+
+    - ``dl4jtpu_iterator_gets_total{state}`` at the consumer's get, the end
+      sentinel's included: ``ready`` when an item was waiting, ``empty`` when
+      the consumer had to block for the producer;
+    - ``dl4jtpu_iterator_produce_seconds`` on the producer thread: each
+      ``next(base)``;
+    - ``dl4jtpu_iterator_queue_full_seconds`` on the producer thread: each
+      wait for room in a full queue (a put that found room observes nothing).
+
+    The producer thread opens no span: a span of another thread would take
+    the attribution of device-idle time from the consumer's (the span that
+    started later wins). ``fit`` puts ``dl4j.fit.next_batch`` around the
+    consumer's side."""
 
     _SENTINEL = object()
 
@@ -164,35 +180,61 @@ class AsyncIterator:
         self._size = int(queue_size)
 
     def __iter__(self) -> Iterator:
+        from ..telemetry import get_registry  # noqa: PLC0415
+
+        reg = get_registry()
+        gets = reg.counter(
+            "dl4jtpu_iterator_gets_total",
+            "consumer gets of the prefetch queue, by what they found",
+            labelnames=("state",))
+        ready, empty = gets.labels(state="ready"), gets.labels(state="empty")
+        produce_s = reg.histogram(
+            "dl4jtpu_iterator_produce_seconds",
+            "producer thread: seconds inside next(base)")
+        full_s = reg.histogram(
+            "dl4jtpu_iterator_queue_full_seconds",
+            "producer thread: seconds blocked on a full prefetch queue")
         q: "queue.Queue" = queue.Queue(maxsize=self._size)
         err: List[BaseException] = []
         stop = threading.Event()
 
+        def put(item) -> None:
+            """Until there is room or the consumer has gone. The one
+            producer: a queue it finds with room still has room at its put."""
+            if not q.full():
+                q.put(item)
+                return
+            t0 = time.perf_counter()
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    break
+                except queue.Full:
+                    continue
+            full_s.observe(time.perf_counter() - t0)
+
         def producer():
             try:
-                for item in self._base:
-                    while not stop.is_set():
-                        try:
-                            q.put(item, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
-                    if stop.is_set():
-                        return
+                base = iter(self._base)
+                while not stop.is_set():
+                    t0 = time.perf_counter()
+                    try:
+                        item = next(base)
+                    except StopIteration:
+                        break
+                    produce_s.observe(time.perf_counter() - t0)
+                    put(item)
             except BaseException as e:
                 err.append(e)
             finally:
-                while not stop.is_set():
-                    try:
-                        q.put(self._SENTINEL, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+                put(self._SENTINEL)
 
         t = threading.Thread(target=producer, daemon=True, name="async-iterator")
         t.start()
         try:
             while True:
+                # the one consumer: an item it finds waiting is its to get
+                (empty if q.empty() else ready).inc()
                 item = q.get()
                 if item is self._SENTINEL:
                     break

@@ -459,16 +459,24 @@ class TestIntegrations:
     def test_step_timer_records_into_registry(self):
         from deeplearning4j_tpu.profiler import StepTimer
 
-        reg = MetricsRegistry()
-        t = StepTimer(registry=reg, component="unit")
+        from deeplearning4j_tpu.telemetry import get_registry
+
+        def steps():
+            # a phase is the span dl4j.<component>.<phase>: the one store
+            fam = get_registry().histogram(
+                "dl4jtpu_span_seconds", "host span durations",
+                labelnames=("name",))
+            return fam.labels(name="dl4j.unit.step").count
+
+        before = steps()
+        t = StepTimer(component="unit")
         with t.phase("data"):
             pass
         with t.phase("step"):
             pass
         with t.phase("step"):
             pass
-        fam = reg.get("dl4jtpu_phase_seconds")
-        assert fam.labels(component="unit", phase="step").count == 2
+        assert steps() - before == 2
         assert t.breakdown()["step"]["count"] == 2  # dict API intact
 
     def test_streaming_pipeline_counters(self):
