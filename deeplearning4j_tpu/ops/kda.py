@@ -50,8 +50,9 @@ precision): the decays, their running sums, the scores, ``T``, the maps and
 the states, and every product between them at ``highest`` precision (six
 bfloat16 passes on the TPU's MXU; one pass, the default for float32 operands
 there, is good to 2^-8 only). ``o`` comes back in ``v``'s dtype.
-Differentiated by autodiff; the scores are under ``jax.checkpoint`` so that
-no ``SUB x SUB x K`` array is kept for the backward pass.
+:func:`kda_chunked` is differentiated by autodiff, its scores under
+``jax.checkpoint`` so that no ``SUB x SUB x K`` array is kept for the
+backward pass.
 
 ``T`` that is not a whole number of chunks is padded (``g = 0``, ``beta =
 0``: the state passes through unchanged) and the padding's outputs are cut
@@ -67,31 +68,50 @@ every call is in ``selection_log()`` with its chunk and shapes, and runs under
 - ``fused`` — :func:`kda_fused`: the same chunk equations with **a loop over
   the chunks that carries the state**, as two Mosaic kernels under one
   ``custom_vjp``, ``kda_fwd`` and ``kda_bwd`` (the pattern of
-  :mod:`.ssd_scan`). The grid is (batch, head, chunk), the chunk axis
-  sequential; the head's float32 state lives in VMEM scratch from chunk to
-  chunk, so ``M``, ``Z`` and the associative scan are not in the program.
-  ``v`` and ``o`` enter and leave as the layer has them, ``[B, T, H * V]``
-  in its dtype (a head is a 128-lane column block, a chunk ``C`` sublanes:
-  no transpose on either side). ``q``, ``k`` and the running sums ``G`` come
-  a chunk and head at a time (``[B, H, N, C, K]`` float32), the one copy the
-  scores read too: while the scores stay jax.numpy that layout has to exist,
-  a second, flat one for the kernels cost 25 ms of copies a step (747
-  against 722 ms busy), and two cotangents of a bfloat16 ``q`` that meet
-  only after each was rounded read 2.4e-3 off where one rounding reads
-  1.66e-3 (my chip runs, PR 37). The query's ``scale`` goes onto ``P`` and,
-  in float32, onto ``q`` inside the kernels (as a bfloat16 product with
-  ``q`` it cost 1e-3 on every result). Every product with
-  the state is in the kernels (``W S``, ``(q e^G) S``, ``P u~``, ``K_end^T
-  u~``, the state's decay), and so are ``W``, ``U``, ``q e^G`` and ``K_end``
-  themselves. The scores ``A``, ``P`` and ``T = (I + beta A)^-1`` stay
-  jax.numpy, batched over all chunks (a chunk's 16 rounds of substitution
-  are a chain of small dependent products: one chunk at a time they wait on
-  each other, 4,096 chunks at a time they do not). The forward saves the
-  state that enters each chunk (``[B, H, N, V, K]`` float32); the backward
-  walks the chunks last to first from those carrying the state's cotangent,
-  and returns the cotangent of ``beta A`` as ``-T^T dT T^T``, so autodiff
-  never sees the substitution. Products in the kernels are float32 at
-  ``highest`` (Mosaic's ``fp32`` contract precision), as the reference's.
+  :mod:`.ssd_scan`), and the chunk-local scores as two more under another,
+  ``kda_scores_fwd`` and ``kda_scores_bwd``. The walk's grid is (batch,
+  head, chunk), the chunk axis sequential; the head's float32 state lives in
+  VMEM scratch from chunk to chunk, so ``M``, ``Z`` and the associative scan
+  are not in the program. ``v`` and ``o`` enter and leave as the layer has
+  them, ``[B, T, H * V]`` in its dtype (a head is a 128-lane column block, a
+  chunk ``C`` sublanes: no transpose on either side). ``q``, ``k`` and the
+  running sums ``G`` come a chunk and head at a time (``[B, H, N, C, K]``
+  float32), the one copy both pairs of kernels read: the cotangents the two
+  pairs give ``q`` and ``k`` meet there and are rounded to the input's
+  dtype once (two cotangents of a bfloat16 ``q`` rounded apart read 2.4e-3
+  off the delta rule on a v5e, where one rounding reads 1.66e-3). The query's
+  ``scale`` goes onto ``P`` and, in float32, onto ``q`` inside the walk (as
+  a bfloat16 product with ``q`` it cost 1e-3 on every result). Every product
+  with the state is in the walk's kernels (``W S``, ``(q e^G) S``, ``P u~``,
+  ``K_end^T u~``, the state's decay), and so are ``W``, ``U``, ``q e^G`` and
+  ``K_end`` themselves. ``T = (I + beta A)^-1`` stays jax.numpy, batched over
+  all chunks (a chunk's 16 rounds of substitution are a chain of small
+  dependent products: one chunk at a time they wait on each other, 4,096
+  chunks at a time they do not). The forward saves the state that enters
+  each chunk (``[B, H, N, V, K]`` float32); the backward walks the chunks
+  last to first from those carrying the state's cotangent, and returns the
+  cotangent of ``beta A`` as ``-T^T dT T^T``, so autodiff never sees the
+  substitution.
+
+  The score kernels take a block of ``W`` positions (2,048 at the published
+  shapes) a grid step, every axis parallel, and give ``beta A`` and ``scale
+  P``. Inside a sub-block every pair has its own ``exp(G_r - G_i)``, formed
+  only where ``i <= r``, on the block turned in VMEM: row ``t`` of every
+  sub-block side by side (``[K, W / SUB]``, the sub-blocks along the lanes),
+  so that a pair of rows is two whole arrays for every sub-block at once and
+  a sum over the channels is a sum of whole vregs. Between sub-blocks, the
+  strips of :func:`_scores` (``G_ref`` on both sides), ``A``'s and ``P``'s
+  rows in one product. No pairwise ``[.., SUB, SUB, K]`` array reaches HBM;
+  the backward kernel forms the pairs' factors again from ``q``, ``k``,
+  ``G`` and ``beta``, so nothing is under ``jax.checkpoint`` and ``remat``
+  runs the scores forward twice a step, not three times.
+
+  Products in the kernels are float32 at ``highest`` (Mosaic's ``fp32``
+  contract precision), as the reference's. Every ``pallas_call`` of the
+  site sits behind a module-level ``jax.jit`` whose static arguments are
+  hashable values (the scale, the direction, interpret mode): each kernel is
+  traced and lowered once a program, however many sublayers call it, and
+  the loops inside the kernels are ``lax.fori_loop`` s, not unrolled.
   Available where ``K`` and ``V`` are whole 128-lane tiles, the chunk is
   ``SUB`` times a power of two and the footprint fits the VMEM budget.
 """
@@ -401,6 +421,285 @@ def _kda_bwd_kernel(scale, q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, p_ref,
                           keepdims=True)
 
 
+# The chunk-local scores as two Mosaic kernels under one ``custom_vjp``. A
+# grid step takes a block of ``W`` positions of one head (whole chunks),
+# every grid axis parallel. Inside the sub-blocks every pair has its own
+# ``exp(G_r - G_i)``, formed where the block is turned in VMEM: row ``t`` of
+# every sub-block side by side, ``[K, W / SUB]`` (the channels down the
+# sublanes, the sub-blocks along the lanes), ``t`` the leading index. So a
+# pair ``(t, u)`` of every sub-block at once is two whole arrays, and a sum
+# over the channels is a sum of whole vregs; nothing moves inside the loop
+# over the pairs. Between sub-blocks the strips of ``_scores``, a product a
+# sub-block (``A``'s and ``P``'s rows stacked), on the block as it lies.
+def _row_of_each(t, n):
+    """Row ``t`` of each of the ``n`` sub-blocks of a block: an index of
+    rows."""
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+
+    return pl.ds(t, n, stride=SUB)
+
+
+def _in_own_sub_block(C, n):
+    """``[C, n]``: whether column ``i`` of a chunk's ``A`` lies in sub-block
+    ``s`` of its chunk, for each of the block's ``n`` sub-blocks ``s``."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (C, n), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (C, n), 1)
+    return i // SUB == s % (C // SUB)
+
+
+def _strips(C, W, g_ref, strip):
+    """``strip(rows, to_ref, from_ref, chunk_rows)`` for every sub-block but
+    a chunk's first, of every chunk of the block: the sub-block's rows,
+    ``exp(G - G_ref)`` of them [SUB, K], ``exp(G_ref - G)`` of every row of
+    the chunk before them (0 at and after) [C, K], ``G_ref`` the running sum
+    just before the sub-block's first row: neither exponent is positive."""
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+
+    s = C // SUB
+    at = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+
+    def one(t, carry):
+        c, j = t // (s - 1), t % (s - 1) + 1
+        chunk_rows = pl.ds(pl.multiple_of(c * C, C), C)
+        rows = pl.ds(pl.multiple_of(c * C + j * SUB, SUB), SUB)
+        G = g_ref[chunk_rows, :]
+        G_ref = jnp.sum(jnp.where(at == j * SUB - 1, G, 0.0), axis=0,
+                        keepdims=True)
+        before = at < j * SUB
+        strip(rows, jnp.exp(g_ref[rows, :] - G_ref),
+              jnp.where(before, jnp.exp(jnp.where(before, G_ref - G, 0.0)),
+                        0.0), chunk_rows)
+        return carry
+
+    if s > 1:
+        jax.lax.fori_loop(0, (W // C) * (s - 1), one, 0)
+
+
+@jit_entry
+def _scores_fwd_kernel(scale, q_ref, k_ref, g_ref, b_ref, a_ref, p_ref, qc,
+                       kc, gc):
+    """``beta A`` and ``scale P`` [W, C] of a block of ``W`` positions: the
+    rows of its chunks one under the other. ``qc``, ``kc``, ``gc`` [SUB, K,
+    n]: the block turned."""
+    W, _ = k_ref.shape
+    C = a_ref.shape[1]
+    n = W // SUB
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUB, n), 0)
+    own = _in_own_sub_block(C, n)
+
+    def turn(t, carry):
+        for ref, turned in ((q_ref, qc), (k_ref, kc), (g_ref, gc)):
+            turned[t] = ref[_row_of_each(t, n), :].T
+        return carry
+
+    jax.lax.fori_loop(0, SUB, turn, 0)
+
+    def position(t, carry):      # row t of every sub-block, the rows before
+        q_t, k_t, G_t = qc[t], kc[t], gc[t]
+
+        def pair(u, acc):            # (t, u): every sub-block's at once
+            kk = kc[u] * jnp.exp(G_t - gc[u])
+            return tuple(jnp.where(row == u, jnp.sum(x * kk, 0, keepdims=True),
+                                   got) for x, got in zip((k_t, q_t), acc))
+
+        zero = jnp.zeros((SUB, n), jnp.float32)
+        A, P = jax.lax.fori_loop(0, t, pair, (zero, zero))
+        P = jnp.where(row == t, jnp.sum(q_t * k_t, 0, keepdims=True), P)
+        # row t of sub-block s is row SUB s + t of the block; its pairs go
+        # to the columns of its own sub-block, the (s mod C / SUB)-th
+        rows = _row_of_each(t, n)
+        for ref, got in ((a_ref, A), (p_ref, P)):
+            ref[rows, :] = jnp.where(own, jnp.tile(got, (C // SUB, 1)), 0.0).T
+        return carry
+
+    jax.lax.fori_loop(0, SUB, position, 0)
+
+    def strip(rows, to_ref, from_ref, chunk_rows):
+        both = _dot(jnp.concatenate([k_ref[rows, :], q_ref[rows, :]]) *
+                    jnp.tile(to_ref, (2, 1)),
+                    k_ref[chunk_rows, :] * from_ref, _ABT)      # [2 SUB, C]
+        a_ref[rows, :] += both[:SUB]
+        p_ref[rows, :] += both[SUB:]
+
+    _strips(C, W, g_ref, strip)
+    a_ref[...] = b_ref[...] * a_ref[...]
+    p_ref[...] = scale * p_ref[...]
+
+
+@jit_entry
+def _scores_bwd_kernel(scale, q_ref, k_ref, g_ref, b_ref, da_ref, dp_ref,
+                       dq_ref, dk_ref, dg_ref, db_ref, qc, kc, gc, kbc, dac,
+                       dpc, rc, qqc, lc):
+    """The cotangents of ``q``, ``k``, ``G`` and ``beta`` from those of ``beta
+    A`` and ``scale P``, the pairs' factors formed again. With ``dA`` the
+    cotangent of ``beta A``, ``dP`` that of ``P``, ``e`` a pair's factor,
+    ``R_r = sum_i dA_ri k_i e_ri``, ``Q_r = sum_i dP_ri k_i e_ri`` and ``L_i
+    = sum_r (dA_ri beta_r k_r + dP_ri q_r) e_ri``: ``dq = Q``, ``dk = beta R
+    + L``, ``dG = q Q + k (beta R - L)``, ``dbeta = <k, R>``. Inside the
+    sub-blocks ``R``, ``Q`` and ``L`` gather turned (``rc``, ``qqc``,
+    ``lc``); the strips add theirs in ``dk_ref``, ``dq_ref`` and
+    ``dg_ref``."""
+    W, K = k_ref.shape
+    C = da_ref.shape[1]
+    n, s = W // SUB, C // SUB
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUB, n), 0)
+    own = _in_own_sub_block(C, n)
+
+    def turn(t, carry):
+        rows = _row_of_each(t, n)
+        for ref, turned in ((q_ref, qc), (k_ref, kc), (g_ref, gc)):
+            turned[t] = ref[rows, :].T
+        kbc[t] = (b_ref[rows, :] * k_ref[rows, :]).T
+        # the cotangents of row t's pairs in its own sub-block: [SUB, n]
+        for ref, turned, f in ((da_ref, dac, 1.0), (dp_ref, dpc, scale)):
+            mine = jnp.where(own, f * ref[rows, :].T, 0.0)            # [C, n]
+            turned[t] = jnp.sum(mine.reshape(s, SUB, n), axis=0)
+        lc[t] = jnp.zeros((K, n), jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, SUB, turn, 0)
+
+    def position(t, carry):
+        q_t, k_t, G_t, kb_t = qc[t], kc[t], gc[t], kbc[t]
+        dA, dP = dac[t], dpc[t]         # row u: the pair (t, u)
+
+        def pair(u, acc):
+            R, Q = acc
+            e = jnp.exp(G_t - gc[u])
+            a = jnp.sum(jnp.where(row == u, dA, 0.0), 0, keepdims=True)
+            p = jnp.sum(jnp.where(row == u, dP, 0.0), 0, keepdims=True)
+            ke = kc[u] * e
+            lc[u] += (a * kb_t + p * q_t) * e
+            return R + a * ke, Q + p * ke
+
+        zero = jnp.zeros((K, n), jnp.float32)
+        R, Q = jax.lax.fori_loop(0, t, pair, (zero, zero))
+        p = jnp.sum(jnp.where(row == t, dP, 0.0), 0, keepdims=True)
+        lc[t] += p * q_t
+        rc[t], qqc[t] = R, Q + p * k_t
+        return carry
+
+    jax.lax.fori_loop(0, SUB, position, 0)
+
+    def turn_back(t, carry):
+        rows = _row_of_each(t, n)
+        for ref, turned in ((dk_ref, rc), (dq_ref, qqc), (dg_ref, lc)):
+            ref[rows, :] = turned[t].T
+        return carry
+
+    jax.lax.fori_loop(0, SUB, turn_back, 0)
+
+    def strip(rows, to_ref, from_ref, chunk_rows):
+        dAP = jnp.concatenate([da_ref[rows, :], scale * dp_ref[rows, :]])
+        RQ = _dot(dAP, k_ref[chunk_rows, :] * from_ref, _AB)   # [2 SUB, K]
+        dk_ref[rows, :] += to_ref * RQ[:SUB]
+        dq_ref[rows, :] += to_ref * RQ[SUB:]
+        kq = jnp.concatenate([b_ref[rows, :] * k_ref[rows, :], q_ref[rows, :]])
+        dg_ref[chunk_rows, :] += from_ref * _dot(
+            dAP, kq * jnp.tile(to_ref, (2, 1)), _ATB)
+
+    _strips(C, W, g_ref, strip)
+    beta, k, R, L = b_ref[...], k_ref[...], dk_ref[...], dg_ref[...]
+    dk_ref[...] = beta * R + L
+    dg_ref[...] = q_ref[...] * dq_ref[...] + k * (beta * R - L)
+    db_ref[...] = jnp.sum(k * R, axis=1, keepdims=True)
+
+
+def _scores_footprint(W: int, C: int, K: int) -> int:
+    """VMEM bytes of the backward score kernel (the larger of the two) at
+    blocks of ``W`` positions: the blocks double-buffered (``q``, ``k``,
+    ``G``, their cotangents, ``beta`` and its cotangent a column each, the
+    two ``[W, C]`` cotangents in), each at least a lane tile wide, and the
+    seven turned arrays."""
+    tile = lambda width: W * max(width, _LANES) * 4  # noqa: E731
+    blocks = 6 * tile(K) + 2 * tile(1) + 2 * tile(C)
+    return 2 * blocks + 7 * 4 * W * K + 2 * 4 * SUB * max(W // SUB, _LANES)
+
+
+def _scores_block(T: int, C: int, K: int) -> int:
+    """Positions a grid step of the score kernels: whole chunks, 2,048 where
+    they fit (128 sub-blocks: whole lane tiles for every pair)."""
+    return next((w for w in (2048, 1024, 512, 256, 128, 64, 32, 16)
+                 if w % C == 0 and T % w == 0
+                 and _scores_footprint(w, C, K) <= _seq_vmem_budget()), C)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "backward",
+                                             "interpret"))
+def _scores_call(*operands, scale, backward, interpret):
+    """``pallas_call`` of one of the two score kernels over the grid (batch,
+    head, blocks of positions), every axis parallel: ``operands`` are ``q``,
+    ``k``, ``G`` [B, H, N, C, K], ``beta`` [B, H, N, 1, C] and, backward,
+    the cotangents of ``beta A`` and ``scale P`` [B, H, N, C, C], all
+    float32, each seen as ``[B, H, N C, .]`` (``beta`` a column). Traced and
+    lowered once a program, however many sublayers call it."""
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    Bsz, H, N, C, K = operands[1].shape
+    W = _scores_block(N * C, C, K)
+    n = W // SUB
+
+    def rows(shape):    # [B, H, N, r, c] -> [B, H, N C, .]; beta a column
+        return (Bsz, H, N * C, 1 if shape[3] == 1 else shape[4])
+
+    def spec(shape):
+        return pl.BlockSpec((None, None, W, shape[3]),
+                            lambda b, h, w: (b, h, w, 0))
+
+    turned = [pltpu.VMEM((SUB, K, n), jnp.float32)] * 3
+    if backward:
+        kernel, outs = _scores_bwd_kernel, operands[:4]
+        turned += ([pltpu.VMEM((SUB, K, n), jnp.float32)]
+                   + [pltpu.VMEM((SUB, SUB, n), jnp.float32)] * 2
+                   + [pltpu.VMEM((SUB, K, n), jnp.float32)] * 3)
+    else:
+        square = jax.ShapeDtypeStruct((Bsz, H, N, C, C), jnp.float32)
+        kernel, outs = _scores_fwd_kernel, (square, square)
+    given = [a.reshape(rows(a.shape)) for a in operands]
+    shapes = [rows(a.shape) for a in outs]
+    got = pl.pallas_call(
+        functools.partial(kernel, scale),
+        grid=(Bsz, H, N * C // W),
+        in_specs=[spec(a.shape) for a in given],
+        out_specs=tuple(spec(shape) for shape in shapes),
+        out_shape=tuple(jax.ShapeDtypeStruct(shape, jnp.float32)
+                        for shape in shapes),
+        scratch_shapes=turned,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=max(_scores_footprint(W, C, K) * 5 // 4,
+                                 _SEQ_MIN_VMEM_LIMIT_BYTES)),
+        interpret=interpret,
+        name="kda_scores_bwd" if backward else "kda_scores_fwd",
+    )(*given)
+    return tuple(a.reshape(o.shape) for a, o in zip(got, outs))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def kda_scores(q, k, G, beta, scale):
+    """``(beta A, scale P)`` [B, H, N, C, C] of a chunk's ``q``, ``k`` and
+    running sums ``G`` [B, H, N, C, K] and ``beta`` [B, H, N, 1, C], all
+    float32: :func:`_scores` with ``beta`` on ``A``'s rows and ``scale`` on
+    ``P``, by the kernels ``kda_scores_fwd`` / ``kda_scores_bwd``. No pairwise
+    ``[.., SUB, SUB, K]`` array reaches HBM, and the backward forms the
+    pairs' factors again in VMEM: nothing to checkpoint."""
+    return _scores_call(q, k, G, beta, scale=scale, backward=False,
+                        interpret=_interpret())
+
+
+def _kda_scores_fwd(q, k, G, beta, scale):
+    return kda_scores(q, k, G, beta, scale), (q, k, G, beta)
+
+
+def _kda_scores_bwd(scale, residuals, cotangents):
+    return _scores_call(*residuals, *cotangents, scale=scale, backward=True,
+                        interpret=_interpret())
+
+
+kda_scores.defvjp(_kda_scores_fwd, _kda_scores_bwd)
+
+
 def kda_footprint(chunk: int, K: int, V: int, itemsize: int) -> int:
     """VMEM bytes of the backward kernel (the larger of the two) a grid step:
     the streamed blocks double-buffered (``q``, ``k``, ``G`` and their
@@ -423,21 +722,30 @@ def kda_layout_ok(chunk: int, K: int, V: int) -> bool:
     return K % _LANES == 0 and V % _LANES == 0 and _sub_block(chunk) == SUB
 
 
-def _kda_call(kernel, reverse, operands, outs):
+@functools.partial(jax.jit, static_argnames=("scale", "reverse",
+                                             "interpret"))
+def _kda_call(*operands, scale, reverse, interpret):
     """``pallas_call`` of one of the two kernels over the grid (batch, head,
     chunk), the backward's (``reverse``) last chunk first. An operand or
     result is ``[B, H, N, r, c]`` (a block ``(r, c)`` at head and chunk) or,
     ``v``, ``o`` and their cotangents, ``[B, T, H * V]`` as the layer has
     them (a block ``(C, V)``: a head is whole lane tiles, a chunk ``C``
-    sublanes, and nothing is transposed for them). ``outs``: the arrays each
-    result is shaped and typed like; ``operands[:5]`` are ``q``, ``k``,
-    ``v``, ``G`` and ``beta`` [B, H, N, 1, C]."""
+    sublanes, and nothing is transposed for them). ``operands[:7]`` are
+    ``q``, ``k``, ``v``, ``G``, ``beta`` [B, H, N, 1, C], ``T`` and ``P``;
+    the forward gives ``o`` and the states that enter the chunks, the
+    backward the cotangents of those seven. Traced and lowered once a
+    program, however many sublayers call it."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
     k, v = operands[1], operands[2]
     Bsz, H, N, C, K = k.shape
     V = v.shape[2] // H
+    if reverse:
+        kernel, outs = _kda_bwd_kernel, operands[:7]
+    else:
+        kernel = _kda_fwd_kernel
+        outs = (v, jax.ShapeDtypeStruct((Bsz, H, N, V, K), jnp.float32))
     at = (lambda n: N - 1 - n) if reverse else (lambda n: n)
 
     def spec(shape):
@@ -446,9 +754,8 @@ def _kda_call(kernel, reverse, operands, outs):
         return pl.BlockSpec((None, None, None) + tuple(shape[3:]),
                             lambda b, h, n: (b, h, at(n), 0, 0))
 
-    interpret = _interpret()
     return pl.pallas_call(
-        kernel,
+        functools.partial(kernel, scale),
         grid=(Bsz, H, N),
         in_specs=[spec(a.shape) for a in operands],
         out_specs=tuple(spec(a.shape) for a in outs),
@@ -464,12 +771,9 @@ def _kda_call(kernel, reverse, operands, outs):
 
 
 def _kda_fwd_call(q, k, v, G, beta, A, P, scale):
-    Bsz, H, N, C, K = k.shape
-    T = _round_state(_inverse_of_unit_lower(A, _sub_block(C)))
-    states = jax.ShapeDtypeStruct((Bsz, H, N, v.shape[2] // H, K),
-                                  jnp.float32)
-    o, states = _kda_call(functools.partial(_kda_fwd_kernel, scale), False,
-                          (q, k, v, G, beta, T, P), (v, states))
+    T = _round_state(_inverse_of_unit_lower(A, _sub_block(k.shape[3])))
+    o, states = _kda_call(q, k, v, G, beta, T, P, scale=scale, reverse=False,
+                          interpret=_interpret())
     return o, (q, k, v, G, beta, T, P, states)
 
 
@@ -487,8 +791,8 @@ def _kda_walk(q, k, v, G, beta, A, P, scale):
 
 def _kda_walk_bwd(scale, residuals, do):
     *operands, states = residuals
-    return _kda_call(functools.partial(_kda_bwd_kernel, scale), True,
-                     (*operands, _round_state(states), do), operands)
+    return _kda_call(*operands, _round_state(states), do, scale=scale,
+                     reverse=True, interpret=_interpret())
 
 
 _kda_walk.defvjp(_kda_fwd_call, _kda_walk_bwd)
@@ -496,10 +800,11 @@ _kda_walk.defvjp(_kda_fwd_call, _kda_walk_bwd)
 
 def kda_fused(q, k, v, g, beta, chunk: int = 64, scale: float = 1.0):
     """Same contract as :func:`kda_chunked`, the state carried over the
-    chunks by the kernels ``kda_fwd`` / ``kda_bwd`` (interpret mode off the
-    TPU): no chunk maps and no scan over them. The scores and the inverse
-    stay jax.numpy, over all chunks at once. Float32 throughout, whatever
-    the inputs' dtype."""
+    chunks by the kernels ``kda_fwd`` / ``kda_bwd`` and the scores formed by
+    ``kda_scores_fwd`` / ``kda_scores_bwd`` (interpret mode off the TPU): no
+    chunk maps, no scan over them, no pairwise array. The inverse stays
+    jax.numpy, over all chunks at once. Float32 throughout, whatever the
+    inputs' dtype."""
     f = jnp.float32
     T, q, k, v, g, beta = _pad_time(chunk, q, k, v, g, beta)
     Bsz, Tp, H, K = k.shape
@@ -519,9 +824,9 @@ def kda_fused(q, k, v, g, beta, chunk: int = 64, scale: float = 1.0):
     G = _round_state(_mm(jnp.tril(jnp.ones((C, C), f)), chunks(g),
                          "ij,...jk->...ik"))                 # [B, H, N, C, K]
     # P is linear in q: the query's scale goes onto its [C, C], not onto q
-    A, P = _scores(q, k, G, _sub_block(C))
-    o = _kda_walk(q, k, v.reshape(Bsz, Tp, H * V), G, beta[..., None, :],
-                  beta[..., None] * A, P * scale, scale)
+    beta = beta[..., None, :]
+    A, P = kda_scores(q, k, G, beta, scale)
+    o = _kda_walk(q, k, v.reshape(Bsz, Tp, H * V), G, beta, A, P, scale)
     return o.reshape(Bsz, Tp, H, V)[:, :T]
 
 

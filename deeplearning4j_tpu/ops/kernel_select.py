@@ -967,12 +967,16 @@ register_site(Site(
     reference="reference",
     preferred_fused="fused",
     variants={
+        # ``scores``: what forms the chunk-local scores (the kernels
+        # ``kda_scores_fwd`` / ``_bwd``, or ``_scores`` in jax.numpy)
         "fused": Variant("fused", fused=True, cost=_kda_fused_cost,
                          available=_kda_fits_ctx,
-                         detail=lambda ctx: {"chunk": ctx["chunk"]}),
+                         detail=lambda ctx: {"chunk": ctx["chunk"],
+                                             "scores": "kernel"}),
         "reference": Variant("reference", fused=False,
                              cost=_kda_reference_cost, unfused_bytes=True,
-                             detail=lambda ctx: {"chunk": ctx["chunk"]}),
+                             detail=lambda ctx: {"chunk": ctx["chunk"],
+                                                 "scores": "jnp"}),
     },
 ))
 

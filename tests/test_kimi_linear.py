@@ -183,6 +183,7 @@ def test_the_site_records_the_chunk_and_the_shapes_once():
     jax.jit(lambda *a: kda.kda_recurrence(*a, chunk=8))(*args)
     (rec,) = [r for r in ks.selection_log() if r["site"] == "kda_recurrence"]
     assert rec["variant"] == "reference" and rec["chunk"] == 8
+    assert rec["scores"] == "jnp"
     assert rec["ctx"] == {"B": 2, "T": 20, "H": 2, "K": 8, "V": 8,
                           "chunk": 8, "itemsize": 4}
     # its jax.numpy under every mode: the kernels do not tile K = 8
@@ -260,6 +261,57 @@ def test_the_kernels_match_the_recurrence(case, dtype):
               1e-6)
 
 
+def scores_inputs(case, dtype, chunk=64):
+    """``(q, k, G, beta)`` of one case as :func:`kda.kda_fused` hands them to
+    the scores: float32 chunks ``[B, H, N, C, K]``, ``G`` the running sums
+    of the log-decays, ``beta`` a row ``[B, H, N, 1, C]``."""
+    q, k, v, g, beta = fused_inputs(case, dtype)
+    _, q, k, v, g, beta = kda._pad_time(chunk, q, k, v, g, beta)
+    Bsz, Tp, H, _ = k.shape
+
+    def chunks(a):
+        a = a.astype(jnp.float32).reshape((Bsz, Tp // chunk, chunk)
+                                          + a.shape[2:])
+        return jnp.moveaxis(a, 3, 1)
+
+    return (chunks(q), chunks(k), jnp.cumsum(chunks(g), axis=3),
+            chunks(beta)[..., None, :])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", FUSED_CASES)
+def test_the_score_kernels_match_the_scores_and_their_gradient(case, dtype):
+    """``kda_scores_fwd`` / ``kda_scores_bwd`` in interpret mode against
+    ``_scores`` (``beta`` on ``A``'s rows, ``scale`` on ``P``) and its
+    autodiff: ``beta A``, ``scale P`` and the cotangents of ``q``, ``k``,
+    ``G`` and ``beta``, float32 all through (``q`` and ``k`` rounded to
+    ``dtype`` first, as the layer hands them over)."""
+    args = scores_inputs(case, dtype)
+    if case == "strong decays":     # a chunk's summed log-decay past -88
+        assert float(jnp.min(args[2][..., -1, :])) < -88.0
+    C = args[0].shape[3]
+
+    def plain(q, k, G, beta):
+        A, P = kda._scores(q, k, G, kda.SUB)
+        return jnp.swapaxes(beta, -1, -2) * A, 0.5 * P
+
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    w = tuple(jax.random.normal(key, args[0].shape[:3] + (C, C),
+                                jnp.float32) for key in keys)
+
+    def both(fn):
+        out, pull = jax.vjp(fn, *args)
+        return out + pull(w)
+
+    want = both(plain)
+    got = both(lambda *a: kda.kda_scores(*a, 0.5))
+    for name, a, b in zip(("beta A", "scale P", "d_q", "d_k", "d_G",
+                           "d_beta"), got, want):
+        assert a.dtype == jnp.float32 and a.shape == b.shape, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        close(a, b, 1e-5)
+
+
 def test_the_site_takes_the_kernels_where_they_tile():
     """``fused`` at the published head (K = V = 128, chunks of 64) when fused
     variants compete, the jax.numpy at the tests' small shapes, off a lane
@@ -290,6 +342,7 @@ def test_the_site_takes_the_kernels_where_they_tile():
           kda.kda_chunked(*args, chunk=64).astype(jnp.float32), 1e-2)
     (rec,) = [r for r in ks.selection_log() if r["site"] == "kda_recurrence"]
     assert rec["variant"] == "fused" and rec["chunk"] == 64
+    assert rec["scores"] == "kernel"
     assert rec["ctx"] == {"B": 1, "T": 64, "H": 1, "K": 128, "V": 128,
                           "chunk": 64, "itemsize": 2}
 

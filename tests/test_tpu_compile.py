@@ -6,6 +6,7 @@ kernels reckon for themselves). Nothing runs, so nothing here is a time.
 All such compiles live in this one file and describe the topology inside a
 fixture: only the worker that is given the file loads the TPU's library."""
 
+import collections
 import re
 
 import jax
@@ -106,6 +107,29 @@ def test_ssd_scan_kernels_compile_for_the_v5e_at_the_published_shapes(
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
+_KDA_KERNELS = ("kda_fwd", "kda_bwd", "kda_scores_fwd", "kda_scores_bwd")
+_KERNEL_NAME = re.compile(r'kernel_name\s*=\s*"?(kda_\w+)')
+
+
+def _kda_args(one_chip, B, T, H, K, V, dtype):
+    """``q``, ``k``, ``v`` in ``dtype``, the log-decays and ``beta`` in
+    float32, as a layer hands them over."""
+    s = lambda shape, d=dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, d, sharding=one_chip)
+    return (s((B, T, H, K)), s((B, T, H, K)), s((B, T, H, V)),
+            s((B, T, H, K), jnp.float32), s((B, T, H), jnp.float32))
+
+
+def _lowered_gradient(fn, args):
+    def loss(*a):
+        return jnp.sum(fn(*a).astype(jnp.float32) ** 2)
+
+    # a function of its own each time: jit would hand back the first trace
+    with jax.enable_x64(False):
+        return jax.jit(lambda *a: jax.grad(loss, argnums=(0, 1, 2, 3, 4))(
+            *a)).lower(*args).as_text()
+
+
 @pytest.mark.parametrize("B,T,H,K,V,chunk,dtype", [
     (1, 8192, 32, 128, 128, 64, "bfloat16"),   # kimi_linear_train_1chip
     (1, 8192, 32, 128, 128, 64, "float32"),    # chip_smoke's f32
@@ -114,19 +138,17 @@ def test_ssd_scan_kernels_compile_for_the_v5e_at_the_published_shapes(
 ])
 def test_kda_kernels_compile_for_the_v5e_at_the_published_shapes(
         one_chip, monkeypatch, B, T, H, K, V, chunk, dtype):
-    """``kda_fwd`` / ``kda_bwd`` with their float32 products at ``highest``
-    through Mosaic: a compile that succeeds held them inside the
-    ``vmem_limit_bytes`` their footprint states."""
+    """``kda_fwd`` / ``kda_bwd`` and the score kernels ``kda_scores_fwd`` /
+    ``kda_scores_bwd`` with their float32 products at ``highest`` through
+    Mosaic: a compile that succeeds held them inside the
+    ``vmem_limit_bytes`` their footprints state."""
     from deeplearning4j_tpu.ops import kda
 
     monkeypatch.setattr(kda, "_interpret", lambda: False)
     dt = jnp.dtype(dtype)
     assert kda.kda_layout_ok(chunk, K, V)
     assert kda.kda_fits(chunk, K, V, dt.itemsize)
-    s = lambda shape, d=dt: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, d, sharding=one_chip)
-    args = (s((B, T, H, K)), s((B, T, H, K)), s((B, T, H, V)),
-            s((B, T, H, K), jnp.float32), s((B, T, H), jnp.float32))
+    args = _kda_args(one_chip, B, T, H, K, V, dt)
 
     def loss(*a):
         o = kda.kda_fused(*a, chunk=chunk, scale=K ** -0.5)
@@ -135,8 +157,104 @@ def test_kda_kernels_compile_for_the_v5e_at_the_published_shapes(
     grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
     with jax.enable_x64(False):
         text = grad.lower(*args).compile().as_text()
-    assert text.count("tpu_custom_call") == 2
-    assert "kda_fwd" in text and "kda_bwd" in text
+    assert text.count("tpu_custom_call") == 4
+    assert {name: len(re.findall(rf"%{name}[.\d]* = ", text))
+            for name in _KDA_KERNELS} == {name: 1 for name in _KDA_KERNELS}
+
+
+def test_kda_fused_program_holds_no_pairwise_array(one_chip, monkeypatch):
+    """The ``fused`` gradient lowered for a described v5e, so that the
+    kernels are custom calls and not interpret-mode HLO: no ``[.., 16, 16,
+    128]`` array of every pair's factor is in its text, where
+    ``kda_chunked``'s, the control, has one."""
+    from deeplearning4j_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    args = _kda_args(one_chip, 1, 1024, 4, 128, 128, jnp.bfloat16)
+    pairwise = re.compile(r"x16x16x128xf32>")
+
+    def text(fn):
+        return _lowered_gradient(
+            lambda *a: fn(*a, chunk=64, scale=128 ** -0.5), args)
+
+    assert pairwise.search(text(kda.kda_chunked))
+    fused = text(kda.kda_fused)
+    assert "kda_scores_fwd" in fused and "kda_scores_bwd" in fused
+    assert not pairwise.search(fused)
+
+
+def test_kda_kernels_are_lowered_once_a_program(one_chip, monkeypatch):
+    """The gradient of four ``jax.checkpoint``ed sublayers, as the engine's
+    ``remat`` runs them, lowers each of the four kernels once: every call
+    site shares the jitted call's one trace, and JAX lowers a kernel of one
+    trace once and hands every caller that lowering (the forward kernels
+    stand in the forward pass and in the recomputed forward, with one body
+    between them). Without the ``jax.jit`` around the calls, the control,
+    every call site traces and lowers its kernels anew: that is what set-up
+    time paid for each sublayer."""
+    from jax._src.pallas.mosaic import lowering as mosaic
+
+    from deeplearning4j_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    lowered = collections.Counter()
+    real = mosaic.lower_jaxpr_to_module
+
+    def counted(*a, **kw):
+        module = real(*a, **kw)
+        lowered[str(module.operation.attributes["sym_name"]).strip('"')] += 1
+        return module
+
+    monkeypatch.setattr(mosaic, "lower_jaxpr_to_module", counted)
+    args = _kda_args(one_chip, 1, 1024, 4, 128, 128, jnp.bfloat16)
+
+    def sublayer(q, k, v, g, beta):
+        return kda.kda_fused(q, k, v, g, beta, chunk=64, scale=128 ** -0.5)
+
+    def four(q, k, v, g, beta):
+        for i in range(4):      # a function of its own, as each layer is
+            with jax.named_scope(f"b{i}K_mixer"):
+                v = jax.checkpoint(lambda *a: sublayer(*a))(q, k, v, g, beta)
+        return v
+
+    text = _lowered_gradient(four, args)
+    assert lowered == {name: 1 for name in _KDA_KERNELS}
+    bodies = collections.defaultdict(set)
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and _KERNEL_NAME.search(line):
+            bodies[_KERNEL_NAME.search(line).group(1)].add(
+                re.search(r'backend_config = "([^"]*)', line).group(1))
+    assert {name: len(b) for name, b in bodies.items()} == {
+        name: 1 for name in _KDA_KERNELS}
+    lowered.clear()
+    monkeypatch.setattr(kda, "_scores_call", kda._scores_call.__wrapped__)
+    monkeypatch.setattr(kda, "_kda_call", kda._kda_call.__wrapped__)
+    _lowered_gradient(four, args)
+    assert all(lowered[name] >= 4 for name in _KDA_KERNELS), lowered
+
+
+def test_kda_site_records_which_form_the_scores_take(one_chip, monkeypatch):
+    """The site's record says what formed the scores: ``kernel`` where it
+    resolves ``fused`` (and the program then holds the score kernels),
+    ``jnp`` on the reference."""
+    from deeplearning4j_tpu.ops import kda
+    from deeplearning4j_tpu.ops import kernel_select as ks
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    args = _kda_args(one_chip, 1, 1024, 4, 128, 128, jnp.bfloat16)
+    ks.reset()
+    try:
+        for mode, scores in (("fused", "kernel"), ("reference", "jnp")):
+            with ks.forced_mode(mode), jax.enable_x64(False):
+                text = jax.jit(lambda *a: kda.kda_recurrence(
+                    *a, chunk=64)).lower(*args).as_text()
+            (rec,) = [r for r in ks.selection_log()
+                      if r["site"] == "kda_recurrence"]
+            assert rec["variant"] == mode and rec["scores"] == scores
+            assert ("kda_scores_fwd" in text) == (mode == "fused")
+            ks.reset()
+    finally:
+        ks.reset()
 
 
 @pytest.mark.parametrize("M,K,N,E,dtype", [
