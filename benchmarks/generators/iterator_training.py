@@ -18,8 +18,11 @@ pool is walked on that producer thread. It ends once the time left is under
 what is already handed out and not yet trained plus one more window (staged)
 or batch (per batch), at the warm-up's step duration. A staged run hands out
 whole windows only (a ragged tail would be a new shape, and a compile inside
-the window), and at least one window or batch is handed out. After ``fit``
-returns, the last loss is fetched, which is the synchronisation.
+the window). Whatever the clock says, it hands out at least two units (two
+windows, or twice ``warmup_batches`` per batch), so that a slow host gives
+``verify`` a last unit that follows a whole one, and a program that drops one
+window still trains another; on the v5e the window trains far more. After
+``fit`` returns, the last loss is fetched, which is the synchronisation.
 
 Closed loop, one client: the training thread pulls as fast as it trains.
 
@@ -34,7 +37,10 @@ function: non-finite losses, the first loss against ln(classes) and against
 the plain reference, the mean loss of the last unit under the first's) and
 ``batches_handed_out_not_trained``: what the iterator handed out less what
 ``net.iteration`` moved by, which has to be 0 (nothing dropped, the epoch not
-ended early).
+ended early). A window that trained nothing gives a line too, never an
+exception: every batch handed out counts as not trained, and the net's first
+unit stands in for the missing last one, so the fall reads 1 (none), over its
+limit, and not NaN, which JSON cannot carry.
 """
 
 from __future__ import annotations
@@ -75,14 +81,17 @@ class KeepLosses:
 class CyclingPool:
     """The pool's batches by reference, round and round; see the module
     docstring for when it ends. ``limit``: end after so many batches instead
-    (the warm-up). Walked on ``fit``'s prefetch thread."""
+    (the warm-up). ``least``: the batches handed out before the clock may end
+    a timed epoch (a multiple of ``unit``). Walked on ``fit``'s prefetch
+    thread."""
 
     prefetch_supported = True
 
     def __init__(self, pool, *, unit, limit=None, seconds=None, step_s=None,
-                 trained=None):
+                 trained=None, least=None):
         self.pool, self.unit, self.limit = pool, int(unit), limit
         self.seconds, self.step_s, self.trained = seconds, step_s, trained
+        self.least = self.unit if least is None else int(least)
         self.handed_out = 0
         self.t0 = None  # the caller starts the clock
         self.times = []  # when each batch was handed out
@@ -96,7 +105,7 @@ class CyclingPool:
     def _over(self) -> bool:
         if self.limit is not None:
             return self.handed_out >= self.limit
-        if self.handed_out == 0:
+        if self.handed_out < self.least:
             return False
         in_flight = self.handed_out - self.trained()
         left = self.seconds - (time.perf_counter() - self.t0)
@@ -218,7 +227,8 @@ def run(ctx, st: dict, seconds: float) -> dict:
     before = net.iteration
     it = CyclingPool(st["pool"], unit=max(st["stage"], 1), seconds=seconds,
                      step_s=st["step_s"],
-                     trained=lambda: net.iteration - before)
+                     trained=lambda: net.iteration - before,
+                     least=2 * st["unit"])
     counts = program_counts()
     it.t0 = time.perf_counter()
     with spans.span("fit"):
@@ -251,11 +261,16 @@ def verify(ctx, st: dict, result: dict) -> dict:
     """Brings the window's losses to the host (and so completes ``result``:
     ``failed``, and ``losses`` with the last unit's last, which is what
     ``staged_training.verify`` reads), then compares."""
-    losses = st["keep"].take()
+    losses, unit = st["keep"].take(), st["unit"]
     result["failed"] = int(np.sum(~np.isfinite(losses)))
-    result["losses"] = [losses[:-st["unit"]], losses[-st["unit"]:]]
-    ctx.log(f"loss {losses[0]:.4f} -> {losses[-1]:.4f} over {len(losses)} "
-            f"steps")
+    if len(losses):
+        result["losses"] = [losses[:-unit], losses[-unit:]]
+        ctx.log(f"loss {losses[0]:.4f} -> {losses[-1]:.4f} over "
+                f"{len(losses)} steps")
+    else:  # the first unit stands in for the last: a fall of 1 (none)
+        result["losses"] = [losses, st["first_losses"]]
+        ctx.log(f"no step trained of the {result['handed_out']} batches "
+                f"handed out; the first unit stands in for the last")
     compared = st["staged"].verify(ctx, st, result)
     compared["batches_handed_out_not_trained"] = (
         abs(result["handed_out"] - result["attempted"]), 0)

@@ -1,8 +1,9 @@
 """The cells of ``fit(iterator)`` (PR 38) and the readers of what the program
 records there: each reader on hand-built spans, gaps and counter deltas (and
 ``None`` on a program without them), the manifest's entries, the generator's
-iterator, and a rehearsal whose planted fault (the program drops the last
-window it was handed) ``verify`` catches.
+iterator, and rehearsals whose planted faults (the program drops the last
+window it was handed, or trains none of the window) ``verify`` catches,
+however slow the host.
 
 ``resnet50_fit_iterator`` is in the manifest. Its staged sibling is not: its
 rate spread 1.6% over five seeds on the v5e, and its entries wait in
@@ -300,6 +301,23 @@ def test_the_pool_is_cycled_by_reference_and_ends_on_a_whole_window():
     assert per_batch.pace() == "too few hand-outs for a pace"
 
 
+def test_a_timed_epoch_hands_out_two_units_however_slow_the_step():
+    """``least``: the clock ends no timed epoch before so many batches, so a
+    slow host's window still holds two units; past it the clock decides."""
+    gen = generator()
+    pool = [object() for _ in range(6)]
+    for unit, least in ((4, 8), (1, 8), (1, 2)):
+        slow = gen.CyclingPool(pool, unit=unit, seconds=0.5, step_s=10.0,
+                               trained=lambda: 0, least=least)
+        slow.t0 = time.perf_counter() - 5.0          # the time is long past
+        assert len(list(slow)) == least == slow.handed_out
+    # on a fast host the clock still ends the epoch past the least
+    fast = gen.CyclingPool(pool, unit=4, seconds=100.0, step_s=1.0,
+                           trained=lambda: 0, least=8)
+    fast.t0 = time.perf_counter()
+    assert len(list(fast)) == 96      # 96 in flight + a window > 99 s left
+
+
 # ------------------------------------------------------------ the rehearsal
 @pytest.mark.parametrize("name", [PER_BATCH, STAGED])
 def test_a_rehearsal_ends_correct_with_every_batch_handed_out_trained(
@@ -360,3 +378,38 @@ def test_a_program_that_drops_the_last_window_it_was_handed_is_not_correct(
     over = {k for k, c in line["compared"].items() if c["value"] > c["limit"]}
     assert over == {"batches_handed_out_not_trained"}
     assert line["compared"]["batches_handed_out_not_trained"]["value"] == 4
+
+
+def test_a_program_that_trains_none_of_the_window_gives_a_line_not_correct(
+        monkeypatch, cell):
+    """The planted fault at its extreme: ``fit``'s prefetch iterator walks
+    the measured epoch and hands ``fit`` none of it. ``verify`` gives a line
+    (no exception on the empty loss list) that says so: every batch handed
+    out not trained, the fall read as 1 (none), and every number finite."""
+    from deeplearning4j_tpu.datasets import iterators
+
+    whole = iterators.AsyncDataSetIterator.__iter__
+    handed_out = []
+
+    def withholds_all(self):
+        if getattr(self.base, "limit", 0) is not None:
+            yield from whole(self)
+            return
+        for _ in whole(self):
+            pass
+        handed_out.append(self.base.handed_out)
+        yield from ()
+
+    monkeypatch.setattr(iterators.AsyncDataSetIterator, "__iter__",
+                        withholds_all)
+    line = rehearse(cell(STAGED), seconds=0.5)
+    json.loads(json.dumps(line, allow_nan=False))   # no NaN, no infinity
+    assert line["correct"] is False
+    assert (line["attempted"], line["failed"]) == (0, 0)
+    compared = line["compared"]
+    assert compared["batches_handed_out_not_trained"]["value"] \
+        == handed_out[0] >= 8                 # two windows of 4 at the least
+    over = {k for k, c in compared.items() if c["value"] > c["limit"]}
+    assert over == {"batches_handed_out_not_trained",
+                    "last_over_first_mean_loss"}
+    assert compared["last_over_first_mean_loss"]["value"] == 1.0
